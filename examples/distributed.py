@@ -12,10 +12,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from splatt_tpu.utils.env import apply_env_platform
-
-apply_env_platform()
-
 import jax
 
 import splatt_tpu

@@ -9,10 +9,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from splatt_tpu.utils.env import apply_env_platform
-
-apply_env_platform()  # make JAX_PLATFORMS authoritative over site plugins
-
 import numpy as np
 
 import splatt_tpu

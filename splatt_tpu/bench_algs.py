@@ -180,25 +180,13 @@ def format_bench(results: Dict[str, List[float]]) -> str:
 
 # -- roofline model ---------------------------------------------------------
 
-#: HBM peak bandwidth by device-kind prefix (GB/s).  Sources: public
-#: TPU spec sheets (v4 1228, v5e 819, v5p 2765, v6e "Trillium" 1640).
-HBM_PEAK_GBS = (("TPU v6", 1640.0), ("TPU v5p", 2765.0),
-                ("TPU v5", 819.0), ("TPU v4", 1228.0), ("TPU v3", 900.0),
-                ("TPU v2", 700.0))
-
-
 def hbm_peak_gbs() -> Optional[float]:
-    """Peak HBM bandwidth of device 0, or None off-TPU."""
-    try:
-        kind = jax.devices()[0].device_kind
-    # splint: ignore[SPL002] device discovery off-accelerator: absence
-    # of a backend is the signal (no roofline), not a failure to route
-    except Exception:
-        return None
-    for prefix, gbs in HBM_PEAK_GBS:
-        if kind.startswith(prefix):
-            return gbs
-    return None
+    """Peak HBM bandwidth of device 0 (splatt_tpu/devices.py), or None
+    off-TPU; an unknown TPU kind raises."""
+    from splatt_tpu.devices import device_spec
+
+    spec = device_spec()
+    return spec.hbm_gbs if spec is not None else None
 
 
 def mttkrp_bytes(alg: str, tt: SparseTensor, rank: int, mode: int,
@@ -316,35 +304,13 @@ def mttkrp_decode_bytes(X: BlockedSparse, rank: int, mode: int,
     return decoded
 
 
-#: MXU peak compute by device-kind prefix (bf16 GFLOP/s per chip).
-#: Sources: public TPU spec sheets (v4 275 TFLOPS, v5e 197, v5p 459,
-#: v6e "Trillium" 918).  The same prefix-match contract as
-#: :data:`HBM_PEAK_GBS`.
-MXU_PEAK_GFLOPS = (("TPU v6", 918000.0), ("TPU v5p", 459000.0),
-                   ("TPU v5", 197000.0), ("TPU v4", 275000.0),
-                   ("TPU v3", 123000.0), ("TPU v2", 45000.0))
-
-#: nominal CPU peaks for the roofline VERDICT off-TPU (docs/dense.md):
-#: the bound classification (memory- vs compute-bound) only needs the
-#: ridge's order of magnitude, and CI runs the densemode bench on CPU —
-#: a missing peak would silence the verdict legs exactly where they
-#: gate.
-NOMINAL_CPU_GBS = 50.0
-NOMINAL_CPU_GFLOPS = 100.0
-
-
 def mxu_peak_gflops() -> Optional[float]:
-    """Peak MXU compute of device 0 (bf16 GFLOP/s), or None off-TPU."""
-    try:
-        kind = jax.devices()[0].device_kind
-    # splint: ignore[SPL002] device discovery off-accelerator: absence
-    # of a backend is the signal (no roofline), not a failure to route
-    except Exception:
-        return None
-    for prefix, gflops in MXU_PEAK_GFLOPS:
-        if kind.startswith(prefix):
-            return gflops
-    return None
+    """Peak MXU compute of device 0 (bf16 GFLOP/s), or None off-TPU;
+    an unknown TPU kind raises."""
+    from splatt_tpu.devices import device_spec
+
+    spec = device_spec()
+    return spec.mxu_gflops if spec is not None else None
 
 
 def mttkrp_flops(alg: str, X: BlockedSparse, rank: int,
@@ -373,16 +339,20 @@ def mttkrp_flops(alg: str, X: BlockedSparse, rank: int,
     return flops
 
 
-def roofline_verdict(bytes_moved: float, flops: float) -> dict:
+def roofline_verdict(bytes_moved: float, flops: float,
+                     spec=None) -> Optional[dict]:
     """Classify one path against the device roofline: arithmetic
     intensity (flops/byte), the device ridge point (peak flops / peak
-    bandwidth), and which side of it the path sits on.  Off-TPU the
-    NOMINAL CPU peaks stand in — the bound verdict is an order-of-
-    magnitude classification, not a measurement (docs/dense.md)."""
-    peak_bw = hbm_peak_gbs() or NOMINAL_CPU_GBS
-    peak_fl = mxu_peak_gflops() or NOMINAL_CPU_GFLOPS
+    bandwidth), and which side of it the path sits on.  `spec` (a
+    devices.DeviceSpec) defaults to device 0's; off-TPU there is no
+    roofline and the verdict is None."""
+    from splatt_tpu.devices import device_spec
+
+    spec = spec if spec is not None else device_spec()
+    if spec is None:
+        return None
     intensity = flops / max(bytes_moved, 1.0)
-    ridge = peak_fl / peak_bw
+    ridge = spec.mxu_gflops / spec.hbm_gbs
     return dict(intensity=round(intensity, 3), ridge=round(ridge, 3),
                 bound=("compute" if intensity >= ridge else "memory"))
 

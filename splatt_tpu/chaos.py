@@ -3,7 +3,7 @@
 Resilience machinery that is only exercised by unit tests decays the
 moment two guards interact in a way no unit test composed.  This module
 runs a REAL (small, seeded, synthetic) CPD under a declarative fault
-schedule — NaN poisoning, blown deadlines, transient relay failures,
+schedule — NaN poisoning, blown deadlines, transient service failures,
 engine crashes, all at once — and asserts the single invariant the
 guarded execution layer promises:
 
@@ -40,7 +40,7 @@ import numpy as np
 
 #: default schedule: one of each guard's quarry — a NaN poisoning at a
 #: fixed iteration (sentinel + rollback), a slow tuner measurement
-#: under the deadline watchdog (TIMEOUT), a transient relay failure at
+#: under the deadline watchdog (TIMEOUT), a transient service failure at
 #: an engine's first compile (retry-with-backoff; ``engine.xla`` is
 #: the terminal engine, live on every backend), and a ring-exchange
 #: failure in the distributed comm drill (the async-ring sweep must
@@ -324,6 +324,10 @@ def run_bench_gate(smoke: bool = True,
     # splint: ignore[SPL001] forwarding the whole environment to the
     # bench subprocess, not reading config — no single ENV_VARS name
     env = dict(os.environ)
+    # the gate child benches the CPU on purpose: the parent may hold the
+    # chip (one process per chip), and bench.py measures the CPU only
+    # when asked to by name
+    env["JAX_PLATFORMS"] = "cpu"
     if smoke:
         # seconds-scale: small tensor, the two format rows the gate is
         # really about; "tuned"/"stream" stay out of the smoke tier
@@ -501,7 +505,11 @@ def run_serve_chaos(seed: int = 0, smoke: bool = True,
     # env.py): the restarted daemon re-adopts its jobs WITHOUT paying
     # the original's XLA compiles — single-device programs only, the
     # CPU-safe scope (see run_fleet_chaos)
-    env["SPLATT_COMPILE_CACHE"] = os.path.join(tmp, "xla_cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(tmp, "xla_cache")
+    # a CPU-only soak: the daemon is pinned to the CPU so it never
+    # contends with a parent that may hold the chip (one process per
+    # chip)
+    env["JAX_PLATFORMS"] = "cpu"
     try:
         # the NaN job's id sorts FIRST ("0" < "c" in the spool's
         # sorted-filename ingest order), so with one worker it is the
@@ -833,7 +841,10 @@ def run_fleet_chaos(seed: int = 0, smoke: bool = True,
         # the suite's own process must NOT set this (sharded CPU
         # executables corrupt the heap when deserialized — see
         # tests/conftest.py)
-        SPLATT_COMPILE_CACHE=os.path.join(tmp, "xla_cache"))
+        JAX_COMPILATION_CACHE_DIR=os.path.join(tmp, "xla_cache"),
+        # a CPU-only soak: several replica daemons share one host, so
+        # they are pinned to the CPU and never contend for the chip
+        JAX_PLATFORMS="cpu")
     # SPLATT_METRICS_PATH stays UNSET: fleet mode defaults each
     # replica's snapshot into <root>/fleet/metrics/<rid>.prom, which
     # is where the aggregator (and this soak's post-mortem) finds

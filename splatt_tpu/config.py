@@ -430,8 +430,8 @@ class Options:
     regularization: float = 0.0
     # Check convergence (and fetch the fit to host) every k iterations.
     # The fit is computed on device every sweep regardless; k > 1 only
-    # batches the host synchronization — on tunneled/remote accelerators
-    # a fetch costs ~10-100ms, dominating small iterations.  k=1 is the
+    # batches the host synchronization — each fetch is a device-to-host
+    # sync that stalls the next sweep's dispatch.  k=1 is the
     # reference semantics (src/cpd.c:357-370).
     fit_check_every: int = 1
     # RNG: None ≙ seed-from-time (src/opts.c RANDSEED default)

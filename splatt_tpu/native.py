@@ -20,22 +20,52 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-_SO_PATH = Path(__file__).resolve().parent / "_native.so"
 _SRC_PATH = Path(__file__).resolve().parent.parent / "native" / "splatt_native.cpp"
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
-def _build() -> bool:
+def _host_key() -> bytes:
+    """What a ``-march=native`` build depends on besides its source: the
+    architecture and the CPU's feature flags."""
+    import platform
+
+    flags = b""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith((b"flags", b"Features")):
+                    flags = line
+                    break
+    except OSError:
+        pass
+    return platform.machine().encode() + b"|" + flags
+
+
+def _so_path() -> Path:
+    """The library built from THIS source on THIS kind of host: the name
+    carries a hash of both, so a build copied from another machine (the
+    chip tool copies the tree as it stands) is never loaded here."""
+    import hashlib
+
+    h = hashlib.sha256(_SRC_PATH.read_bytes() + b"|" + _host_key())
+    return Path(__file__).resolve().parent / f"_native-{h.hexdigest()[:16]}.so"
+
+
+def _build(so_path: Path) -> bool:
+    # built beside, then renamed into place: concurrent processes never
+    # load a half-written library
+    tmp = so_path.with_name(f"{so_path.name}.{os.getpid()}.tmp")
     base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-            "-o", str(_SO_PATH), str(_SRC_PATH)]
+            "-o", str(tmp), str(_SRC_PATH)]
     # -march=native vectorizes the MTTKRP rank loops; retry without it
     # for toolchains that reject the flag
     for flags in (base[:2] + ["-march=native"] + base[2:], base):
         try:
             subprocess.run(flags, check=True, capture_output=True,
                            timeout=300)
+            os.replace(tmp, so_path)
             return True
         except (OSError, subprocess.SubprocessError):
             continue
@@ -48,14 +78,15 @@ def _load() -> Optional[ctypes.CDLL]:
         return _lib
     if _load_failed:
         return None
-    if not _SO_PATH.exists() or (
-            _SRC_PATH.exists()
-            and _SRC_PATH.stat().st_mtime > _SO_PATH.stat().st_mtime):
-        if not _SRC_PATH.exists() or not _build():
-            _load_failed = True
-            return None
+    if not _SRC_PATH.exists():
+        _load_failed = True
+        return None
+    so_path = _so_path()
+    if not so_path.exists() and not _build(so_path):
+        _load_failed = True
+        return None
     try:
-        lib = ctypes.CDLL(str(_SO_PATH))
+        lib = ctypes.CDLL(str(so_path))
     except OSError:
         _load_failed = True
         return None

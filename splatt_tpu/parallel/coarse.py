@@ -24,7 +24,7 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-from splatt_tpu.utils.env import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from splatt_tpu.config import Options, default_opts, resolve_dtype

@@ -42,7 +42,7 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from splatt_tpu.utils.env import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from splatt_tpu.config import Options, Verbosity, default_opts, resolve_dtype

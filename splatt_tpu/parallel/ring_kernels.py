@@ -41,10 +41,13 @@ full lifecycle diagram):
 - Step 0 opens with a neighbor barrier (``get_barrier_semaphore``) so
   no RDMA can land on a device that has not yet entered the kernel.
 
+On the chip the kernels are REFUSED for now (:func:`_refuse_on_chip`):
+they compile for a v5e:2x2, but the PR 21 four-chip smoke's first
+async-ring sweep never completed.
+
 Fallback ladder: the kernels only run on a real TPU backend
-(:func:`async_ring_supported`); everywhere else — CPU tests,
-interpret mode, jax builds without the RDMA primitives —
-:func:`async_ring_gather_rows` / :func:`async_blockwise_reduce_rows`
+(:func:`async_ring_supported`); everywhere else — CPU tests and
+interpret mode — :func:`async_ring_gather_rows` / :func:`async_blockwise_reduce_rows`
 delegate to the ``ppermute`` implementations in
 :mod:`splatt_tpu.parallel.ring`, so the ASYNC_RING comm strategy keeps
 *today's semantics bit-for-bit* off-TPU and tier-1 exercises the exact
@@ -64,6 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from splatt_tpu.ops.mttkrp import mxu_precision
 from splatt_tpu.utils import faults
 
 #: nnz rows processed per in-kernel chunk of the gather/reduce compute
@@ -81,21 +85,24 @@ def _pltpu():
 
 @functools.cache
 def async_ring_supported() -> bool:
-    """Whether the Pallas remote-copy ring kernels can run here: a real
-    TPU backend (interpret mode has no ICI) and a jax with the RDMA
-    primitives.  Everywhere else the ASYNC_RING strategy silently uses
-    the ppermute dataflow — same math, bit-for-bit — so selection never
-    needs to fail off-TPU."""
-    try:
-        if jax.default_backend() != "tpu":
-            return False
-        pltpu = _pltpu()
-        return (hasattr(pltpu, "make_async_remote_copy")
-                and hasattr(pltpu, "get_barrier_semaphore"))
-    # splint: ignore[SPL002] backend discovery off-accelerator: any
-    # failure to even ask means "not a TPU", which selects the fallback
-    except Exception:
-        return False
+    """Whether the Pallas remote-copy ring kernels would run here: only
+    on a real TPU backend (interpret mode has no ICI).  Everywhere else
+    the ASYNC_RING strategy uses the ppermute dataflow — same math,
+    bit-for-bit — so selection never needs to fail off-TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def _refuse_on_chip() -> None:
+    """The RDMA ring kernels compile for a v5e:2x2 but, run there, the
+    first sweep never completed (PR 21 four-chip smoke: the host waited
+    on the result until the call was killed).  Until that deadlock is
+    found, the chip refuses them loudly — a hang would hold the chips,
+    and a quiet ppermute stand-in would misreport what ran.  With
+    engine fallback on, the driver's comm ladder demotes async_ring to
+    the ppermute ring with a ``comm_fallback`` event."""
+    raise NotImplementedError(
+        "ASYNC_RING: the Pallas RDMA ring kernels hang on TPU (PR 21 "
+        "four-chip smoke); use --comm point2point or all2all")
 
 
 # -- kernel building blocks -------------------------------------------------
@@ -155,7 +162,7 @@ def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
                         ndev: int, axis: str, block: int, nnz_pad: int):
     """One device's whole gather ring (≙ mpi_update_rows streamed).
 
-    idx_div/idx_loc: (nnz_pad,) int32 — owner shard and within-block
+    idx_div/idx_loc: (1, nnz_pad) int32 — owner shard and within-block
     row of each local nonzero's request (step-independent; only the
     ownership mask changes per step; pad entries carry owner -1 and
     match no shard).  u0: (block, R) my factor block.  rows (out):
@@ -201,26 +208,26 @@ def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
     def chunk_body(c, _):
         lo = c * _NNZ_CHUNK
         # index streams live in HBM (ANY refs load only via DMA)
-        _stage(pltpu, idx_div_ref.at[pl.ds(lo, _NNZ_CHUNK)], div_vmem,
+        _stage(pltpu, idx_div_ref.at[:, pl.ds(lo, _NNZ_CHUNK)], div_vmem,
                local_sems.at[2])
-        _stage(pltpu, idx_loc_ref.at[pl.ds(lo, _NNZ_CHUNK)], loc_vmem,
+        _stage(pltpu, idx_loc_ref.at[:, pl.ds(lo, _NNZ_CHUNK)], loc_vmem,
                local_sems.at[2])
-        div = div_vmem[...]
-        loc = loc_vmem[...]
+        div = div_vmem[...]                  # (1, C): a row, so no
+        loc = loc_vmem[...]                  # in-kernel reshape
         mask = div == shard
         # one-hot row pick against the VMEM-resident block: the same
         # MXU-friendly contraction the single-chip engines use
-        # ((C, block) @ (block, R)).  Each nonzero matches exactly one
+        # ((block, C)ᵀ @ (block, R)).  Each nonzero matches exactly one
         # shard, so the cross-step accumulation only ever adds zeros —
         # bit-identical to a single gather.
         safe = jnp.where(mask, loc, 0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (_NNZ_CHUNK, block), 1)
-        onehot = ((safe[:, None] == iota)
-                  & mask[:, None]).astype(u_vmem.dtype)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (block, _NNZ_CHUNK), 0)
+        onehot = ((safe == iota) & mask).astype(u_vmem.dtype)
         picked = jax.lax.dot_general(
             onehot, u_vmem[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=rows_vmem.dtype)
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=rows_vmem.dtype,
+            precision=mxu_precision(u_vmem.dtype))
 
         @pl.when(s > 0)
         def _load():
@@ -327,23 +334,23 @@ def _ring_reduce_kernel(idx_div_ref, idx_loc_ref, prod_ref, out_ref,
 
     def chunk_body(c, _):
         lo = c * _NNZ_CHUNK
-        _stage(pltpu, idx_div_ref.at[pl.ds(lo, _NNZ_CHUNK)], div_vmem,
+        _stage(pltpu, idx_div_ref.at[:, pl.ds(lo, _NNZ_CHUNK)], div_vmem,
                local_sems.at[2])
-        _stage(pltpu, idx_loc_ref.at[pl.ds(lo, _NNZ_CHUNK)], loc_vmem,
+        _stage(pltpu, idx_loc_ref.at[:, pl.ds(lo, _NNZ_CHUNK)], loc_vmem,
                local_sems.at[2])
-        div = div_vmem[...]
-        loc = loc_vmem[...]
+        div = div_vmem[...]                  # (1, C): a row, so no
+        loc = loc_vmem[...]                  # in-kernel reshape
         _stage(pltpu, prod_ref.at[pl.ds(lo, _NNZ_CHUNK)], prod_vmem,
                local_sems.at[1])
         mask = div == j
         safe = jnp.where(mask, loc, 0)
         iota = jax.lax.broadcasted_iota(jnp.int32, (block, _NNZ_CHUNK), 0)
-        onehot = ((safe[None, :] == iota)
-                  & mask[None, :]).astype(acc_vmem.dtype)
+        onehot = ((safe == iota) & mask).astype(acc_vmem.dtype)
         part = jax.lax.dot_general(
             onehot, prod_vmem[...].astype(acc_vmem.dtype),
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=acc_vmem.dtype)
+            preferred_element_type=acc_vmem.dtype,
+            precision=mxu_precision(acc_vmem.dtype))
 
         @pl.when(c == 0)
         def _init():
@@ -418,24 +425,26 @@ def _pad_streams(idx: jax.Array, block: int):
 
 
 def _ring_compiler_params(collective_id: int):
-    from splatt_tpu.ops.pallas_kernels import _compiler_params
+    from jax.experimental.pallas import tpu as pltpu
 
-    params = _compiler_params()
-    try:
-        return type(params)(vmem_limit_bytes=params.vmem_limit_bytes,
-                            collective_id=collective_id,
-                            has_side_effects=True)
-    except TypeError:
-        # older jax CompilerParams without these fields: the barrier
-        # semaphore falls back to its default id
-        return params
+    from splatt_tpu.ops.pallas_kernels import _vmem_limit
+
+    return pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(),
+                                collective_id=collective_id,
+                                has_side_effects=True)
 
 
 def _gather_pallas(U_l: jax.Array, idx: jax.Array, axis: str,
                    ndev: int) -> jax.Array:
     """TPU path of :func:`async_ring_gather_rows`."""
+    from splatt_tpu.utils.env import ceil_to
+
     pltpu = _pltpu()
-    block, R = int(U_l.shape[0]), int(U_l.shape[1])
+    block, R0 = int(U_l.shape[0]), int(U_l.shape[1])
+    # rank padded to whole 128-lane tiles: Mosaic slices the HBM comm
+    # buffer per slot only along tile-aligned lanes
+    R = ceil_to(R0, 128)
+    U_l = jnp.pad(U_l, ((0, 0), (0, R - R0)))
     div, loc, nnz_pad = _pad_streams(idx, block)
     kernel = functools.partial(_ring_gather_kernel, ndev=ndev, axis=axis,
                                block=block, nnz_pad=nnz_pad)
@@ -450,16 +459,16 @@ def _gather_pallas(U_l: jax.Array, idx: jax.Array, axis: str,
         scratch_shapes=(
             pltpu.VMEM((block, R), U_l.dtype),
             pltpu.VMEM((_NNZ_CHUNK, R), U_l.dtype),
-            pltpu.VMEM((_NNZ_CHUNK,), jnp.int32),
-            pltpu.VMEM((_NNZ_CHUNK,), jnp.int32),
+            pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
+            pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.SemaphoreType.DMA((3,)),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.REGULAR,
         ),
         compiler_params=_ring_compiler_params(7),
-    )(div, loc, U_l)
-    return rows[:int(idx.shape[0])]
+    )(div[None, :], loc[None, :], U_l)
+    return rows[:int(idx.shape[0]), :R0]
 
 
 def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
@@ -468,11 +477,14 @@ def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
     pltpu = _pltpu()
     from splatt_tpu.ops.mttkrp import acc_dtype
 
-    R = int(prod.shape[1])
+    from splatt_tpu.utils.env import ceil_to
+
+    R0 = int(prod.shape[1])
+    R = ceil_to(R0, 128)     # whole lane tiles, as in _gather_pallas
     out_dtype = acc_dtype(prod.dtype)
     div, loc, nnz_pad = _pad_streams(idx, block)
     n = int(prod.shape[0])
-    prod_pad = jnp.pad(prod, ((0, nnz_pad - n), (0, 0)))
+    prod_pad = jnp.pad(prod, ((0, nnz_pad - n), (0, R - R0)))
     kernel = functools.partial(_ring_reduce_kernel, ndev=ndev, axis=axis,
                                block=block, nnz_pad=nnz_pad)
     out, _, _ = pl.pallas_call(
@@ -489,16 +501,16 @@ def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
             pltpu.VMEM((block, R), out_dtype),
             pltpu.VMEM((block, R), out_dtype),
             pltpu.VMEM((_NNZ_CHUNK, R), prod.dtype),
-            pltpu.VMEM((_NNZ_CHUNK,), jnp.int32),
-            pltpu.VMEM((_NNZ_CHUNK,), jnp.int32),
+            pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
+            pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.SemaphoreType.DMA((3,)),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.REGULAR,
         ),
         compiler_params=_ring_compiler_params(8),
-    )(div, loc, prod_pad)
-    return out
+    )(div[None, :], loc[None, :], prod_pad)
+    return out[:, :R0]
 
 
 # -- public entry points (what make_sharded_sweep calls) --------------------
@@ -518,6 +530,7 @@ def async_ring_gather_rows(U_l: jax.Array, idx: jax.Array, axis: str,
     """
     faults.maybe_fail("comm.ring_exchange")
     if ndev >= 2 and async_ring_supported():
+        _refuse_on_chip()
         return _gather_pallas(U_l, idx, axis, ndev)
     # interpret/CPU fallback: today's ppermute semantics, bit-for-bit
     # (docs/ring.md fallback ladder) — tier-1 exercises this dataflow
@@ -535,6 +548,7 @@ def async_blockwise_reduce_rows(prod: jax.Array, idx: jax.Array, axis: str,
     with the POINT2POINT path."""
     faults.maybe_fail("comm.ring_exchange")
     if ndev >= 2 and async_ring_supported():
+        _refuse_on_chip()
         return _reduce_pallas(prod, idx, axis, ndev, block)
     from splatt_tpu.parallel.ring import blockwise_reduce_rows
 

@@ -36,8 +36,8 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from splatt_tpu.utils.env import shard_map
 
 from splatt_tpu.config import (CommPattern, Options, Verbosity, default_opts,
                                resolve_comm_pattern, resolve_dtype)

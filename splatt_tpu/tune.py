@@ -655,7 +655,7 @@ def tune(tt, rank: int, opts=None, modes: Optional[Sequence[int]] = None,
     unknown failures skip the candidate for this session only, and a
     measurement that blows the deadline watchdog (TIMEOUT,
     docs/guarded-als.md) is skipped this session but never persisted —
-    a wedged relay today must not blacklist a healthy candidate
+    a wedged service today must not blacklist a healthy candidate
     forever.  A mode where every candidate fails gets NO plan —
     dispatch keeps the heuristic chain, recorded as a
     ``tuner_degraded`` run-report event.

@@ -43,7 +43,7 @@ Chaos schedules (docs/guarded-als.md)
     a schedule and asserts the soak invariant.
 
 Sites used by the production code:
-    - ``probe_compile``          — the capability-probe remote compile
+    - ``probe_compile``          — the capability-probe compile
     - ``engine.<name>``          — an MTTKRP dispatch engine at call
       time (e.g. ``engine.fused_t``, ``engine.xla_scan``)
     - ``checkpoint_write``       — raise during the checkpoint save
@@ -132,7 +132,7 @@ SLOW_DELAY_S = 1.0
 #: (Tests may arm ad-hoc sites to test the harness itself; those need
 #: no declaration.)
 SITES = {
-    "probe_compile": "the capability-probe remote compile "
+    "probe_compile": "the capability-probe compile "
                      "(ops/pallas_kernels.py)",
     "engine.*": "an MTTKRP dispatch engine at call time, e.g. "
                 "engine.fused_t / engine.xla_scan (ops/mttkrp.py); "
@@ -293,14 +293,14 @@ SITES = {
 def _canned(kind: str, site: str) -> Exception:
     if kind == "http500":
         return RuntimeError(
-            f"XLA:TPU compile failed: HTTP code 500 from remote compile "
+            f"XLA:TPU compile failed: HTTP code 500 from the compile "
             f"service (injected fault at {site})")
     if kind == "internal":
         return RuntimeError(
             f"INTERNAL: injected transient service failure at {site}")
     if kind == "unavailable":
         return RuntimeError(
-            f"UNAVAILABLE: injected relay failure at {site}")
+            f"UNAVAILABLE: injected service failure at {site}")
     if kind == "timeout":
         return TimeoutError(f"injected deadline expiry at {site}")
     if kind == "oom":

@@ -601,12 +601,17 @@ def test_flops_model_and_roofline_verdict():
     # sparse modes keep the per-nonzero Hadamard-chain count
     sparse_flops = mttkrp_flops("stream", hyb, rank, 1)
     assert sparse_flops >= 2.0 * tt.nnz * rank * (tt.nmodes - 1)
-    # the roofline verdict classifies on CPU through the nominal peaks
-    v = roofline_verdict(1e9, 1e9)
+    # the roofline verdict needs a device's peaks: none off-TPU, the
+    # table's row when one is given
+    from splatt_tpu.devices import DEVICE_SPECS
+
+    assert roofline_verdict(1e9, 1e9) is None
+    v5e = DEVICE_SPECS["TPU v5 lite"]
+    v = roofline_verdict(1e9, 1e9, spec=v5e)
     assert set(v) == {"intensity", "ridge", "bound"}
-    assert v["bound"] in ("compute", "memory") and v["ridge"] > 0
-    assert roofline_verdict(1.0, 1e12)["bound"] == "compute"
-    assert roofline_verdict(1e12, 1.0)["bound"] == "memory"
+    assert v["ridge"] == round(v5e.mxu_gflops / v5e.hbm_gbs, 3)
+    assert roofline_verdict(1.0, 1e12, spec=v5e)["bound"] == "compute"
+    assert roofline_verdict(1e12, 1.0, spec=v5e)["bound"] == "memory"
 
 
 # -- registries (splint stays at zero) ---------------------------------------

@@ -87,11 +87,12 @@ def test_public_mttkrp_forced_pallas():
     np.testing.assert_allclose(np.asarray(got), want, atol=TOL)
 
 
-def test_fused_mttkrp_kernel_direct():
-    """Direct fused-kernel calls (sorted partials + privatized totals)
-    vs the numpy brute force."""
+def test_unfused_pallas_engine_direct():
+    """The chip's main-path engine (scan over block chunks: XLA gather
+    + Hadamard, Mosaic one-hot reduce) — sorted partials + privatized
+    totals vs the numpy brute force."""
     from splatt_tpu.blocked import build_layout
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp
+    from splatt_tpu.ops.mttkrp import _scan_fused
 
     tt = gen.fixture_tensor("med")
     factors = make_factors(tt.dims)
@@ -99,19 +100,22 @@ def test_fused_mttkrp_kernel_direct():
         lay = build_layout(tt, mode, block=128, val_dtype=np.float64)
         want = np_mttkrp(tt, factors, mode)
         S = lay.seg_width
-        parts = fused_mttkrp(lay, factors, mode, S, accumulate=False,
-                             interpret=True)
+        # a small step target forces several scan steps and a padded tail
+        parts = _scan_fused(lay, factors, mode, S, accumulate=False,
+                            target_elems=3 * 128 * 8, pallas=True,
+                            interpret=True)
         idx = (np.asarray(lay.row_start)[:, None] + np.arange(S)).reshape(-1)
         out = np.zeros((tt.dims[mode] + S + 1, factors[0].shape[1]))
         np.add.at(out, idx, np.asarray(parts).reshape(-1, factors[0].shape[1]))
         np.testing.assert_allclose(out[:tt.dims[mode]], want, atol=TOL,
-                                   err_msg=f"fused sorted mode={mode}")
+                                   err_msg=f"unfused sorted mode={mode}")
         W = -(-(tt.dims[mode] + 1) // 8) * 8
-        tot = fused_mttkrp(lay, factors, mode, W, accumulate=True,
-                           interpret=True)
+        tot = _scan_fused(lay, factors, mode, W, accumulate=True,
+                          target_elems=3 * 128 * 8, pallas=True,
+                          interpret=True)
         np.testing.assert_allclose(np.asarray(tot)[:tt.dims[mode]], want,
                                    atol=TOL,
-                                   err_msg=f"fused privatized mode={mode}")
+                                   err_msg=f"unfused privatized mode={mode}")
 
 
 def test_fused_tg_kernel_direct():
@@ -191,13 +195,13 @@ def test_fused_tg_bf16_accumulates_f32():
 
 
 def test_fused_vmem_gate():
-    from splatt_tpu.ops.pallas_kernels import fused_vmem_ok
+    from splatt_tpu.ops.pallas_kernels import fused_t_vmem_ok
 
     small = [jnp.zeros((64, 16)) for _ in range(3)]
-    assert fused_vmem_ok(small, 0, 64, 128)
+    assert fused_t_vmem_ok(small, 0, 64, 128)
     huge = [jax.ShapeDtypeStruct((4_000_000, 64), jnp.float32)
             for _ in range(3)]
-    assert not fused_vmem_ok(huge, 0, 64, 4096)
+    assert not fused_t_vmem_ok(huge, 0, 64, 4096)
 
 
 def test_pallas_unfused_fallback_matches(monkeypatch):
@@ -210,7 +214,6 @@ def test_pallas_unfused_fallback_matches(monkeypatch):
                    val_dtype=np.float64)
     bs = BlockedSparse.from_coo(tt, opts)
     factors = make_factors(tt.dims)
-    monkeypatch.setattr(pk, "fused_vmem_ok", lambda *a, **k: False)
     monkeypatch.setattr(pk, "fused_t_vmem_ok", lambda *a, **k: False)
     monkeypatch.setattr(pk, "fused_tg_vmem_ok", lambda *a, **k: False)
     # identical statics/avals were traced earlier in this file with the
@@ -229,16 +232,17 @@ def test_pallas_unfused_fallback_matches(monkeypatch):
                                    err_msg=f"unfused fallback mode={mode}")
 
 
-def test_fused_bf16_accumulates_f32():
+def test_unfused_pallas_bf16_accumulates_f32():
     from splatt_tpu.blocked import build_layout
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp
+    from splatt_tpu.ops.mttkrp import _scan_fused
 
     tt = gen.fixture_tensor("med")
     factors = [jnp.asarray(np.asarray(f), dtype=jnp.bfloat16)
                for f in make_factors(tt.dims)]
     lay = build_layout(tt, 0, block=128, val_dtype=jnp.bfloat16)
     W = -(-(tt.dims[0] + 1) // 8) * 8
-    tot = fused_mttkrp(lay, factors, 0, W, accumulate=True, interpret=True)
+    tot = _scan_fused(lay, factors, 0, W, accumulate=True, pallas=True,
+                      interpret=True)
     assert tot.dtype == jnp.float32
     want = np_mttkrp(tt, [np.asarray(f, np.float64) for f in factors], 0)
     np.testing.assert_allclose(np.asarray(tot)[:tt.dims[0]], want, atol=0.6,

@@ -62,10 +62,10 @@ def _opts(**kw):
     ("Unsupported lowering of take_along_axis",
      FailureClass.DETERMINISTIC),
     ("NotImplementedError: dynamic gather", FailureClass.DETERMINISTIC),
-    # transient: relay/service failures, never persisted
+    # transient: service failures, never persisted
     ("XLA compile: HTTP code 500 from service", FailureClass.TRANSIENT),
     ("HTTP code 503: service unavailable", FailureClass.TRANSIENT),
-    ("INTERNAL: stream reset by relay", FailureClass.TRANSIENT),
+    ("INTERNAL: stream reset by the service", FailureClass.TRANSIENT),
     ("UNAVAILABLE: TPU backend setup error", FailureClass.TRANSIENT),
     ("DEADLINE_EXCEEDED: compile RPC", FailureClass.TRANSIENT),
     ("OSError: Connection reset by peer", FailureClass.TRANSIENT),
@@ -94,7 +94,7 @@ def test_classify_precedence():
 
 
 def test_classify_accepts_exceptions():
-    e = RuntimeError("UNAVAILABLE: relay dropped")
+    e = RuntimeError("UNAVAILABLE: service dropped")
     assert classify_failure(e) is FailureClass.TRANSIENT
 
 
@@ -190,27 +190,42 @@ def test_faults_env_var(monkeypatch):
     faults.maybe_fail("unarmed_site")
 
 
-def test_apply_compile_cache_knob(monkeypatch):
-    """SPLATT_COMPILE_CACHE points jax's persistent executable cache
-    at the named directory with the caching floors zeroed (fleet
-    replicas share many small same-regime compiles); unset leaves the
-    config untouched.  Config only — executing deserialized entries is
-    the chaos soaks' job (and is CPU-unsafe for sharded programs, see
-    utils/env.py)."""
+@pytest.mark.parametrize("case", ["env_dir", "checkout_default",
+                                  "virtual_cpu_devices"])
+def test_apply_compile_cache(monkeypatch, case):
+    """The persistent compile cache (utils/env.py): JAX_COMPILATION_CACHE_DIR
+    when set, and then nothing is set in code; else the fixed in-checkout
+    <repo>/.jax_cache; none under virtual CPU devices (deserialized
+    sharded CPU executables corrupt the heap).  Config only —
+    executing deserialized entries is the chaos soaks' job."""
+    import pathlib
+
     import jax
 
     from splatt_tpu.utils.env import apply_compile_cache
 
+    repo = pathlib.Path(__file__).resolve().parents[1]
     prior = jax.config.jax_compilation_cache_dir
     prior_t = jax.config.jax_persistent_cache_min_compile_time_secs
     prior_b = jax.config.jax_persistent_cache_min_entry_size_bytes
+    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
-        monkeypatch.delenv("SPLATT_COMPILE_CACHE", raising=False)
-        apply_compile_cache()   # unset: a no-op
-        assert jax.config.jax_compilation_cache_dir == prior
-        monkeypatch.setenv("SPLATT_COMPILE_CACHE", "/tmp/xc-test")
-        apply_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == "/tmp/xc-test"
+        if case == "env_dir":
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/xc-test")
+            assert apply_compile_cache() == "/tmp/xc-test"
+            # JAX reads the variable itself: no directory set in code
+            assert jax.config.jax_compilation_cache_dir == prior
+        elif case == "checkout_default":
+            want = str(repo / ".jax_cache")
+            assert apply_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            monkeypatch.setenv(
+                "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+            assert apply_compile_cache() is None
+            assert jax.config.jax_compilation_cache_dir == prior
+            return
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
         assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
     finally:
@@ -219,6 +234,15 @@ def test_apply_compile_cache_knob(monkeypatch):
             "jax_persistent_cache_min_compile_time_secs", prior_t)
         jax.config.update(
             "jax_persistent_cache_min_entry_size_bytes", prior_b)
+
+
+def test_compile_cache_dir_is_gitignored():
+    """The default cache path lives in the checkout and git ignores it."""
+    import pathlib
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    lines = (repo / ".gitignore").read_text().split()
+    assert ".jax_cache/" in lines and "chiprun_out/" in lines
 
 
 def test_faults_kinds_map_to_taxonomy():
@@ -433,7 +457,7 @@ def test_injected_compile_500_leaves_no_persisted_rejection(tmp_path,
     text = cache.read_text()
     assert "compile_failed" not in text
     assert json.loads(text)  # still valid JSON
-    # relay recovers within the retry budget: proven in-process
+    # the service recovers within the retry budget: proven in-process
     pk.PROBE_STATES.clear()
     with faults.inject("probe_compile", "http500", times=1):
         assert pk._probe_compiles(None, "testk2", "ck1", 4096) is True

@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from splatt_tpu.utils.env import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 from functools import partial
 

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from splatt_tpu import resilience
@@ -42,7 +43,7 @@ from splatt_tpu.parallel.sharded import (comm_chain, measure_ring_overlap,
                                          shard_factors, shard_nnz,
                                          sharded_cpd_als)
 from splatt_tpu.utils import faults
-from splatt_tpu.utils.env import ceil_to, shard_map
+from splatt_tpu.utils.env import ceil_to
 from tests import gen
 
 
@@ -239,6 +240,33 @@ def test_demoted_comm_engine_skipped_next_run():
     # not re-probed and re-failed
     assert len(resilience.run_report().events("comm_fallback")) == 1
     assert np.isfinite(float(out.fit))
+
+
+@pytest.mark.parametrize("fallback", [True, False])
+def test_async_ring_refused_on_chip(monkeypatch, fallback):
+    """On a TPU the RDMA ring kernels are refused (they hung the PR 21
+    four-chip smoke): never a hang, never a quiet ppermute stand-in —
+    a loud NotImplementedError, which the comm ladder turns into a
+    comm_fallback to the ppermute ring when fallback is on."""
+    import splatt_tpu.parallel.ring_kernels as rk
+
+    tt = gen.fixture_tensor("med")
+    mesh = make_mesh(n_devices=8)
+    init = init_factors(tt.dims, 5, 42, dtype=jnp.float64)
+    clean = _run(CommPattern.POINT2POINT, mesh, tt, init)
+    resilience.run_report().clear()
+    resilience.reset_demotions()
+    monkeypatch.setattr(rk, "async_ring_supported", lambda: True)
+    if not fallback:
+        with pytest.raises(NotImplementedError, match="hang on TPU"):
+            _run(CommPattern.ASYNC_RING, mesh, tt, init,
+                 opts_kw=dict(engine_fallback=False))
+        return
+    out = _run(CommPattern.ASYNC_RING, mesh, tt, init)
+    evs = resilience.run_report().events("comm_fallback")
+    assert [(e["strategy"], e["fallback_to"]) for e in evs] \
+        == [("async_ring", "ring")]
+    assert float(out.fit) == float(clean.fit)
 
 
 def test_comm_fallback_disabled_fails_loudly():

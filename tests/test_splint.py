@@ -1308,7 +1308,7 @@ def test_config_matches_pyproject():
     assert "tile_packing" in cfg.tile_pack_helpers
     assert int(cfg.vmem_budget_mib) > 0
     gate_map = dict(e.split("=") for e in cfg.vmem_gate_map)
-    assert gate_map["fused_mttkrp"] == "fused_vmem_ok"
+    assert gate_map["fused_mttkrp_t"] == "fused_t_vmem_ok"
     assert "_tuned_plan_for" in cfg.plan_match_functions
     # SPL005 joined the zero-rules in the v5 burn-down
     assert "SPL005" in cfg.zero_rules
@@ -1385,14 +1385,14 @@ def test_spl026_fires_when_gate_consult_dropped(tmp_path):
     """Short-circuiting the fused_t dispatch gate — the kernel runs
     whether or not its block plan fits VMEM — must trip SPL026's
     registry leg: the declared gate is never consulted."""
-    anchor = ('    if pallas and live("fused_t") and '
+    anchor = ('    if gather and live("fused_t") and '
               "fused_t_vmem_ok(factors, mode,")
 
     def mutate(src):
         assert anchor in src, "mttkrp.py fused_t gate anchor drifted"
         return src.replace(
             anchor,
-            '    if pallas and live("fused_t") and '
+            '    if gather and live("fused_t") and '
             "(lambda *a: True)(factors, mode,", 1)
 
     cfg = _copy_package_tree(tmp_path, "splatt_tpu/ops/mttkrp.py", mutate)

@@ -220,7 +220,7 @@ def test_transient_failure_is_retried_in_place(monkeypatch):
     def flaky(layout, factors, mode, path, impl, engine, st, **kw):
         calls["n"] += 1
         if calls["n"] < 3:
-            raise RuntimeError("XLA compile: HTTP code 500 from relay")
+            raise RuntimeError("XLA compile: HTTP code 500 from the compile service")
         return 0.001
 
     monkeypatch.setattr(tune, "_measure_candidate", flaky)
