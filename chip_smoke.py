@@ -8,6 +8,8 @@ The one-chip run is what ``python -m splatt_tpu.cli cpd`` does —
 FROSTT NELL-2's shape (12092 x 9184 x 28818, 76,879,419 nonzeros, f32
 values, made from ``--seed`` on the host), at rank 50, allmode layouts,
 default Options and 5 ALS iterations, with the Pallas MTTKRP engines.
+The tensor has a planted rank-50 structure (:func:`nell2_tensor`), so
+the fit climbs well above zero and a wrong sweep shows in it.
 It fails (nonzero exit, no result line) unless:
 
 - JAX's first device is a TPU;
@@ -17,14 +19,17 @@ It fails (nonzero exit, no result line) unless:
 - the engine that ran in every mode is the Pallas engine the plan
   named, with no runtime demotion and no capability probe verdict other
   than "ok" (engine fallback is off);
-- every iteration's fit is finite, and the fit does not fall after
-  iteration 2.
+- every iteration's fit is finite, the fit does not fall after
+  iteration 2, and the final fit reaches FIT_FLOOR.
 
-``--four-chips`` runs only the medium-grain grid, the fine-grain
-decomposition (all2all) and the fine-grain async ring on a 4-device
-mesh at the same tensor, seed and iterations, plus the one-chip
-``cpd_als`` they are compared with.  Their final fits must agree with
-it within 1e-4, and every device must hold its own shards.
+``--four-chips`` runs only the medium-grain grid and the fine-grain
+decomposition (all2all) on a 4-device mesh at the same tensor, seed
+and iterations, plus the one-chip ``cpd_als`` they are compared with.
+Their final fits must agree with it within 1e-4, and every device must
+hold its own shards.  Then the async ring's two RDMA exchanges must
+match numpy on a small input within a short deadline, and an
+async-ring CPD must be refused loudly: at the 20M cut it finished no
+iteration in 359 s (PR 21), so the library refuses it on TPU.
 
 Times printed here are smoke readings, not benchmark numbers.  The last
 line of stdout is ``{"ok": true, "device": {...}}`` and nothing else.
@@ -50,8 +55,13 @@ RTOL = 2e-5
 FIT_AGREE = 1e-4
 #: the whole smoke, compilation included, ends within this
 DEADLINE_S = 1150.0
-#: f32 rounding of the fit itself (1 - residual/norm) near fit 0
+#: the RDMA ring precheck (small input) ends within this
+RING_CHECK_S = 120.0
+#: f32 rounding of the fit itself (1 - residual/norm)
 FIT_NOISE = 1e-5
+#: 5 iterations on the planted tensor reach fit 0.64-0.72 (CPU runs at
+#: 1M-10M nonzeros, PR 21); a broken sweep stays near 0
+FIT_FLOOR = 0.3
 
 
 class SmokeFailure(Exception):
@@ -68,29 +78,53 @@ def check(cond: bool, msg: str) -> None:
 
 
 def nell2_tensor(nnz: int, seed: int):
-    """A NELL-2-shaped COO tensor: uniform coordinates, f32 values in
-    (0, 1], made in bulk on the host from `seed`."""
+    """A NELL-2-shaped COO tensor with a planted rank-RANK structure,
+    made in bulk on the host from `seed`.
+
+    Component r is a dense sub-cube: the outer product of three
+    positive vectors (values in [0.5, 1.5)) over disjoint row sets, one
+    per mode; the sub-cubes are sized so that RANK of them hold `nnz`
+    nonzeros, and the last is cut to make the count exact.  Each row
+    set is spread evenly over its mode, so blocks span a few rows as on
+    NELL-2 (about 13k nonzeros per row here, 2.7k-8.4k there), and
+    rows outside every set stay empty.  The tensor is exactly rank
+    RANK but for the cut, so ALS climbs towards fit 1.
+    """
     from splatt_tpu.coo import SparseTensor
 
     rng = np.random.default_rng(seed)
-    inds = np.empty((len(NELL2_DIMS), nnz), dtype=np.int64)
-    for m, d in enumerate(NELL2_DIMS):
-        inds[m] = rng.integers(0, d, size=nnz, dtype=np.int64)
-    vals = (1.0 - rng.random(nnz, dtype=np.float32)).astype(np.float32)
-    return SparseTensor(inds, vals, NELL2_DIMS)
+    per = nnz / RANK
+    n0 = n1 = math.ceil(per ** (1 / 3))
+    sizes = (n0, n1, math.ceil(per / (n0 * n1)))
+    cell = np.arange(sizes[0] * sizes[1] * sizes[2])
+    local = (cell // (sizes[1] * sizes[2]), (cell // sizes[2]) % sizes[1],
+             cell % sizes[2])
+    inds = np.empty((len(NELL2_DIMS), RANK * cell.size), dtype=np.int64)
+    vals = np.ones(RANK * cell.size, dtype=np.float32)
+    for m, (d, n) in enumerate(zip(NELL2_DIMS, sizes)):
+        rows = (rng.permutation(RANK * n) * d // (RANK * n)).reshape(RANK, n)
+        vec = rng.uniform(0.5, 1.5, (RANK, n)).astype(np.float32)
+        inds[m] = rows[:, local[m]].ravel()
+        vals *= vec[:, local[m]].ravel()
+    return SparseTensor(inds[:, :nnz], vals[:nnz], NELL2_DIMS)
 
 
 def reference_rows(tt, factors, mode: int, rows: np.ndarray) -> np.ndarray:
-    """float64 numpy MTTKRP of `mode`, restricted to output `rows`."""
+    """float64 numpy MTTKRP of `mode`, restricted to the sorted output
+    `rows`: one bincount per rank column, so memory stays at a few
+    nonzero-length vectors."""
     sel = np.isin(tt.inds[mode], rows)
     inds = tt.inds[:, sel]
-    prod = tt.vals[sel].astype(np.float64)[:, None]
-    for k, U in enumerate(factors):
-        if k != mode:
-            prod = prod * U[inds[k]]
-    out = np.zeros((tt.dims[mode], prod.shape[1]))
-    np.add.at(out, inds[mode], prod)
-    return out[rows]
+    vals = tt.vals[sel].astype(np.float64)
+    out_row = np.searchsorted(rows, inds[mode])
+    others = [k for k in range(tt.nmodes) if k != mode]
+    out = np.empty((len(rows), factors[0].shape[1]))
+    for r in range(out.shape[1]):
+        w = vals.copy()
+        for k in others:
+            w *= factors[k][inds[k], r]
+        out[:, r] = np.bincount(out_row, weights=w, minlength=len(rows))
+    return out
 
 
 def check_mttkrp(tt, bs, factors, seed: int) -> dict:
@@ -112,15 +146,20 @@ def check_mttkrp(tt, bs, factors, seed: int) -> dict:
         t0 = time.perf_counter()
         got = jax.block_until_ready(mttkrp(bs, factors, m))
         secs = time.perf_counter() - t0
-        rows = np.sort(rng.choice(tt.dims[m], size=min(SAMPLE_ROWS,
-                                                        tt.dims[m]),
-                                  replace=False))
+        got = np.asarray(got, dtype=np.float64)
+        held = np.bincount(tt.inds[m], minlength=tt.dims[m]) > 0
+        check(not got[~held].any(),
+              f"mode {m}: MTTKRP rows of empty slices are not zero")
+        nonempty = np.flatnonzero(held)
+        rows = np.sort(rng.choice(nonempty, replace=False,
+                                  size=min(SAMPLE_ROWS, nonempty.size)))
         want = reference_rows(tt, host, m, rows)
-        got = np.asarray(got, dtype=np.float64)[rows]
+        got = got[rows]
         err = float(np.max(np.abs(got - want) / np.maximum(np.abs(want),
                                                            1e-30)))
         say(f"mode {m}: plan {plan.path}/{plan.engine}; MTTKRP vs numpy "
-            f"on {len(rows)} rows: max rel err {err:.3e} (rtol {RTOL}); "
+            f"on {len(rows)} held rows: max rel err {err:.3e} (rtol "
+            f"{RTOL}), {int((~held).sum())} empty rows zero; "
             f"first call {secs:.3f}s incl. compile")
         check(np.allclose(got, want, rtol=RTOL, atol=0.0),
               f"mode {m} MTTKRP differs from the host reference "
@@ -128,8 +167,7 @@ def check_mttkrp(tt, bs, factors, seed: int) -> dict:
     return plans
 
 
-PALLAS_ENGINES = ("fused_v2", "fused_t", "fused_tg", "unfused_pallas",
-                  "fused_dense")
+PALLAS_ENGINES = ("fused_t", "fused_tg", "unfused_pallas", "fused_dense")
 
 
 def check_engines(plans: dict) -> None:
@@ -168,6 +206,9 @@ def check_fits(fits: list, iters: int) -> None:
     for i in range(2, len(vals)):
         check(vals[i] >= vals[i - 1] - FIT_NOISE,
               f"fit fell after iteration 2: {vals}")
+    check(vals[-1] >= FIT_FLOOR,
+          f"final fit {vals[-1]:.6f} is below {FIT_FLOOR} on the planted "
+          f"rank-{RANK} tensor")
 
 
 def one_chip(args) -> None:
@@ -265,7 +306,49 @@ class PlacementLog:
         return per
 
 
-def four_chips(args) -> None:
+def check_ring_exchange(ndev: int) -> None:
+    """The async ring's two RDMA exchanges alone, on a small input, vs
+    numpy: the gather must pick exactly the requested rows and the
+    reduce must sum every chip's partials into the owner's block."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from splatt_tpu.parallel.mesh import make_mesh
+    from splatt_tpu.parallel.ring_kernels import (
+        async_blockwise_reduce_rows, async_ring_gather_rows)
+
+    block, n = 256, 50_000
+    mesh = make_mesh(ndev, axis_names=("x",))
+    rng = np.random.default_rng(1)
+    U = rng.standard_normal((ndev * block, RANK)).astype(np.float32)
+    prod = rng.standard_normal((ndev * n, RANK)).astype(np.float32)
+    idx = rng.integers(0, ndev * block, size=ndev * n).astype(np.int32)
+
+    def run(body, x):
+        fn = jax.jit(shard_map(body, mesh=mesh,
+                               in_specs=(P("x", None), P("x")),
+                               out_specs=P("x", None), check_vma=False))
+        return np.asarray(jax.block_until_ready(fn(x, idx)))
+
+    t0 = time.perf_counter()
+    got = run(lambda u, i: async_ring_gather_rows(u, i, "x", ndev), U)
+    gerr = float(np.max(np.abs(got - U[idx]) / np.abs(U[idx])))
+    check(gerr <= 1e-6, f"async ring gather differs from numpy (max rel "
+          f"err {gerr:.3e})")
+    got = run(lambda p, i: async_blockwise_reduce_rows(p, i, "x", ndev,
+                                                       block), prod)
+    want = np.zeros((ndev * block, RANK))
+    np.add.at(want, idx, prod.astype(np.float64))
+    err = float(np.max(np.abs(got - want)))
+    check(np.allclose(got, want, rtol=1e-4, atol=1e-3),
+          f"async ring reduce differs from numpy (max abs err {err:.3e})")
+    say(f"async ring RDMA exchanges match numpy (gather max rel err "
+        f"{gerr:.3e}, reduce max abs err {err:.3e}) in "
+        f"{time.perf_counter() - t0:.1f}s incl. compile")
+
+
+def four_chips(args, dog) -> None:
     """The distributed CPDs on a 4-device mesh vs one-chip cpd_als."""
     import jax
 
@@ -290,12 +373,11 @@ def four_chips(args) -> None:
                         init=init).fit)
     say(f"one-chip cpd_als: final fit {ref:.8f} "
         f"({time.perf_counter() - t0:.1f}s incl. set-up)")
+    check(ref >= FIT_FLOOR, f"one-chip final fit {ref:.8f} is below "
+          f"{FIT_FLOOR} on the planted rank-{RANK} tensor")
 
-    check(async_ring_supported(), "the async ring kernels are not "
-          "supported here: the run would use the ppermute dataflow")
     runs = [("medium", Decomposition.MEDIUM, None),
-            ("fine", Decomposition.FINE, CommPattern.ALL2ALL),
-            ("fine+async_ring", Decomposition.FINE, CommPattern.ASYNC_RING)]
+            ("fine", Decomposition.FINE, CommPattern.ALL2ALL)]
     for label, decomp, comm in runs:
         opts = Options(decomposition=decomp, comm_pattern=comm, **base)
         resilience.run_report().clear()
@@ -325,22 +407,50 @@ def four_chips(args) -> None:
         say(f"device {d.id}: peak_bytes_in_use {peak}")
         check(peak > 0, f"device {d.id} never held anything")
 
+    check(async_ring_supported(), "the async ring kernels are not "
+          "supported here: the exchanges would use the ppermute dataflow")
+    dog.phase("async ring RDMA exchanges", RING_CHECK_S)
+    check_ring_exchange(ndev)
+    dog.phase("async ring refusal")
+    opts = Options(decomposition=Decomposition.FINE,
+                   comm_pattern=CommPattern.ASYNC_RING, **base)
+    try:
+        distributed_cpd_als(tt, RANK, opts=opts, init=init)
+    except NotImplementedError as e:
+        say(f"async ring CPD refused loudly: {str(e)[:120]}")
+    else:
+        raise SmokeFailure("the async ring CPD was not refused on TPU")
 
-def watchdog(seconds: float) -> None:
-    """Fail the smoke, and end the process, once `seconds` have passed:
-    a device call that never returns (a hung collective) must not hold
-    the chip until an outer limit kills it."""
-    import os
-    import threading
 
-    def fire():
-        print(f"chip_smoke: FAIL: deadline of {seconds:g}s passed",
-              file=sys.stderr, flush=True)
-        os._exit(3)
+class Watchdog:
+    """Fails the smoke, and ends the process, when the whole run or the
+    current phase outlasts its deadline: a device call that never
+    returns (a hung collective) must not hold the chip until an outer
+    limit kills it."""
 
-    t = threading.Timer(seconds, fire)
-    t.daemon = True
-    t.start()
+    def __init__(self, seconds: float):
+        self.end = time.monotonic() + seconds
+        self.timer = None
+        self.phase("smoke")
+
+    def phase(self, label: str, seconds: float = math.inf) -> None:
+        import threading
+
+        if self.timer is not None:
+            self.timer.cancel()
+        left = self.end - time.monotonic()
+        limit = min(seconds, left)
+
+        def fire():
+            import os
+
+            print(f"chip_smoke: FAIL: {label} outlasted its deadline "
+                  f"({limit:.0f}s)", file=sys.stderr, flush=True)
+            os._exit(3)
+
+        self.timer = threading.Timer(max(limit, 0.0), fire)
+        self.timer.daemon = True
+        self.timer.start()
 
 
 def main(argv=None) -> int:
@@ -355,7 +465,7 @@ def main(argv=None) -> int:
                     help="seconds after which the smoke fails and exits, "
                          "whatever it is waiting on")
     args = ap.parse_args(argv)
-    watchdog(args.deadline)
+    dog = Watchdog(args.deadline)
     try:
         from splatt_tpu.utils.env import apply_compile_cache
     except ImportError as e:
@@ -387,7 +497,7 @@ def main(argv=None) -> int:
         f"compile cache {cache}")
     try:
         if args.four_chips:
-            four_chips(args)
+            four_chips(args, dog)
         else:
             one_chip(args)
     except SmokeFailure as e:

@@ -277,13 +277,12 @@ def mttkrp_decode_bytes(X: BlockedSparse, rank: int, mode: int,
     an encoded layout before its kernel runs (docs/format.md) — the
     traffic the in-kernel decode line exists to delete.  Zero for v1
     layouts and for the stream-native engines
-    (:data:`splatt_tpu.ops.mttkrp.STREAM_NATIVE_ENGINES`: fused_v2
-    decodes in registers, xla_scan per scan chunk, the xla scatter
-    inside its fusion).  The prep-decoding Pallas engines rematerialize
+    (:data:`splatt_tpu.ops.mttkrp.STREAM_NATIVE_ENGINES`: xla_scan
+    decodes per scan chunk, the xla scatter inside its fusion).  The prep-decoding Pallas engines rematerialize
     every mode's global-i32 stream (write + read), and the transposed-
     table kernels additionally stream the sublane-replicated request
-    tiles ``_prep_t_operands`` materializes — the reason "achieved
-    bytes ≈ 2x encoded" before the fused_v2 engine.  bench reports the
+    tiles ``_prep_t_operands`` materializes — "achieved bytes ≈ 2x
+    encoded" for those engines.  bench reports the
     per-path ratio as ``decode_overhead`` next to
     ``model_gb_per_path``."""
     from splatt_tpu.ops.mttkrp import STREAM_NATIVE_ENGINES

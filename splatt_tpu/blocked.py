@@ -54,10 +54,10 @@ _IDX_SHORT = {"uint8": "u8", "uint16": "u16", "int8": "i8",
 #
 # THE single decode vocabulary of the blocked format (docs/format.md):
 # every engine — the XLA scatter/segment paths, the scanned-XLA chunk
-# decode, the Pallas operand prep, the in-kernel fused_v2 decode, and
-# the ring kernels' index widening — consumes a layout's encoded
-# streams through these helpers, so a new encoding lands in exactly
-# one place and bit parity across engines is by construction.  All are
+# decode, the Pallas operand prep, and the ring kernels' index
+# widening — consumes a layout's encoded streams through these
+# helpers, so a new encoding lands in exactly one place and bit parity
+# across engines is by construction.  All are
 # pure jnp, shape-polymorphic over leading batch dims, trace-safe and
 # donation-safe, and legal inside Pallas kernel bodies (they operate
 # on values, not refs).
@@ -116,7 +116,7 @@ def decode_gather_ids(arr: jax.Array, base, enc: str) -> jax.Array:
 
     `base` must already be broadcastable against the widened stream
     (callers shape it: ``(..., 1)`` per-block columns in the scan
-    engine, a scalar inside the fused_v2 kernel); pass None for
+    engine); pass None for
     "glob".  "delta" decodes with an exact integer cumulative sum
     along the block axis — the chunk axis boundary IS the block
     boundary, so chunked consumers need no carry."""
@@ -302,8 +302,7 @@ class ModeLayout:
     def mode_streams(self) -> ModeStreams:
         """The :class:`ModeStreams` stream-consumer view — raw encoded
         per-mode index arrays, bases and encoding kinds — for engines
-        that decode per scan chunk (ops/mttkrp._scan_fused) or inside
-        the kernel (ops/pallas_kernels.fused_mttkrp_v2) instead of
+        that decode per scan chunk (ops/mttkrp._scan_fused) instead of
         whole-array."""
         return ModeStreams(
             streams=tuple(self.inds[k] for k in range(self.nmodes)),

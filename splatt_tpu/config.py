@@ -149,9 +149,8 @@ class Verbosity(enum.IntEnum):
 IDX_WIDTHS = ("i32", "auto", "u16", "u8", "delta", "rle")
 
 #: legal decode-placement policies (SPLATT_DECODE): "kernel" lets
-#: dispatch consume the compact streams natively (the fused_v2 Pallas
-#: engine and the per-chunk scan decode — achieved HBM bytes ≈ encoded
-#: bytes, docs/format.md); "prep" forces operand-prep decode (the
+#: dispatch consume the compact streams natively (the per-chunk scan
+#: decode — achieved HBM bytes ≈ encoded bytes, docs/format.md); "prep" forces operand-prep decode (the
 #: pre-format-v2 dataflow: every engine widens to global i32 before
 #: the kernel) — the A/B lever for the decode_overhead bench model.
 DECODES = ("kernel", "prep")
@@ -162,8 +161,7 @@ def resolve_decode() -> str:
     SPLATT_DECODE env default is "kernel" (native stream consumption);
     "prep" forces operand-prep decode — dispatch materializes the
     global-i32 form up front (blocked.decode_to_v1) so EVERY engine
-    runs the pre-format-v2 dataflow, and fused_v2 leaves the chain.
-    Centralized here like the format knobs so a typo'd policy fails
+    runs the pre-format-v2 dataflow.  Centralized here like the format knobs so a typo'd policy fails
     with one clear message."""
     from splatt_tpu.utils.env import read_env
 

@@ -33,17 +33,23 @@ full lifecycle diagram):
   its own send that sourced that slot.  Credits granted == sends, so
   the semaphore drains to zero — a leaked count would wedge the next
   collective.
-- ``send_sem``/``recv_sem`` are the DMA-completion semaphores:
-  ``recv`` is waited before computing on a freshly received slot,
-  ``send`` before a slot is handed back as a landing zone (and before
-  the kernel retires).  Each DMA's send and recv side is waited
-  exactly once.
+- ``send_sems``/``recv_sems`` are the DMA-completion semaphores, one
+  per slot: ``recv`` is waited before computing on a freshly received
+  slot, ``send`` before a slot is handed back as a landing zone (and
+  before the kernel retires).  Each DMA's send and recv side is waited
+  exactly once.  Two of the left neighbor's sends can be in flight at
+  once (into both slots), so one shared recv semaphore would let a
+  wait for slot *s* be met by the bytes of slot *s+1* — TPU interpret
+  mode, which runs a DMA only when its semaphore is waited on, read
+  the unwritten slot that way.
 - Step 0 opens with a neighbor barrier (``get_barrier_semaphore``) so
   no RDMA can land on a device that has not yet entered the kernel.
 
-On the chip the kernels are REFUSED for now (:func:`_refuse_on_chip`):
-they compile for a v5e:2x2, but the PR 21 four-chip smoke's first
-async-ring sweep never completed.
+On a TPU, an ASYNC_RING CPD is refused before any device work
+(:func:`splatt_tpu.parallel.sharded.refuse_async_ring_on_tpu`): the
+exchanges match numpy on a v5e 2x2 at a small size (chip_smoke
+--four-chips), but the CPD at 20M nonzeros finished no iteration in
+359 s (PR 21).  Finding that stall is open (PERF.md).
 
 Fallback ladder: the kernels only run on a real TPU backend
 (:func:`async_ring_supported`); everywhere else — CPU tests and
@@ -51,7 +57,9 @@ interpret mode — :func:`async_ring_gather_rows` / :func:`async_blockwise_reduc
 delegate to the ``ppermute`` implementations in
 :mod:`splatt_tpu.parallel.ring`, so the ASYNC_RING comm strategy keeps
 *today's semantics bit-for-bit* off-TPU and tier-1 exercises the exact
-dataflow.  A runtime failure of the async engine is degraded
+dataflow.  The kernels themselves are tested on CPU devices under TPU
+interpret mode (``interpret=pltpu.InterpretParams(...)``), which
+simulates the remote DMAs, semaphores and barrier and detects races.  A runtime failure of the async engine is degraded
 classified by the driver (sharded.py): the comm engine is demoted
 under its own shape key and the sweep rebuilds on the sync ring, then
 all2all (``comm_fallback`` run-report events) — never an unhandled
@@ -92,19 +100,6 @@ def async_ring_supported() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _refuse_on_chip() -> None:
-    """The RDMA ring kernels compile for a v5e:2x2 but, run there, the
-    first sweep never completed (PR 21 four-chip smoke: the host waited
-    on the result until the call was killed).  Until that deadlock is
-    found, the chip refuses them loudly — a hang would hold the chips,
-    and a quiet ppermute stand-in would misreport what ran.  With
-    engine fallback on, the driver's comm ladder demotes async_ring to
-    the ppermute ring with a ``comm_fallback`` event."""
-    raise NotImplementedError(
-        "ASYNC_RING: the Pallas RDMA ring kernels hang on TPU (PR 21 "
-        "four-chip smoke); use --comm point2point or all2all")
-
-
 # -- kernel building blocks -------------------------------------------------
 
 
@@ -125,15 +120,18 @@ def _grant_credit(pltpu, credit_sem, left):
                            device_id_type=pltpu.DeviceIdType.MESH)
 
 
-def _hop(pltpu, buf_ref, src_slot, dst_slot, send_sem, recv_sem, right):
-    """The step's remote copy descriptor: my ``src_slot`` streams into
-    the right neighbor's ``dst_slot``.  Reconstructed with identical
-    refs wherever its send/recv side is waited (the descriptor is just
-    the address/semaphore tuple)."""
+def _hop(pltpu, src_ref, dst_ref, src_slot, dst_slot, send_sems,
+         recv_sems, right):
+    """The step's remote copy descriptor: my ``src_ref[src_slot]``
+    streams into the right neighbor's ``dst_ref[dst_slot]``.  Each slot
+    has its own send and recv semaphore, so a wait retires exactly the
+    DMA of that slot, never a later one that shares a count.
+    Reconstructed with identical refs wherever its send/recv side is
+    waited (the descriptor is just the address/semaphore tuple)."""
     return pltpu.make_async_remote_copy(
-        src_ref=buf_ref.at[src_slot], dst_ref=buf_ref.at[dst_slot],
-        send_sem=send_sem, recv_sem=recv_sem, device_id=(right,),
-        device_id_type=pltpu.DeviceIdType.MESH)
+        src_ref=src_ref.at[src_slot], dst_ref=dst_ref.at[dst_slot],
+        send_sem=send_sems.at[src_slot], recv_sem=recv_sems.at[dst_slot],
+        device_id=(right,), device_id_type=pltpu.DeviceIdType.MESH)
 
 
 def _stage(pltpu, src_ref, dst_ref, sem):
@@ -158,7 +156,7 @@ def _stage(pltpu, src_ref, dst_ref, sem):
 
 def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
                         buf_ref, u_vmem, rows_vmem, div_vmem, loc_vmem,
-                        local_sems, send_sem, recv_sem, credit_sem, *,
+                        local_sems, send_sems, recv_sems, credit_sem, *,
                         ndev: int, axis: str, block: int, nnz_pad: int):
     """One device's whole gather ring (≙ mpi_update_rows streamed).
 
@@ -188,8 +186,8 @@ def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
     @pl.when(s > 0)
     def _recv_wait():
         # the step-(s-1) hop delivered this slot
-        _hop(pltpu, buf_ref, 1 - cur, cur, send_sem, recv_sem,
-             right).wait_recv()
+        _hop(pltpu, buf_ref, buf_ref, 1 - cur, cur, send_sems,
+             recv_sems, right).wait_recv()
 
     @pl.when(s < ndev - 1)
     def _send():
@@ -197,8 +195,8 @@ def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
         # when it freed the destination slot, then stream my current
         # block forward — this DMA is what overlaps the compute below
         pltpu.semaphore_wait(credit_sem, 1)
-        _hop(pltpu, buf_ref, cur, 1 - cur, send_sem, recv_sem,
-             right).start()
+        _hop(pltpu, buf_ref, buf_ref, cur, 1 - cur, send_sems,
+             recv_sems, right).start()
 
     # stage the resident block for compute (HBM -> VMEM)
     _stage(pltpu, buf_ref.at[cur], u_vmem, local_sems.at[1])
@@ -252,22 +250,22 @@ def _ring_gather_kernel(idx_div_ref, idx_loc_ref, u0_ref, rows_ref,
     # step only drains the last in-flight send.
     @pl.when((s <= ndev - 3) & (s < ndev - 1))
     def _free():
-        _hop(pltpu, buf_ref, cur, 1 - cur, send_sem, recv_sem,
-             right).wait_send()
+        _hop(pltpu, buf_ref, buf_ref, cur, 1 - cur, send_sems,
+             recv_sems, right).wait_send()
         _grant_credit(pltpu, credit_sem, left)
 
     @pl.when((s == ndev - 2) & (s < ndev - 1))
     def _retire_penultimate():
         # the second-to-last send is the LAST send; its slot is never
         # re-landed, so retire the DMA without granting a credit
-        _hop(pltpu, buf_ref, cur, 1 - cur, send_sem, recv_sem,
-             right).wait_send()
+        _hop(pltpu, buf_ref, buf_ref, cur, 1 - cur, send_sems,
+             recv_sems, right).wait_send()
 
 
 def _ring_reduce_kernel(idx_div_ref, idx_loc_ref, prod_ref, out_ref,
                         buf_ref, sbuf_ref, acc_vmem, blk_vmem, prod_vmem,
                         div_vmem, loc_vmem,
-                        local_sems, send_sem, recv_sem, credit_sem, *,
+                        local_sems, send_sems, recv_sems, credit_sem, *,
                         ndev: int, axis: str, block: int, nnz_pad: int):
     """One device's whole reduce ring (≙ mpi_reduce_rows streamed).
 
@@ -307,10 +305,8 @@ def _ring_reduce_kernel(idx_div_ref, idx_loc_ref, prod_ref, out_ref,
         # the left neighbor's step-(s-1) send: its sbuf[1-cur] into my
         # buf[cur] (the descriptor is symmetric under SPMD, so the same
         # refs reconstruct both wait sides)
-        return pltpu.make_async_remote_copy(
-            src_ref=sbuf_ref.at[1 - cur], dst_ref=buf_ref.at[cur],
-            send_sem=send_sem, recv_sem=recv_sem, device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.MESH)
+        return _hop(pltpu, sbuf_ref, buf_ref, 1 - cur, cur, send_sems,
+                    recv_sems, right)
 
     @pl.when(s == 0)
     def _open():
@@ -378,10 +374,8 @@ def _ring_reduce_kernel(idx_div_ref, idx_loc_ref, prod_ref, out_ref,
         # s-2) was retired at step s-1, so re-staging is safe.
         pltpu.semaphore_wait(credit_sem, 1)
         _stage(pltpu, acc_vmem, sbuf_ref.at[cur], local_sems.at[0])
-        pltpu.make_async_remote_copy(
-            src_ref=sbuf_ref.at[cur], dst_ref=buf_ref.at[1 - cur],
-            send_sem=send_sem, recv_sem=recv_sem, device_id=(right,),
-            device_id_type=pltpu.DeviceIdType.MESH).start()
+        _hop(pltpu, sbuf_ref, buf_ref, cur, 1 - cur, send_sems,
+             recv_sems, right).start()
 
     @pl.when((s > 0) & (s <= ndev - 2))
     def _retire():
@@ -435,8 +429,11 @@ def _ring_compiler_params(collective_id: int):
 
 
 def _gather_pallas(U_l: jax.Array, idx: jax.Array, axis: str,
-                   ndev: int) -> jax.Array:
-    """TPU path of :func:`async_ring_gather_rows`."""
+                   ndev: int, interpret=False) -> jax.Array:
+    """TPU path of :func:`async_ring_gather_rows`.  `interpret` takes
+    a ``pltpu.InterpretParams``: TPU interpret mode simulates the remote
+    DMAs and semaphores on CPU devices (the tests' only way to run the
+    ring protocol off the chip)."""
     from splatt_tpu.utils.env import ceil_to
 
     pltpu = _pltpu()
@@ -451,9 +448,9 @@ def _gather_pallas(U_l: jax.Array, idx: jax.Array, axis: str,
     rows, _ = pl.pallas_call(
         kernel,
         grid=(ndev,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         out_shape=(jax.ShapeDtypeStruct((nnz_pad, R), U_l.dtype),
                    jax.ShapeDtypeStruct((2, block, R), U_l.dtype)),
         scratch_shapes=(
@@ -462,18 +459,20 @@ def _gather_pallas(U_l: jax.Array, idx: jax.Array, axis: str,
             pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.SemaphoreType.DMA((3,)),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR,
         ),
         compiler_params=_ring_compiler_params(7),
+        interpret=interpret,
     )(div[None, :], loc[None, :], U_l)
     return rows[:int(idx.shape[0]), :R0]
 
 
 def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
-                   block: int) -> jax.Array:
-    """TPU path of :func:`async_blockwise_reduce_rows`."""
+                   block: int, interpret=False) -> jax.Array:
+    """TPU path of :func:`async_blockwise_reduce_rows`; `interpret` as
+    in :func:`_gather_pallas`."""
     pltpu = _pltpu()
     from splatt_tpu.ops.mttkrp import acc_dtype
 
@@ -490,10 +489,10 @@ def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
     out, _, _ = pl.pallas_call(
         kernel,
         grid=(ndev,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 3,
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 3,
+        out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)),
         out_shape=(jax.ShapeDtypeStruct((block, R), out_dtype),
                    jax.ShapeDtypeStruct((2, block, R), out_dtype),
                    jax.ShapeDtypeStruct((2, block, R), out_dtype)),
@@ -504,11 +503,12 @@ def _reduce_pallas(prod: jax.Array, idx: jax.Array, axis: str, ndev: int,
             pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.VMEM((1, _NNZ_CHUNK), jnp.int32),
             pltpu.SemaphoreType.DMA((3,)),
-            pltpu.SemaphoreType.DMA(()),
-            pltpu.SemaphoreType.DMA(()),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.REGULAR,
         ),
         compiler_params=_ring_compiler_params(8),
+        interpret=interpret,
     )(div[None, :], loc[None, :], prod_pad)
     return out[:, :R0]
 
@@ -530,7 +530,6 @@ def async_ring_gather_rows(U_l: jax.Array, idx: jax.Array, axis: str,
     """
     faults.maybe_fail("comm.ring_exchange")
     if ndev >= 2 and async_ring_supported():
-        _refuse_on_chip()
         return _gather_pallas(U_l, idx, axis, ndev)
     # interpret/CPU fallback: today's ppermute semantics, bit-for-bit
     # (docs/ring.md fallback ladder) — tier-1 exercises this dataflow
@@ -548,7 +547,6 @@ def async_blockwise_reduce_rows(prod: jax.Array, idx: jax.Array, axis: str,
     with the POINT2POINT path."""
     faults.maybe_fail("comm.ring_exchange")
     if ndev >= 2 and async_ring_supported():
-        _refuse_on_chip()
         return _reduce_pallas(prod, idx, axis, ndev, block)
     from splatt_tpu.parallel.ring import blockwise_reduce_rows
 

@@ -566,6 +566,20 @@ _COMM_CHAINS = {
 }
 
 
+def refuse_async_ring_on_tpu() -> None:
+    """ASYNC_RING fails loudly on a TPU, whatever the engine-fallback
+    setting: the RDMA kernels pass their exchange check on a v5e 2x2
+    (chip_smoke --four-chips), but the async-ring CPD at 20M nonzeros
+    finished no iteration in 359 s (PR 21, PERF.md).  Neither a hang
+    nor a quiet ppermute stand-in is acceptable, so the run is refused
+    before any device work."""
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "--comm async_ring is refused on TPU: its RDMA ring CPD "
+            "stalls at scale on a v5e 2x2 (PERF.md, open questions); "
+            "use --comm point2point or all2all")
+
+
 def comm_chain(comm: CommPattern) -> tuple:
     """The ordered comm-strategy fallback chain for a requested
     pattern (best first, terminal last)."""
@@ -861,6 +875,8 @@ def sharded_cpd_als(tt: SparseTensor, rank: int, mesh: Optional[Mesh] = None,
         raise ValueError(f"unknown row_distribute {row_distribute!r}")
 
     comm = resolve_comm_pattern(opts)
+    if comm is CommPattern.ASYNC_RING and ndev > 1:
+        refuse_async_ring_on_tpu()
     chain = comm_chain(comm)
     ring_family = chain[0] != "all2all"
     if local_engine is None:

@@ -61,8 +61,8 @@ import numpy as np
 #: regime component so uniform-tuned plans never steer power-law
 #: tensors.
 #: v4: the delta/RLE catalog entries join the format candidates and
-#: winners were measured in the in-kernel-decode era (the fused_v2
-#: engine heads compact chains, docs/format.md) — plans tuned when
+#: winners were measured in the in-kernel-decode era (docs/format.md)
+#: — plans tuned when
 #: every engine paid operand-prep decode are re-earned, not
 #: reinterpreted.
 #: v5: the plan key gains a mode-density regime component and the

@@ -618,7 +618,7 @@ def test_u8_registry_and_validation():
     assert "u8" in ENV_VARS["SPLATT_IDX_WIDTH"].doc
 
 
-# -- in-kernel decode: the fused_v2 engine + delta/RLE catalog (ISSUE 13) ----
+# -- decode placement + the delta/RLE catalog (ISSUE 13) ---------------------
 
 ALL_V2 = ("auto", "u8", "delta", "rle")
 
@@ -662,93 +662,24 @@ def test_delta_rle_bitparity_all_paths(idx):
             np.testing.assert_array_equal(a, b, err_msg=f"{eng}/{other}")
 
 
-@pytest.mark.parametrize("tt_name", ["med", "med4", "wide"])
-def test_fused_v2_interpret_bit_identical_to_v1_reference(tt_name):
-    """ACCEPTANCE: the decode-in-kernel fused_v2 engine (interpret
-    mode — the exact kernel dataflow on CPU) is bit-identical to the
-    v1 reference on the sorted path, for EVERY catalog encoding."""
-    tt = _wide_tensor() if tt_name == "wide" else gen.fixture_tensor(tt_name)
-    facs = init_factors(tt.dims, 5, 3, dtype=jnp.float32)
-    v1, encoded = _enc_layouts(tt, 0)
-    ref_scan = np.asarray(_mttkrp_blocked_jit(
-        v1, facs, 0, "sorted_onehot", "xla", 1 << 21, "xla_scan"))
-    ref_scatter = np.asarray(mttkrp_blocked(
-        v1, facs, 0, path="sorted_scatter", impl="xla"))
-    for idx, lay in encoded.items():
-        got = np.asarray(_mttkrp_blocked_jit(
-            lay, facs, 0, "sorted_onehot", "pallas_interpret", 1 << 21,
-            "fused_v2"))
-        np.testing.assert_array_equal(ref_scan, got, err_msg=idx)
-        # and against the v1 scatter formulation (reassociation-free
-        # on the sorted stream: both accumulate in stream order)
-        np.testing.assert_allclose(ref_scatter, got, rtol=1e-6,
-                                   err_msg=idx)
-
-
-def test_fused_v2_privatized_same_engine_parity():
-    """The accumulating (privatized) fused_v2 path: bit-identical
-    ACROSS encodings (same engine, same reduction order) and within
-    reassociation tolerance of the scan engine — the fused_t
-    standard."""
-    tt = _tensor()
-    facs = init_factors(tt.dims, 4, 1, dtype=jnp.float32)
-    v1, encoded = _enc_layouts(tt, 0)
-    outs = {idx: np.asarray(_mttkrp_blocked_jit(
-                lay, facs, 1, "privatized", "pallas_interpret", 1 << 21,
-                "fused_v2"))
-            for idx, lay in encoded.items()}
-    for idx in ("u8", "delta", "rle"):
-        np.testing.assert_array_equal(outs["auto"], outs[idx],
-                                      err_msg=idx)
-    ref = np.asarray(_mttkrp_blocked_jit(v1, facs, 1, "privatized",
-                                         "xla", 1 << 21, "xla_scan"))
-    np.testing.assert_allclose(ref, outs["auto"], rtol=1e-5)
-
-
-def test_fused_v2_requires_encoded_layout():
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp_v2
-
-    tt = _tensor()
-    facs = init_factors(tt.dims, 3, 0, dtype=jnp.float32)
-    v1 = build_layout(tt, 0, block=128, val_dtype=np.float32)
-    with pytest.raises(ValueError, match="compact encoded streams"):
-        fused_mttkrp_v2(v1, facs, 0, v1.seg_width, accumulate=False,
-                        interpret=True)
-
-
-def test_engine_chain_heads_with_fused_v2(monkeypatch):
-    """Chain position: fused_v2 heads the Pallas chain for compact
-    layouts only, and SPLATT_DECODE=prep (the operand-prep A/B lever)
-    removes it."""
-    from splatt_tpu.ops.mttkrp import engine_chain
-
+def test_decode_prep_lever(monkeypatch):
+    """SPLATT_DECODE=prep (the operand-prep A/B lever) materializes the
+    decoded v1 form up front for every engine and stays bit-identical;
+    an unknown policy fails with one clear message."""
     tt = _tensor()
     facs = init_factors(tt.dims, 4, 0, dtype=jnp.float32)
     v1, encoded = _enc_layouts(tt, 0)
-    for idx, lay in encoded.items():
-        chain = engine_chain(lay, facs, 0, "sorted_onehot",
-                             "pallas_interpret")
-        assert chain[0] == "fused_v2", idx
-    assert "fused_v2" not in engine_chain(v1, facs, 0, "sorted_onehot",
-                                          "pallas_interpret")
-    # the xla family never runs it (no Pallas)
-    assert "fused_v2" not in engine_chain(encoded["auto"], facs, 0,
-                                          "sorted_onehot", "xla")
-    monkeypatch.setenv("SPLATT_DECODE", "prep")
-    assert "fused_v2" not in engine_chain(encoded["auto"], facs, 0,
-                                          "sorted_onehot",
-                                          "pallas_interpret")
-    # prep is a REAL lever: dispatch materializes the decoded v1 form
-    # up front for every engine — and stays bit-identical
     ref = np.asarray(mttkrp_blocked(v1, facs, 0, path="sorted_onehot",
                                     impl="xla"))
-    got = np.asarray(mttkrp_blocked(encoded["auto"], facs, 0,
-                                    path="sorted_onehot", impl="xla"))
-    np.testing.assert_array_equal(ref, got)
+    monkeypatch.setenv("SPLATT_DECODE", "prep")
+    for idx, lay in encoded.items():
+        got = np.asarray(mttkrp_blocked(lay, facs, 0,
+                                        path="sorted_onehot", impl="xla"))
+        np.testing.assert_array_equal(ref, got, err_msg=idx)
     monkeypatch.setenv("SPLATT_DECODE", "nope")
     with pytest.raises(ValueError, match="SPLATT_DECODE"):
-        engine_chain(encoded["auto"], facs, 0, "sorted_onehot",
-                     "pallas_interpret")
+        mttkrp_blocked(encoded["auto"], facs, 0, path="sorted_onehot",
+                       impl="xla")
 
 
 def test_decode_fault_degrades_to_v1_path():
@@ -859,13 +790,14 @@ def test_format_decode_event_names_strategy():
     # warm dispatch: no second event for the same (engine, shape)
     mttkrp_blocked(l2, facs, 0, path="sorted_onehot", impl="xla")
     assert len(resilience.run_report().events("format_decode")) == n
-    # the interpret-Pallas chain heads with fused_v2 — also 'kernel'
+    # the interpret-Pallas chain heads with fused_t, which decodes at
+    # operand prep
     _DEADLINE_ARMED.clear()
     mttkrp_blocked(l2, facs, 0, path="sorted_onehot",
                    impl="pallas_interpret")
     evs = resilience.run_report().events("format_decode")
-    assert evs[-1]["engine"] == "fused_v2"
-    assert evs[-1]["strategy"] == "kernel"
+    assert evs[-1]["engine"] == "fused_t"
+    assert evs[-1]["strategy"] == "prep"
 
 
 def test_delta_rle_cpd_bitparity_under_donation():
@@ -928,7 +860,7 @@ def test_decode_bytes_model():
         mttkrp_decode_bytes
     from splatt_tpu.ops.mttkrp import STREAM_NATIVE_ENGINES
 
-    assert "fused_v2" in STREAM_NATIVE_ENGINES
+    assert set(STREAM_NATIVE_ENGINES) == {"xla_scan", "xla"}
     tt = _tensor()
     opts_v1 = Options(verbosity=Verbosity.NONE, use_pallas=False,
                       autotune=False, block_alloc=BlockAlloc.ALLMODE)
@@ -945,26 +877,8 @@ def test_decode_bytes_model():
         dec = mttkrp_decode_bytes(bs2, 4, 0, eng)
         assert dec > 0.0, eng
     # the transposed-table kernels' replicated request tiles dominate:
-    # the achieved/encoded ratio is the ~2x the in-kernel decode cuts
+    # the achieved/encoded ratio is ~2x
     assert (enc + mttkrp_decode_bytes(bs2, 4, 0, "fused_t")) / enc > 1.3
-
-
-def test_fused_v2_probe_keys_per_encoding():
-    """The fused_v2 capability probe is scoped per ENCODING family:
-    the stream kinds are static kernel params tracing different
-    Mosaic code, so an "auto" verdict never vouches for a delta or
-    RLE dispatch (off-TPU every probe honestly reports not_tpu, under
-    its own state key)."""
-    import splatt_tpu.ops.pallas_kernels as pk
-
-    pk.fused_v2_supported.cache_clear()
-    for idx in ("auto", "u8", "delta", "rle"):
-        assert pk.fused_v2_supported("ck1", 256, idx) is False  # no TPU
-    for idx in ("auto", "u8", "delta", "rle"):
-        assert pk.PROBE_STATES[f"fused_v2_{idx}:ck1:b256"] == "not_tpu"
-    # an i32 (or unknown) request collapses to the auto family
-    assert pk.fused_v2_supported("ck1", 256, "i32") is False
-    assert "fused_v2_i32:ck1:b256" not in pk.PROBE_STATES
 
 
 def test_decode_registries_declared():

@@ -242,31 +242,126 @@ def test_demoted_comm_engine_skipped_next_run():
     assert np.isfinite(float(out.fit))
 
 
-@pytest.mark.parametrize("fallback", [True, False])
-def test_async_ring_refused_on_chip(monkeypatch, fallback):
-    """On a TPU the RDMA ring kernels are refused (they hung the PR 21
-    four-chip smoke): never a hang, never a quiet ppermute stand-in —
-    a loud NotImplementedError, which the comm ladder turns into a
-    comm_fallback to the ppermute ring when fallback is on."""
+@pytest.fixture
+def x32():
+    """The chip path runs in 32-bit mode (conftest turns x64 on for the
+    f64 differential tests; the kernels' scalar index math is i32)."""
+    x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
+    yield
+    jax.config.update("jax_enable_x64", x64)
+
+
+def _tpu_interpret():
+    """TPU interpret mode with the DMA-on-wait model and the race
+    detector: a DMA runs only when a semaphore it signals is waited on,
+    so a wait that another slot's DMA could satisfy reads stale data."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    pltpu.reset_tpu_interpret_mode_state()
+    return pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                 detect_races=True)
+
+
+def _races_found() -> bool:
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    races = interpret_pallas_call.races
+    return bool(races is not None and races.races_found)
+
+
+@pytest.mark.parametrize("ndev", [2, 4])
+@pytest.mark.parametrize("kernel", ["gather", "reduce"])
+def test_rdma_ring_kernel_interpret_matches_numpy(x32, kernel, ndev):
+    """The Pallas RDMA ring kernels themselves — remote copies, credit
+    and barrier semaphores — under TPU interpret mode on CPU devices:
+    the gather picks exactly the requested rows, the reduce sums every
+    device's partials into the owner's block, and no race is found."""
     import splatt_tpu.parallel.ring_kernels as rk
 
-    tt = gen.fixture_tensor("med")
-    mesh = make_mesh(n_devices=8)
-    init = init_factors(tt.dims, 5, 42, dtype=jnp.float64)
-    clean = _run(CommPattern.POINT2POINT, mesh, tt, init)
-    resilience.run_report().clear()
-    resilience.reset_demotions()
+    block, R, n = 16, 8, 1500
+    mesh = make_mesh(n_devices=ndev, axis_names=("x",))
+    rng = np.random.default_rng(ndev)
+    idx = rng.integers(0, ndev * block, size=ndev * n).astype(np.int32)
+    params = _tpu_interpret()
+    if kernel == "gather":
+        U = rng.standard_normal((ndev * block, R)).astype(np.float32)
+
+        def body(u, i):
+            return rk._gather_pallas(u, i, "x", ndev, interpret=params)
+
+        args, want = (U, idx), U[idx]
+    else:
+        prod = rng.standard_normal((ndev * n, R)).astype(np.float32)
+
+        def body(p, i):
+            return rk._reduce_pallas(p, i, "x", ndev, block,
+                                     interpret=params)
+
+        want = np.zeros((ndev * block, R))
+        np.add.at(want, idx, prod.astype(np.float64))
+        args = (prod, idx)
+    fn = jax.jit(shard_map(body, mesh=mesh,
+                           in_specs=(P("x", None), P("x")),
+                           out_specs=P("x", None), check_vma=False))
+    got = np.asarray(fn(*args))
+    assert not _races_found()
+    if kernel == "gather":
+        np.testing.assert_array_equal(got, want)
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_async_ring_sweep_on_rdma_kernels_matches_all2all(x32, monkeypatch):
+    """A whole ASYNC_RING CPD with the sweep on the RDMA kernels (TPU
+    interpret mode standing in for the chip) reaches the all2all fit
+    and factors, with no comm fallback and no race."""
+    import splatt_tpu.parallel.ring_kernels as rk
+    from splatt_tpu.coo import SparseTensor
+
+    rng = np.random.default_rng(0)
+    dims, nnz = (40, 36, 52), 1500
+    tt = SparseTensor(np.stack([rng.integers(0, d, nnz) for d in dims]),
+                      rng.random(nnz), dims)
+    init = init_factors(dims, 8, 0, dtype=jnp.float32)
+    mesh = make_mesh(n_devices=4)
+    params = _tpu_interpret()
     monkeypatch.setattr(rk, "async_ring_supported", lambda: True)
-    if not fallback:
-        with pytest.raises(NotImplementedError, match="hang on TPU"):
-            _run(CommPattern.ASYNC_RING, mesh, tt, init,
-                 opts_kw=dict(engine_fallback=False))
-        return
-    out = _run(CommPattern.ASYNC_RING, mesh, tt, init)
-    evs = resilience.run_report().events("comm_fallback")
-    assert [(e["strategy"], e["fallback_to"]) for e in evs] \
-        == [("async_ring", "ring")]
-    assert float(out.fit) == float(clean.fit)
+    monkeypatch.setattr(rk, "_gather_pallas",
+                        partial(rk._gather_pallas, interpret=params))
+    monkeypatch.setattr(rk, "_reduce_pallas",
+                        partial(rk._reduce_pallas, interpret=params))
+    out = sharded_cpd_als(tt, rank=8, mesh=mesh, init=init,
+                          opts=_opts(max_iterations=2,
+                                     comm_pattern=CommPattern.ASYNC_RING,
+                                     val_dtype=np.float32,
+                                     engine_fallback=False))
+    ref = sharded_cpd_als(tt, rank=8, mesh=mesh, init=init,
+                          opts=_opts(max_iterations=2,
+                                     val_dtype=np.float32,
+                                     engine_fallback=False))
+    assert not _races_found()
+    assert not resilience.run_report().events("comm_fallback")
+    assert float(out.fit) == pytest.approx(float(ref.fit), abs=1e-5)
+    for fa, fb in zip(out.factors, ref.factors):
+        np.testing.assert_allclose(np.asarray(fa), np.asarray(fb),
+                                   rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("fallback", [True, False])
+def test_async_ring_refused_on_tpu(monkeypatch, fallback):
+    """On a TPU an ASYNC_RING CPD is refused before any device work,
+    whatever the fallback setting — never a hang, never a quiet
+    ppermute stand-in (its at-scale stall is open, PERF.md)."""
+    import splatt_tpu.parallel.sharded as sh
+
+    tt = gen.fixture_tensor("med")
+    init = init_factors(tt.dims, 5, 42, dtype=jnp.float64)
+    monkeypatch.setattr(sh.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="refused on TPU"):
+        _run(CommPattern.ASYNC_RING, make_mesh(n_devices=8), tt, init,
+             opts_kw=dict(engine_fallback=fallback))
+    assert not resilience.run_report().events("comm_fallback")
 
 
 def test_comm_fallback_disabled_fails_loudly():

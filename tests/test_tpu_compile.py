@@ -19,7 +19,6 @@ import numpy as np
 import pytest
 
 from splatt_tpu.blocked import build_layout
-from splatt_tpu.config import LayoutFormat
 from splatt_tpu.coo import SparseTensor
 
 NELL2 = (12092, 9184, 28818)
@@ -33,14 +32,13 @@ GATHER_REFUSAL = "Multiple source vregs along gather dimension"
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A SingleDeviceSharding on a described v5e, with the kernels'
-    VMEM budget steered to the v5e row of splatt_tpu/devices.py."""
+def topo():
+    """A described v5e:2x2, with the kernels' VMEM budget steered to
+    the v5e row of splatt_tpu/devices.py."""
     import os
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     import splatt_tpu.ops.pallas_kernels as pk
     from splatt_tpu.devices import DEVICE_SPECS
@@ -50,8 +48,8 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    dev = topo.devices[0]
-    assert dev.device_kind in DEVICE_SPECS
+    kind = topo.devices[0].device_kind
+    assert kind in DEVICE_SPECS
     # the chip path runs in 32-bit mode (conftest turns x64 on for the
     # f64 differential tests; Mosaic index maps must stay i32)
     x64 = jax.config.jax_enable_x64
@@ -59,17 +57,24 @@ def one_chip():
     try:
         with pytest.MonkeyPatch.context() as mp:
             mp.setattr(pk, "_vmem_limit",
-                       lambda: DEVICE_SPECS[dev.device_kind].vmem_limit)
-            yield SingleDeviceSharding(dev)
+                       lambda: DEVICE_SPECS[kind].vmem_limit)
+            yield topo
     finally:
         jax.config.update("jax_enable_x64", x64)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(x, sharding):
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
-def spec_layout(sharding, mode, dims=NELL2, nnz=NNZ, fmt=None, seg=SEG):
+def spec_layout(sharding, mode, dims=NELL2, nnz=NNZ, seg=SEG):
     """A ModeLayout of `nnz` nonzeros as shapes only: built for real on
     a few blocks, then every per-block and per-nonzero axis is scaled
     to the full block count."""
@@ -77,7 +82,7 @@ def spec_layout(sharding, mode, dims=NELL2, nnz=NNZ, fmt=None, seg=SEG):
     n = 4 * BLOCK
     inds = np.stack([rng.integers(0, d, n) for d in dims])
     lay = build_layout(SparseTensor(inds, np.ones(n), dims), mode,
-                       block=BLOCK, val_dtype=np.float32, fmt=fmt,
+                       block=BLOCK, val_dtype=np.float32,
                        dense=False, record_stats=False)
     nb = -(-nnz // BLOCK)
 
@@ -134,44 +139,31 @@ def test_onehot_reduce_compiles(one_chip, kernel, width):
 def _fused(name):
     from splatt_tpu.ops import pallas_kernels as pk
 
-    return {"fused_t": pk.fused_mttkrp_t, "fused_tg": pk.fused_mttkrp_tg,
-            "fused_v2": pk.fused_mttkrp_v2}[name]
+    return {"fused_t": pk.fused_mttkrp_t, "fused_tg": pk.fused_mttkrp_tg}[name]
 
 
-@pytest.mark.parametrize("name", ["fused_t", "fused_tg", "fused_v2"])
+@pytest.mark.parametrize("name", ["fused_t", "fused_tg"])
 def test_fused_gather_refused_at_nell2_widths(one_chip, name):
     """The in-kernel gather family at NELL-2 widths: Mosaic refuses the
     lane gather over a 9184-row table.  This is why engine_chain keeps
     these engines off the chip above 128 gathered rows; when a JAX
     release lowers it, this test fails and the gate can go."""
-    fmt = LayoutFormat(idx="auto") if name == "fused_v2" else None
-    lay = spec_layout(one_chip, 0, fmt=fmt)
+    lay = spec_layout(one_chip, 0)
     with pytest.raises(Exception, match=GATHER_REFUSAL):
         _fused(name).lower(lay, spec_factors(one_chip), mode=0,
                            width=SEG, accumulate=False,
                            interpret=False).compile()
 
 
-@pytest.mark.parametrize("name", ["fused_t", "fused_tg", "fused_v2"])
+@pytest.mark.parametrize("name", ["fused_t", "fused_tg"])
 def test_fused_gather_within_one_vreg(one_chip, name):
-    """...and where every gathered factor fits one 128-lane vreg,
-    fused_t/fused_tg compile (the chain admits them on the chip), while
-    fused_v2's in-register request build is still refused (the chain
-    keeps it to interpret mode)."""
+    """...and where every gathered factor fits one 128-lane vreg they
+    compile (the chain admits them on the chip)."""
     dims = (NELL2[0], 128, 120)
-    fmt = LayoutFormat(idx="auto") if name == "fused_v2" else None
-    lay = spec_layout(one_chip, 0, dims=dims, nnz=1000 * BLOCK, fmt=fmt)
-
-    def compile_():
-        _fused(name).lower(lay, spec_factors(one_chip, dims), mode=0,
-                           width=SEG, accumulate=False,
-                           interpret=False).compile()
-
-    if name == "fused_v2":
-        with pytest.raises(Exception, match="Invalid input layout"):
-            compile_()
-    else:
-        compile_()
+    lay = spec_layout(one_chip, 0, dims=dims, nnz=1000 * BLOCK)
+    _fused(name).lower(lay, spec_factors(one_chip, dims), mode=0,
+                       width=SEG, accumulate=False,
+                       interpret=False).compile()
 
 
 def test_fused_dense_compiles(one_chip):
@@ -189,3 +181,39 @@ def test_fused_dense_compiles(one_chip):
     assert dense_vmem_ok(lay, facs, 0)
     lay = jax.tree_util.tree_map(lambda x: _spec(x, one_chip), lay)
     fused_dense.lower(lay, facs, mode=0, interpret=False).compile()
+
+
+@pytest.mark.parametrize("kernel", ["gather", "reduce"])
+def test_rdma_ring_kernels_compile_for_2x2(topo, kernel):
+    """The async ring's RDMA kernels inside the fine decomposition's
+    shard_map on a 4-chip mesh, at the four-chip smoke's size (20M
+    nonzeros, 5M per chip) and NELL-2's widest mode (7205 rows per
+    chip), rank 50."""
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import splatt_tpu.parallel.ring_kernels as rk
+
+    ndev, nnz = 4, 20_000_000
+    block = -(-NELL2[2] // ndev)
+    mesh = Mesh(np.array(topo.devices), ("x",))
+
+    def spec(shape, dtype, p):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, p))
+
+    idx = spec((nnz,), jnp.int32, P("x"))
+    if kernel == "gather":
+        def body(u, i):
+            return rk._gather_pallas(u, i, "x", ndev)
+
+        first = spec((ndev * block, RANK), jnp.float32, P("x", None))
+    else:
+        def body(p, i):
+            return rk._reduce_pallas(p, i, "x", ndev, block)
+
+        first = spec((nnz, RANK), jnp.float32, P("x", None))
+    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("x", None), P("x")),
+                           out_specs=P("x", None), check_vma=False))
+    compiled = fn.lower(first, idx).compile()
+    assert "tpu_custom_call" in compiled.as_text()
