@@ -325,9 +325,13 @@ class ModeLayout:
     def idx_widths(self) -> List[str]:
         """Per-mode stored index width ("u8"/"u16"/"i8"/"i16"/"i32") —
         the ACHIEVED encoding, next to the requested ``idx_width``
-        policy (signed widths appear under "delta")."""
-        return [_IDX_SHORT.get(jnp.dtype(self.inds[k].dtype).name, "i32")
-                for k in range(self.nmodes)]
+        policy (signed widths appear under "delta").  Read from dtypes
+        alone: indexing the stacked v1 array would run a device slice
+        per mode."""
+        dtypes = ([self.inds.dtype] * self.nmodes
+                  if hasattr(self.inds, "dtype")
+                  else [a.dtype for a in self.inds])
+        return [_IDX_SHORT.get(jnp.dtype(d).name, "i32") for d in dtypes]
 
     def format_desc(self) -> str:
         """Compact achieved-format summary, e.g. ``u16/seg/bf16`` (v2)

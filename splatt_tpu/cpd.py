@@ -577,16 +577,25 @@ def cpd_als(X: Union[SparseTensor, BlockedSparse], rank: int,
     checkpoints running jobs instead of abandoning or outliving them.
     """
     opts = (opts or default_opts()).validate()
+    trace.count_jit()
     # structured tracing (docs/observability.md): Options.trace pins
     # recording on/off for this run (None defers to the process/env
     # default), and every span below nests under the cpd.als root —
-    # the tree the Chrome-trace exporter and `splatt trace` summarize
+    # the tree the Chrome-trace exporter and `splatt trace` summarize.
+    # The root also records the compile work that fell inside the run
+    # (trace.jit_delta: jit_s, and per program its stages and seconds)
     with trace.enabling(opts.trace):
         with trace.span("cpd.als", rank=int(rank),
                         donate=opts.donate_sweep,
-                        max_iterations=int(opts.max_iterations)):
-            return _cpd_als_traced(X, rank, opts, init, checkpoint_path,
-                                   checkpoint_every, resume, stop)
+                        max_iterations=int(opts.max_iterations)) as root:
+            jit0 = trace.jit_totals() if trace.enabled() else None
+            try:
+                return _cpd_als_traced(X, rank, opts, init,
+                                       checkpoint_path, checkpoint_every,
+                                       resume, stop)
+            finally:
+                if jit0 is not None:
+                    root.set(**trace.jit_delta(jit0))
 
 
 def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,

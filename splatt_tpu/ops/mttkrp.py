@@ -49,6 +49,23 @@ PATHS = ("stream", "sorted_onehot", "privatized", "scatter",
 #: format_decode run-report event both read this set.
 STREAM_NATIVE_ENGINES = ("xla_scan", "xla")
 
+#: the phases of one blocked MTTKRP, as ``jax.named_scope`` names: every
+#: op of the ``_mttkrp_blocked_jit`` program sits under
+#: ``mttkrp.mode<m>`` and one of these, so a device trace splits the
+#: program's time by phase (docs/observability.md).  Lowering metadata
+#: only: the scopes change no op.
+#:
+#: - operands: stream padding, index decode, factor-row gather, the
+#:   Hadamard product (and a fused kernel's operand prep);
+#: - reduce: the reduction itself — the one-hot einsum, a Mosaic
+#:   one-hot kernel with its chunk padding, ``segment_sum``, the dense
+#:   ``dot_general``, or a kernel that fuses gather and reduce
+#:   (fused_t, fused_tg, fused_dense) whole;
+#: - merge: block partials into output rows (``out.at[idx].add``) and
+#:   the final slice.
+OPERANDS, REDUCE, MERGE = PHASES = ("mttkrp.operands", "mttkrp.reduce",
+                                    "mttkrp.merge")
+
 
 def _gather_prod(inds: jax.Array, vals: jax.Array,
                  factors: Sequence[jax.Array], mode: int) -> jax.Array:
@@ -232,14 +249,18 @@ def dense_mttkrp(layout, factors: Sequence[jax.Array],
         raise ValueError("dense_mttkrp requires the layout's own mode")
     dtype = factors[0].dtype
     R = int(factors[0].shape[1])
-    w, u = dense_operands(layout, factors, mode)
-    kr = (w[:, None, :] * u[None, :, :]).reshape(-1, R)   # (span, R)
-    out = jax.lax.dot_general(
-        layout.tiles.astype(dtype), kr,
-        dimension_numbers=(((2,), (0,)), ((), ())),
-        preferred_element_type=_acc_dtype(dtype),
-        precision=mxu_precision(dtype))                   # (ntiles, tile, R)
-    return out.reshape(-1, R)[:layout.dim]
+    with jax.named_scope(OPERANDS):
+        w, u = dense_operands(layout, factors, mode)
+        kr = (w[:, None, :] * u[None, :, :]).reshape(-1, R)   # (span, R)
+        tiles = layout.tiles.astype(dtype)
+    with jax.named_scope(REDUCE):
+        out = jax.lax.dot_general(
+            tiles, kr,
+            dimension_numbers=(((2,), (0,)), ((), ())),
+            preferred_element_type=_acc_dtype(dtype),
+            precision=mxu_precision(dtype))               # (ntiles, tile, R)
+    with jax.named_scope(MERGE):
+        return out.reshape(-1, R)[:layout.dim]
 
 
 # -- blocked paths ---------------------------------------------------------
@@ -298,41 +319,42 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
     # encoded layouts.
     from splatt_tpu.blocked import decode_global_ids, decode_segment_ids
 
-    streams, bases, encs = layout.mode_streams()
-    streams = list(streams)
-    vals = layout.vals
-    row_start = layout.row_start
-    if nb_pad != nb:
-        # pad with whole sentinel blocks: mode index = dim (falls in the
-        # dropped tail rows; for v2 the BASE carries the sentinel and
-        # the stored locals stay 0 — an RLE pad block's count vector is
-        # [B, 0, ...], every entry in segment 0), other indices 0,
-        # values 0
-        pad = (nb_pad - nb) * B
-        for k, s in enumerate(streams):
-            if encs[k] == "rle":
-                s = jnp.pad(s, ((0, nb_pad - nb), (0, 0)))
-                streams[k] = s.at[nb:, 0].set(B)
-            else:
-                streams[k] = jnp.pad(
-                    s, (0, pad),
-                    constant_values=(layout.dim
-                                     if bases is None and k == mode
-                                     else 0))
-        vals = jnp.pad(vals, (0, pad))
-        row_start = jnp.pad(row_start, (0, nb_pad - nb),
-                            constant_values=layout.dim)
-        if bases is not None:
-            bases = [jnp.pad(b, (0, nb_pad - nb),
-                             constant_values=(layout.dim if k == mode
-                                              else 0))
-                     for k, b in enumerate(bases)]
+    with jax.named_scope(OPERANDS):
+        streams, bases, encs = layout.mode_streams()
+        streams = list(streams)
+        vals = layout.vals
+        row_start = layout.row_start
+        if nb_pad != nb:
+            # pad with whole sentinel blocks: mode index = dim (falls in the
+            # dropped tail rows; for v2 the BASE carries the sentinel and
+            # the stored locals stay 0 — an RLE pad block's count vector is
+            # [B, 0, ...], every entry in segment 0), other indices 0,
+            # values 0
+            pad = (nb_pad - nb) * B
+            for k, s in enumerate(streams):
+                if encs[k] == "rle":
+                    s = jnp.pad(s, ((0, nb_pad - nb), (0, 0)))
+                    streams[k] = s.at[nb:, 0].set(B)
+                else:
+                    streams[k] = jnp.pad(
+                        s, (0, pad),
+                        constant_values=(layout.dim
+                                         if bases is None and k == mode
+                                         else 0))
+            vals = jnp.pad(vals, (0, pad))
+            row_start = jnp.pad(row_start, (0, nb_pad - nb),
+                                constant_values=layout.dim)
+            if bases is not None:
+                bases = [jnp.pad(b, (0, nb_pad - nb),
+                                 constant_values=(layout.dim if k == mode
+                                                  else 0))
+                         for k, b in enumerate(bases)]
 
-    inds_s = tuple(s.reshape(nsteps, C, -1) for s in streams)
-    vals_s = vals.reshape(nsteps, C, B)
-    rs_s = row_start.reshape(nsteps, C)
-    base_s = (None if bases is None
-              else tuple(b.reshape(nsteps, C) for b in bases))
+        inds_s = tuple(s.reshape(nsteps, C, -1) for s in streams)
+        vals_s = vals.reshape(nsteps, C, B)
+        rs_s = row_start.reshape(nsteps, C)
+        base_s = (None if bases is None
+                  else tuple(b.reshape(nsteps, C) for b in bases))
 
     iota = jnp.arange(width, dtype=jnp.int32)
     acc = _acc_dtype(dtype)
@@ -342,57 +364,59 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
         # (C,B) vals, (C,) run starts, per-mode (C,) bases (None for
         # v1) — decoded here, in registers, via the shared helpers
         inds_c, vals_c, rs_c, base_c = xs
-        prod = vals_c.astype(dtype)[..., None]
-        for k in range(nmodes):
-            if k != mode:
-                # decode_global_ids handles every stream kind — incl.
-                # gathering the layout's SORTED mode (its segment/RLE
-                # stream expands here) when dispatching another mode
-                g = decode_global_ids(
-                    inds_c[k],
-                    None if base_c is None else base_c[k][:, None],
-                    encs[k], B)
-                rows = jnp.take(factors[k], g.reshape(-1), axis=0,
-                                mode="clip").reshape(C, B, R)
-                prod = prod * rows
-        if accumulate:
-            if base_c is None:
-                local = inds_c[mode]
-            else:
-                local = decode_global_ids(inds_c[mode],
-                                          base_c[mode][:, None],
-                                          encs[mode], B)
-        elif base_c is None:
-            local = inds_c[mode] - rs_c[:, None]
-        else:
-            local = decode_segment_ids(inds_c[mode], encs[mode], B)
-        if pallas:
-            from splatt_tpu.ops.pallas_kernels import (onehot_reduce_full,
-                                                       onehot_reduce_sorted,
-                                                       vmem_chunk)
-
-            kchunk = vmem_chunk(width, B, R, jnp.dtype(dtype).itemsize)
+        with jax.named_scope(OPERANDS):
+            prod = vals_c.astype(dtype)[..., None]
+            for k in range(nmodes):
+                if k != mode:
+                    # decode_global_ids handles every stream kind — incl.
+                    # gathering the layout's SORTED mode (its segment/RLE
+                    # stream expands here) when dispatching another mode
+                    g = decode_global_ids(
+                        inds_c[k],
+                        None if base_c is None else base_c[k][:, None],
+                        encs[k], B)
+                    rows = jnp.take(factors[k], g.reshape(-1), axis=0,
+                                    mode="clip").reshape(C, B, R)
+                    prod = prod * rows
             if accumulate:
-                return carry + onehot_reduce_full(
-                    local, prod, width, interpret=interpret,
-                    chunk=kchunk), None
-            return carry, onehot_reduce_sorted(local, prod, width,
-                                               interpret=interpret,
-                                               chunk=kchunk)
-        onehot = (local[:, None, :] == iota[None, :, None]).astype(dtype)
-        part = jnp.einsum("cwb,cbr->cwr", onehot, prod,
-                          preferred_element_type=acc,
-                          precision=onehot_precision(dtype, "lhs"))
-        if accumulate:
-            return carry + jnp.sum(part, axis=0), None
-        return carry, part
+                if base_c is None:
+                    local = inds_c[mode]
+                else:
+                    local = decode_global_ids(inds_c[mode],
+                                              base_c[mode][:, None],
+                                              encs[mode], B)
+            elif base_c is None:
+                local = inds_c[mode] - rs_c[:, None]
+            else:
+                local = decode_segment_ids(inds_c[mode], encs[mode], B)
+        with jax.named_scope(REDUCE):
+            if pallas:
+                from splatt_tpu.ops.pallas_kernels import (
+                    onehot_reduce_full, onehot_reduce_sorted, vmem_chunk)
+
+                kchunk = vmem_chunk(width, B, R, jnp.dtype(dtype).itemsize)
+                if accumulate:
+                    return carry + onehot_reduce_full(
+                        local, prod, width, interpret=interpret,
+                        chunk=kchunk), None
+                return carry, onehot_reduce_sorted(local, prod, width,
+                                                   interpret=interpret,
+                                                   chunk=kchunk)
+            onehot = (local[:, None, :] == iota[None, :, None]).astype(dtype)
+            part = jnp.einsum("cwb,cbr->cwr", onehot, prod,
+                              preferred_element_type=acc,
+                              precision=onehot_precision(dtype, "lhs"))
+            if accumulate:
+                return carry + jnp.sum(part, axis=0), None
+            return carry, part
 
     if accumulate:
         init = jnp.zeros((width, R), dtype=acc)
         out, _ = jax.lax.scan(step, init, (inds_s, vals_s, rs_s, base_s))
         return out
     _, parts = jax.lax.scan(step, None, (inds_s, vals_s, rs_s, base_s))
-    return parts.reshape(nb_pad, width, R)[:nb]
+    with jax.named_scope(MERGE):
+        return parts.reshape(nb_pad, width, R)[:nb]
 
 
 def _tuned_plan_for(layout: ModeLayout, factors: Sequence[jax.Array],
@@ -616,13 +640,18 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
             # propagate immediately to the demotion below.  The span
             # records host-side dispatch cost with the CHOSEN engine;
             # under a jitted sweep it fires at trace time, once per
-            # compilation (docs/observability.md).
+            # compilation (docs/observability.md).  Its attributes are
+            # built only when tracing is on: this runs once per mode
+            # per iteration, so the off path stays one boolean check.
             from splatt_tpu import trace
 
-            with trace.span("mttkrp.dispatch", mode=int(mode), path=path,
-                            engine=engine, block=int(layout.block),
-                            enc=getattr(layout, "format_desc",
-                                        lambda: "i32/glob/?")()):
+            span = (trace.span("mttkrp.dispatch", mode=int(mode),
+                               path=path, engine=engine,
+                               block=int(layout.block),
+                               enc=getattr(layout, "format_desc",
+                                           lambda: "i32/glob/?")())
+                    if trace.enabled() else trace.NOOP)
+            with span:
                 return resilience.retry_transient(attempt,
                                                   label=f"engine.{engine}")
         except Exception as e:
@@ -637,6 +666,16 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
 def _mttkrp_blocked_jit(layout: ModeLayout, factors: List[jax.Array],
                         mode: int, path: str, impl: str,
                         scan_target: int, engine: str) -> jax.Array:
+    with jax.named_scope(f"mttkrp.mode{mode}"):
+        return _mttkrp_phases(layout, factors, mode, path, impl,
+                              scan_target, engine)
+
+
+def _mttkrp_phases(layout: ModeLayout, factors: List[jax.Array],
+                   mode: int, path: str, impl: str,
+                   scan_target: int, engine: str) -> jax.Array:
+    """:func:`_mttkrp_blocked_jit`'s body, each op under one of the
+    :data:`PHASES` scopes."""
     from splatt_tpu.ops.pallas_kernels import fused_mttkrp_t, fused_mttkrp_tg
 
     dim = int(factors[mode].shape[0])
@@ -668,13 +707,16 @@ def _mttkrp_blocked_jit(layout: ModeLayout, factors: List[jax.Array],
         # per mode inside the same fusion (mode_ids/_gather_prod_layout).
         sorted_seg = (path == "sorted_scatter"
                       or (path not in ("scatter",) and mode == layout.mode))
-        prod = _gather_prod_layout(layout, factors, mode)
         nseg = dim + 1 if mode == layout.mode else dim
-        out = jax.ops.segment_sum(prod.astype(_acc_dtype(prod.dtype)),
-                                  layout.mode_ids(mode),
-                                  num_segments=nseg,
-                                  indices_are_sorted=sorted_seg)
-        return out[:dim]
+        with jax.named_scope(OPERANDS):
+            prod = _gather_prod_layout(layout, factors, mode)
+            prod = prod.astype(_acc_dtype(prod.dtype))
+            ids = layout.mode_ids(mode)
+        with jax.named_scope(REDUCE):
+            out = jax.ops.segment_sum(prod, ids, num_segments=nseg,
+                                      indices_are_sorted=sorted_seg)
+        with jax.named_scope(MERGE):
+            return out[:dim]
 
     # the resolved engine is a static arg: mttkrp_blocked walks the
     # engine_chain outside the jit, so a runtime demotion retraces with
@@ -684,17 +726,18 @@ def _mttkrp_blocked_jit(layout: ModeLayout, factors: List[jax.Array],
     if path == "privatized":
         width = -(-(dim + 1) // 8) * 8  # +1: room for the sentinel row
         if plan == "fused_t":
-            return fused_mttkrp_t(layout, factors, mode, width,
-                                  accumulate=True,
-                                  interpret=interpret)[:dim]
-        if plan == "fused_tg":
-            return fused_mttkrp_tg(layout, factors, mode, width,
-                                   accumulate=True,
-                                   interpret=interpret)[:dim]
-        return _scan_fused(layout, factors, mode, width,
-                           accumulate=True, target_elems=scan_target,
-                           pallas=plan == "unfused_pallas",
-                           interpret=interpret)[:dim]
+            out = fused_mttkrp_t(layout, factors, mode, width,
+                                 accumulate=True, interpret=interpret)
+        elif plan == "fused_tg":
+            out = fused_mttkrp_tg(layout, factors, mode, width,
+                                  accumulate=True, interpret=interpret)
+        else:
+            out = _scan_fused(layout, factors, mode, width,
+                              accumulate=True, target_elems=scan_target,
+                              pallas=plan == "unfused_pallas",
+                              interpret=interpret)
+        with jax.named_scope(MERGE):
+            return out[:dim]
 
     if path == "sorted_onehot":
         if mode != layout.mode:
@@ -712,10 +755,12 @@ def _mttkrp_blocked_jit(layout: ModeLayout, factors: List[jax.Array],
                                 target_elems=scan_target,
                                 pallas=plan == "unfused_pallas",
                                 interpret=interpret)    # (nb, S, R)
-        idx = (layout.row_start[:, None] + jnp.arange(S, dtype=jnp.int32)).reshape(-1)
-        out = jnp.zeros((dim + S + 1, R), dtype=parts.dtype)
-        out = out.at[idx].add(parts.reshape(-1, R))
-        return out[:dim]
+        with jax.named_scope(MERGE):
+            idx = (layout.row_start[:, None]
+                   + jnp.arange(S, dtype=jnp.int32)).reshape(-1)
+            out = jnp.zeros((dim + S + 1, R), dtype=parts.dtype)
+            out = out.at[idx].add(parts.reshape(-1, R))
+            return out[:dim]
 
     raise ValueError(f"unknown path {path!r}")
 

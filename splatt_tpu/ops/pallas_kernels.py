@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from splatt_tpu.ops.mttkrp import _acc_dtype, mxu_precision
+from splatt_tpu.ops.mttkrp import (MERGE, OPERANDS, REDUCE, _acc_dtype,
+                                   mxu_precision)
 from splatt_tpu.utils.env import ceil_to
 
 # Max blocks per grid step; the actual chunk is sized against VMEM by
@@ -152,6 +153,7 @@ def onehot_reduce_sorted(local: jax.Array, prod: jax.Array, seg_width: int,
         out_shape=jax.ShapeDtypeStruct((nb_pad, seg_width, R),
                                        _acc_dtype(prod.dtype)),
         interpret=interpret,
+        name="onehot_reduce_sorted",
         compiler_params=_compiler_params(),
     )(local, prod)
     return out[:nb]
@@ -354,8 +356,9 @@ def fused_mttkrp_t(layout, factors, mode: int, width: int,
     others = [k for k in range(layout.nmodes) if k != mode]
     grid = (nb,)
 
-    local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
-                                               accumulate)
+    with jax.named_scope(OPERANDS):
+        local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
+                                                   accumulate)
     ut_specs = [pl.BlockSpec(u.shape, lambda i: (0, 0)) for u in uts]
     gidx_specs = [pl.BlockSpec((1,) + g.shape[1:], lambda i: (i, 0, 0, 0))
                   for g in gidxs]
@@ -368,25 +371,28 @@ def fused_mttkrp_t(layout, factors, mode: int, width: int,
         out_spec = pl.BlockSpec((1, R8, width), lambda i: (i, 0, 0))
         out_shape = jax.ShapeDtypeStruct((nb, R8, width), acc)
 
-    out = pl.pallas_call(
-        functools.partial(_fused_t_kernel, width=width,
-                          accumulate=accumulate, nother=len(others)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
-            *gidx_specs,
-            *ut_specs,
-        ],
-        out_specs=out_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-    )(local, vals, *gidxs, *uts)
+    with jax.named_scope(REDUCE):
+        out = pl.pallas_call(
+            functools.partial(_fused_t_kernel, width=width,
+                              accumulate=accumulate, nother=len(others)),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+                pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
+                *gidx_specs,
+                *ut_specs,
+            ],
+            out_specs=out_spec,
+            out_shape=out_shape,
+            interpret=interpret,
+            name="fused_mttkrp_t",
+            compiler_params=_compiler_params(),
+        )(local, vals, *gidxs, *uts)
     # back to the (…, width, R) contract of the untransposed kernels
-    if accumulate:
-        return out.T[:, :R]
-    return jnp.swapaxes(out, 1, 2)[:, :, :R]
+    with jax.named_scope(MERGE):
+        if accumulate:
+            return out.T[:, :R]
+        return jnp.swapaxes(out, 1, 2)[:, :, :R]
 
 
 # -- sublane-tiled fused kernel (inner grid over rank tiles) ----------------
@@ -505,8 +511,9 @@ def fused_mttkrp_tg(layout, factors, mode: int, width: int,
     others = [k for k in range(layout.nmodes) if k != mode]
     grid = (n_rtiles, nb)     # nb fastest: table slices fetched per r-tile
 
-    local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
-                                               accumulate)
+    with jax.named_scope(OPERANDS):
+        local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
+                                                   accumulate)
     ut_specs = [pl.BlockSpec((_SUBLANE, u.shape[1]), lambda r, i: (r, 0))
                 for u in uts]
     gidx_specs = [pl.BlockSpec((1,) + g.shape[1:],
@@ -520,26 +527,29 @@ def fused_mttkrp_tg(layout, factors, mode: int, width: int,
         out_spec = pl.BlockSpec((1, _SUBLANE, width), lambda r, i: (i, r, 0))
         out_shape = jax.ShapeDtypeStruct((nb, R8, width), acc)
 
-    out = pl.pallas_call(
-        functools.partial(_fused_tg_kernel, width=width,
-                          accumulate=accumulate, nother=len(others)),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
-            pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
-            *gidx_specs,
-            *ut_specs,
-        ],
-        out_specs=out_spec,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((_SUBLANE, B), dtype)],
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-    )(local, vals, *gidxs, *uts)
+    with jax.named_scope(REDUCE):
+        out = pl.pallas_call(
+            functools.partial(_fused_tg_kernel, width=width,
+                              accumulate=accumulate, nother=len(others)),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
+                pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
+                *gidx_specs,
+                *ut_specs,
+            ],
+            out_specs=out_spec,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((_SUBLANE, B), dtype)],
+            interpret=interpret,
+            name="fused_mttkrp_tg",
+            compiler_params=_compiler_params(),
+        )(local, vals, *gidxs, *uts)
     # back to the (…, width, R) contract of the untransposed kernels
-    if accumulate:
-        return out.T[:, :R]
-    return jnp.swapaxes(out, 1, 2)[:, :, :R]
+    with jax.named_scope(MERGE):
+        if accumulate:
+            return out.T[:, :R]
+        return jnp.swapaxes(out, 1, 2)[:, :, :R]
 
 
 #: outcome of each capability probe, keyed by kernel name — "ok",
@@ -962,6 +972,7 @@ def onehot_reduce_full(local: jax.Array, prod: jax.Array, width: int,
         out_specs=pl.BlockSpec((width, R), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(prod.dtype)),
         interpret=interpret,
+        name="onehot_reduce_full",
         compiler_params=_compiler_params(),
     )(local, prod)
     return out
@@ -1030,24 +1041,28 @@ def fused_dense(layout, factors, mode: int,
         raise ValueError("fused_dense requires the layout's own mode")
     R = int(factors[0].shape[1])
     dtype = factors[0].dtype
-    w, u = dense_operands(layout, factors, mode)
+    with jax.named_scope(OPERANDS):
+        w, u = dense_operands(layout, factors, mode)
     ntiles, tile, span = (int(s) for s in layout.tiles.shape)
     acc = _acc_dtype(dtype)
-    out = pl.pallas_call(
-        functools.partial(_dense_kernel, rank=R,
-                          precision=mxu_precision(dtype)),
-        grid=(ntiles,),
-        in_specs=[
-            pl.BlockSpec((1, tile, span), lambda i: (i, 0, 0)),
-            pl.BlockSpec((int(w.shape[0]), R), lambda i: (0, 0)),
-            pl.BlockSpec((int(u.shape[0]), R), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile, R), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((ntiles, tile, R), acc),
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-    )(layout.tiles, w, u)
-    return out.reshape(-1, R)[:layout.dim]
+    with jax.named_scope(REDUCE):
+        out = pl.pallas_call(
+            functools.partial(_dense_kernel, rank=R,
+                              precision=mxu_precision(dtype)),
+            grid=(ntiles,),
+            in_specs=[
+                pl.BlockSpec((1, tile, span), lambda i: (i, 0, 0)),
+                pl.BlockSpec((int(w.shape[0]), R), lambda i: (0, 0)),
+                pl.BlockSpec((int(u.shape[0]), R), lambda i: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, tile, R), lambda i: (i, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((ntiles, tile, R), acc),
+            interpret=interpret,
+            name="fused_dense",
+            compiler_params=_compiler_params(),
+        )(layout.tiles, w, u)
+    with jax.named_scope(MERGE):
+        return out.reshape(-1, R)[:layout.dim]
 
 
 def _probe_case_dense(kernel_fn, regime: str, block: int) -> bool:

@@ -45,11 +45,18 @@ Exporters
 Overhead contract
     Spans are NO-OPS unless enabled (``SPLATT_TRACE`` /
     ``Options.trace`` / :func:`set_enabled`): one boolean check, no
-    allocation.  Enabled spans never sync the device (SPL003-clean —
-    they read ``perf_counter`` only; host blocking stays at the
-    existing fit-check syncs), and the bench trace A/B leg
-    (bench.py ``trace_ab``) measures enabled-but-unexported tracing on
-    the blocked path — the <2 % budget docs/observability.md documents.
+    allocation — and a hot-path caller computes a span's attributes
+    only when :func:`enabled` is true (``mttkrp.dispatch``).  Enabled
+    spans never sync the device (SPL003-clean — they read
+    ``perf_counter`` only; host blocking stays at the existing
+    fit-check syncs); what enabled tracing costs on the chip is
+    measured in docs/observability.md.
+
+Compile counters
+    :func:`count_jit` (registered by ``cpd_als``) counts JAX's traces,
+    lowerings and compiles per program into ``splatt_jit_total`` /
+    ``splatt_jit_seconds_total``; with tracing on, the ``cpd.als`` root
+    records the run's own share (``jit_s``, ``jit``).
 
 Flight recorder
     :func:`set_flight` arms a bounded, incrementally-appended ring of
@@ -98,7 +105,9 @@ from typing import Dict, List, Optional, Tuple
 #: family (``trace.span(f"timer.{name}")``).
 SPANS = {
     "cpd.als": "one cpd_als run end-to-end (attrs: rank, guard budget, "
-               "donation; the root every single-chip span nests under)",
+               "donation, and jit_s / jit: the JAX trace, lower and "
+               "compile work inside the run; the root every "
+               "single-chip span nests under)",
     "cpd.iter": "one ALS iteration — sweep dispatch through the commit "
                 "(attrs: it, fit at check iterations); per-iteration "
                 "sums reconcile with the driver's printed sec/iter",
@@ -278,6 +287,15 @@ METRICS = {
                      "(serve.py _run_update on ingest-chained specs) "
                      "— the live-feed freshness SLO of docs/"
                      "ingest.md"),
+    "splatt_jit_total": (
+        "counter", "JAX compile-path stages run, by stage (trace = "
+                   "jaxpr tracing, lower = MLIR lowering, compile = "
+                   "backend compile or a persistent-cache load) and "
+                   "program (JAX's function name); a stage nested in "
+                   "another counts in the outer one (count_jit)"),
+    "splatt_jit_seconds_total": (
+        "counter", "host seconds those stages took, by stage and "
+                   "program (JAX's own durations)"),
 }
 
 #: histogram bucket upper bounds (seconds); +Inf is implicit.  The
@@ -734,6 +752,91 @@ def _event_metrics(kind: str, info: dict) -> None:
         metric_inc("splatt_slo_burn_total",
                    slo=info.get("slo", "?"),
                    replica=info.get("replica"), **labels)
+
+
+#: JAX's compile-path duration events -> the ``stage`` label of the
+#: splatt_jit_* counters
+_JIT_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+               "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+               "/jax/core/compile/backend_compile_duration": "compile"}
+_JIT_LOCK = threading.Lock()
+_jit_counting = False
+#: per thread: compile-path stages open right now (JAX announces each
+#: stage's start as a scalar event and its end as a duration event)
+_JIT_OPEN = threading.local()
+
+
+def count_jit() -> None:
+    """Start counting JAX's traces, lowerings and compiles into
+    ``splatt_jit_total`` / ``splatt_jit_seconds_total`` (idempotent;
+    ``cpd_als`` calls it).  The listeners fire only when JAX traces or
+    compiles, never per dispatch, so they stay on like the other event
+    metrics.  A stage that runs inside another on the same thread (the
+    jnp helpers a jit traces through, an eager op while tracing) is
+    time of the outer one and is not counted again."""
+    global _jit_counting
+    with _JIT_LOCK:
+        if _jit_counting:
+            return
+        import jax
+
+        jax.monitoring.register_scalar_listener(_jit_stage_start)
+        jax.monitoring.register_event_duration_secs_listener(
+            _jit_stage_end)
+        _jit_counting = True
+
+
+def _jit_stage_start(event: str, value, **kw) -> None:
+    if event in _JIT_STAGES:
+        _JIT_OPEN.n = getattr(_JIT_OPEN, "n", 0) + 1
+
+
+def _jit_stage_end(event: str, secs: float, fun_name: str = "?",
+                   **kw) -> None:
+    stage = _JIT_STAGES.get(event)
+    if stage is None:
+        return
+    depth = getattr(_JIT_OPEN, "n", 0)
+    _JIT_OPEN.n = max(depth - 1, 0)
+    if depth > 1:
+        return
+    # lower/compile name the program "jit(f)", trace names it "f"
+    program = (fun_name[4:-1] if fun_name.startswith("jit(")
+               and fun_name.endswith(")") else fun_name)
+    metric_inc("splatt_jit_total", stage=stage, program=program)
+    metric_inc("splatt_jit_seconds_total", secs, stage=stage,
+               program=program)
+
+
+def jit_totals() -> Dict[str, List[float]]:
+    """``program -> [stage runs, seconds]`` counted so far by
+    :func:`count_jit`, all stages summed, for the active serve job
+    (samples without a job label outside one)."""
+    job = _job()
+    out: Dict[str, List[float]] = {}
+    with _MET_LOCK:
+        for (name, lk), v in _SAMPLES.items():
+            labels = dict(lk)
+            if labels.get("job") != job:
+                continue
+            i = {"splatt_jit_total": 0,
+                 "splatt_jit_seconds_total": 1}.get(name)
+            if i is not None:
+                out.setdefault(labels["program"], [0.0, 0.0])[i] += v
+    return out
+
+
+def jit_delta(before: Dict[str, List[float]]) -> dict:
+    """Span attributes for the compile work since `before` (a
+    :func:`jit_totals`): ``jit_s``, the seconds over every stage and
+    program, and ``jit``, ``program -> [stage runs, seconds]`` for the
+    programs that moved."""
+    moved = {}
+    for prog, (n, s) in jit_totals().items():
+        n0, s0 = before.get(prog, (0.0, 0.0))
+        if n > n0:
+            moved[prog] = [int(n - n0), s - s0]
+    return {"jit_s": sum(s for _, s in moved.values()), "jit": moved}
 
 
 def _fmt_labels(lk: Tuple[Tuple[str, str], ...]) -> str:

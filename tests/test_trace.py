@@ -338,6 +338,58 @@ def test_event_metrics_are_always_on_spans_are_not():
     assert trace.points() == []  # points gated with the spans
 
 
+def test_jit_counters_count_each_stage_once_per_compile():
+    """A fresh jit counts one trace, one lower and one compile under its
+    own program name (the jnp helpers it traces through count in it,
+    not again); calling it again counts nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    trace.count_jit()
+    x = jnp.ones(5)
+
+    def _jit_probe(v):
+        return v * 3.0 + 1.0
+
+    f = jax.jit(_jit_probe)
+    before = trace.metrics_snapshot()
+    f(x)
+    snap = trace.metrics_snapshot()
+    new = {k: v for k, v in snap.items() if before.get(k) != v}
+    for stage in ("trace", "lower", "compile"):
+        key = f'{{program="_jit_probe",stage="{stage}"}}'
+        assert new["splatt_jit_total" + key] == 1.0
+        assert new["splatt_jit_seconds_total" + key] > 0.0
+    assert sorted({k.split("{")[1] for k in new}) == [
+        'program="_jit_probe",stage="compile"}',
+        'program="_jit_probe",stage="lower"}',
+        'program="_jit_probe",stage="trace"}']
+    f(x)
+    assert trace.metrics_snapshot() == snap
+    assert trace.jit_totals()["_jit_probe"][0] == 3.0
+    _assert_prometheus_text(trace.metrics_text())
+
+
+def test_traced_cpd_records_its_compile_work():
+    """The cpd.als root carries the compile work inside the run: jit_s
+    and, per program, its stage runs and seconds."""
+    from splatt_tpu.blocked import BlockedSparse
+    from splatt_tpu.cpd import cpd_als
+
+    X = BlockedSparse.from_coo(_small_tensor(), _opts())
+    cpd_als(X, rank=3, opts=_opts(trace=True))
+    root = trace.spans("cpd.als")[-1]["args"]
+    assert root["jit_s"] > 0.0
+    assert root["jit_s"] == pytest.approx(
+        sum(s for _, s in root["jit"].values()))
+    # the sweep is jit-wrapped afresh by each call: traced, lowered and
+    # compiled inside it
+    assert root["jit"]["sweep"][0] >= 3
+    trace.reset()
+    cpd_als(X, rank=3, opts=_opts(trace=False))
+    assert trace.spans() == []
+
+
 def test_metrics_per_job_isolation():
     with resilience.scope("tenant-a"):
         resilience.run_report().add("health_rollback", iteration=1,
