@@ -300,7 +300,9 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
     onehot_reduce_sorted / onehot_reduce_full), which build the one-hot
     in VMEM instead of materializing it.  `target_elems` bounds the
     elements one step materializes in HBM: the one-hot for the einsum,
-    the (C, B, R) products for the kernels.
+    the (C, B, R) products for the kernels.  A scan step is a whole
+    number of kernel grid steps (pallas_kernels.dividing_chunk), so the
+    kernels never pad the step's products to a larger chunk.
     """
     nb, B = layout.nblocks, layout.block
     R = int(factors[0].shape[1])
@@ -392,9 +394,10 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
         with jax.named_scope(REDUCE):
             if pallas:
                 from splatt_tpu.ops.pallas_kernels import (
-                    onehot_reduce_full, onehot_reduce_sorted, vmem_chunk)
+                    dividing_chunk, onehot_reduce_full, onehot_reduce_sorted)
 
-                kchunk = vmem_chunk(width, B, R, jnp.dtype(dtype).itemsize)
+                kchunk = dividing_chunk(C, width, B, R,
+                                        jnp.dtype(dtype).itemsize)
                 if accumulate:
                     return carry + onehot_reduce_full(
                         local, prod, width, interpret=interpret,

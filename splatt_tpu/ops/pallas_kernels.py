@@ -83,6 +83,19 @@ def vmem_chunk(width: int, block: int, rank: int,
     return min(_CHUNK, budget_bytes // per_block)
 
 
+def dividing_chunk(nblocks: int, width: int, block: int, rank: int,
+                   itemsize: int = 4) -> int:
+    """Blocks per grid step for a call on exactly `nblocks` blocks: the
+    largest divisor of `nblocks` no larger than :func:`vmem_chunk`'s
+    cap, so the call is a whole number of grid steps and
+    :func:`_pad_blocks` copies nothing (a 10-block scan step under a
+    cap of 8 runs as 2 steps of 5, not 16 blocks with 6 of zeros).
+    0 exactly when :func:`vmem_chunk` is 0."""
+    cap = vmem_chunk(width, block, rank, itemsize)
+    return max((d for d in range(1, min(cap, nblocks) + 1)
+                if nblocks % d == 0), default=0)
+
+
 def _sorted_kernel(local_ref, prod_ref, out_ref, *, seg_width: int):
     local = local_ref[:, 0, :]                  # (C, B) int32
     prod = prod_ref[...]                        # (C, B, R)
@@ -121,7 +134,11 @@ def _full_kernel(local_ref, prod_ref, out_ref, *, width: int):
 def _pad_blocks(local: jax.Array, prod: jax.Array, chunk: int):
     """Pad to whole chunks; local gains a singleton middle dim so its
     Mosaic block shape (chunk, 1, B) is legal for any chunk (the last
-    two block dims must divide (8, 128) or equal the array dims)."""
+    two block dims must divide (8, 128) or equal the array dims).
+
+    The pad is a fresh HBM copy of `prod`; only direct callers pay it.
+    ``_scan_fused`` passes a :func:`dividing_chunk`, so its scan step
+    is a whole number of kernel steps and nothing is padded here."""
     nb = local.shape[0]
     nb_pad = ceil_to(max(nb, 1), chunk)
     if nb_pad != nb:

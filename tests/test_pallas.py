@@ -5,6 +5,8 @@ tests (tests/mttkrp_test.c) — interpret mode executes the exact kernel
 semantics that Mosaic compiles on TPU.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -309,19 +311,62 @@ def test_fused_tg_production_dims_interpret():
     assert err < 5e-5, err
 
 
-def test_scan_target_knob_changes_chunking_not_results():
-    """scan_target tunes the XLA engine's scan granularity (the
-    hardware sweep knob; default from SPLATT_SCAN_TARGET_ELEMS) as a
-    static jit argument — distinct values re-trace — without changing
-    the computed MTTKRP."""
+#: scan steps of 7, 10 and 11 blocks: none a multiple of the kernel's
+#: largest chunk (8), so each needs a kernel chunk dividing the step
+_UNEVEN_STEPS = (7, 10, 11)
+
+
+@pytest.mark.parametrize("engine,path", [("xla", "sorted_onehot"),
+                                         ("unfused_pallas", "sorted_onehot"),
+                                         ("unfused_pallas", "privatized")])
+def test_scan_target_knob_changes_chunking_not_results(engine, path):
+    """scan_target tunes the scan engines' step size (the hardware
+    sweep knob; default from SPLATT_SCAN_TARGET_ELEMS) as a static jit
+    argument — distinct values re-trace — without changing the computed
+    MTTKRP.  Under the Mosaic kernels (interpret mode) the steps are
+    sized to 7, 10 and 11 blocks, and no step's products are padded up
+    to a larger kernel chunk."""
     from splatt_tpu.blocked import build_layout
+    from splatt_tpu.ops.mttkrp import _mttkrp_blocked_jit, mttkrp_stream
 
     tt = gen.fixture_tensor("med")
     factors = make_factors(tt.dims)
-    lay = build_layout(tt, 0, block=128, val_dtype=np.float64)
-    want = np_mttkrp(tt, factors, 0)
-    for target in (1 << 10, 1 << 16, 1 << 24):
-        got = mttkrp_blocked(lay, factors, 0, path="sorted_onehot",
-                             impl="xla", scan_target=target)
-        np.testing.assert_allclose(np.asarray(got), want, atol=TOL,
-                                   err_msg=str(target))
+    R, B = factors[0].shape[1], 128
+    lay = build_layout(tt, 0 if path == "sorted_onehot" else 1, block=B,
+                       val_dtype=np.float64)
+    want = mttkrp_stream(jnp.asarray(tt.inds), jnp.asarray(tt.vals),
+                         factors, 0, tt.dims[0])
+    if engine == "xla":
+        targets = (1 << 10, 1 << 16, 1 << 24)
+    else:
+        targets = tuple(c * R * B for c in _UNEVEN_STEPS)
+    for target in targets:
+        if engine == "xla":
+            got = mttkrp_blocked(lay, factors, 0, path=path, impl="xla",
+                                 scan_target=target)
+        else:
+            args = (lay, factors, 0, path, "pallas_interpret", target,
+                    engine)
+            hlo = _mttkrp_blocked_jit.lower(*args).as_text()
+            assert not re.search(
+                rf"stablehlo\.pad .*-> tensor<\d+x{B}x{R}xf64>", hlo), target
+            got = _mttkrp_blocked_jit(*args)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=TOL, err_msg=str(target))
+
+
+@pytest.mark.parametrize("C,expect", [(10, 5), (40, 8), (81, 3), (7, 7),
+                                      (11, 1), (1, 1)])
+def test_dividing_chunk(C, expect):
+    """The kernel chunk of a C-block scan step: the largest divisor of C
+    within vmem_chunk's cap (8 at rank 200, block 4096), and 0 exactly
+    where vmem_chunk is 0 (the engine does not fit; dispatch falls back
+    to XLA)."""
+    from splatt_tpu.ops.pallas_kernels import dividing_chunk, vmem_chunk
+
+    assert vmem_chunk(8, 4096, 200) == 8
+    got = dividing_chunk(C, 8, 4096, 200)
+    assert got == expect
+    assert C % got == 0 and got <= 8
+    assert vmem_chunk(4096, 4096, 128) == 0
+    assert dividing_chunk(C, 4096, 4096, 128) == 0

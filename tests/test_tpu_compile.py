@@ -12,6 +12,7 @@ TPU loads libtpu, which one process at a time may hold.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,22 +97,28 @@ def spec_layout(sharding, mode, dims=NELL2, nnz=NNZ, seg=SEG):
     return dataclasses.replace(lay, seg_width=seg, nnz=nnz)
 
 
-def spec_factors(sharding, dims=NELL2):
-    return [jax.ShapeDtypeStruct((d, RANK), jnp.float32, sharding=sharding)
+def spec_factors(sharding, dims=NELL2, rank=RANK):
+    return [jax.ShapeDtypeStruct((d, rank), jnp.float32, sharding=sharding)
             for d in dims]
 
 
-@pytest.mark.parametrize("mode", [0, 1, 2])
-def test_unfused_pallas_engine_compiles(one_chip, mode):
+@pytest.mark.parametrize(
+    "mode,rank", [(m, r) for r in (RANK, 200) for m in range(3)],
+    ids=[f"{m}" for m in range(3)] + [f"{m}-r200" for m in range(3)])
+def test_unfused_pallas_engine_compiles(one_chip, mode, rank):
     """The engine the chip runs for NELL-2: a scan over block chunks,
-    XLA gather + Hadamard, Mosaic one-hot reduce."""
+    XLA gather + Hadamard, Mosaic one-hot reduce.  At rank 200 a scan
+    step holds 10 blocks, which the kernel takes as 2 grid steps of 5:
+    no step's (C, B, R) products are padded to a larger chunk."""
     from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _mttkrp_blocked_jit
 
     lay = spec_layout(one_chip, mode)
     compiled = _mttkrp_blocked_jit.lower(
-        lay, spec_factors(one_chip), mode, "sorted_onehot", "pallas",
-        _SCAN_TARGET, "unfused_pallas").compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        lay, spec_factors(one_chip, rank=rank), mode, "sorted_onehot",
+        "pallas", _SCAN_TARGET, "unfused_pallas").compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(rf"= f32\[\d+,{BLOCK},{rank}\]\S* pad\(", text)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 4 << 30
 
