@@ -34,8 +34,9 @@ from splatt_tpu.config import (Options, Verbosity, acc_dtype, default_opts,
                                resolve_dtype)
 from splatt_tpu.coo import SparseTensor
 from splatt_tpu.kruskal import KruskalTensor, post_process
-from splatt_tpu.ops.linalg import (form_normal_lhs, gram, normalize_columns,
-                                   solve_normals)
+from splatt_tpu.ops.linalg import (SOLVE_REPORT, form_normal_lhs, gram,
+                                   normalize_columns, solve_normals,
+                                   solve_normals_report)
 from splatt_tpu.ops.mttkrp import mttkrp, mttkrp_stream
 from splatt_tpu.utils.timers import timers
 
@@ -85,9 +86,71 @@ def _zz_inner(lam, grams, M, U_last):
     return jnp.sum(had, dtype=acc), inner
 
 
+class SweepResult(tuple):
+    """What a sweep returns: the tuple ``(factors, grams, lam, znormsq,
+    inner)``, carrying as ``.solve`` each mode's solve report in mode
+    order (:func:`~splatt_tpu.ops.linalg.solve_normals_report`: one
+    ``[pinv, cond, margin]`` device vector per mode), which
+    :func:`cpd_als` fetches with the fit.  A sweep wrapper that returns
+    a plain tuple simply reports no solves."""
+
+    def __new__(cls, out, solve):
+        self = super().__new__(cls, out)
+        self.solve = list(solve)
+        return self
+
+
+# a pytree node, so the jitted fused sweep returns one as it is
+jax.tree_util.register_pytree_node(
+    SweepResult, lambda r: ((*r, r.solve), None),
+    lambda _, c: SweepResult(c[:-1], c[-1]))
+
+
+class _SolveTally:
+    """The solve paths one :func:`cpd_als` call took, per mode, its
+    largest condition estimate and its smallest factorization margin,
+    read from the solve reports its fit checks fetched; each mode's
+    path also counts into ``splatt_solve_total``
+    (docs/observability.md)."""
+
+    def __init__(self):
+        self.counts = {"cholesky": [], "pinv": []}
+        self.worst = {}
+
+    def add(self, reports) -> dict:
+        """Count one sweep's fetched reports (``nmodes × [pinv, cond,
+        margin]``, flat or not); returns the sweep's largest condition
+        estimate and smallest margin (``cond_max``, ``margin_min``),
+        or nothing when it reported no solves."""
+        if not len(reports):
+            return {}
+        reports = np.asarray(reports).reshape(-1, len(SOLVE_REPORT))
+        for counts in self.counts.values():
+            counts.extend([0] * (len(reports) - len(counts)))
+        for m, (pinv, _, _) in enumerate(reports):
+            path = "pinv" if pinv > 0.5 else "cholesky"
+            self.counts[path][m] += 1
+            trace.metric_inc("splatt_solve_total", path=path, mode=m)
+        sweep = {"cond_max": float(reports[:, 1].max()),
+                 "margin_min": float(reports[:, 2].min())}
+        self.worst = {
+            "cond_max": max(sweep["cond_max"],
+                            self.worst.get("cond_max", 0.0)),
+            "margin_min": min(sweep["margin_min"],
+                              self.worst.get("margin_min", np.inf))}
+        return sweep
+
+    def attrs(self) -> dict:
+        """The ``cpd.als`` root span's attributes: ``solve`` (path ->
+        per-mode counts), and ``cond_max`` and ``margin_min`` once a
+        sweep reported."""
+        return {"solve": self.counts, **self.worst}
+
+
 def _make_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
                 reg: float, donate: bool = False) -> Callable:
-    """Build the jitted one-sweep function for this tensor.
+    """Build the jitted one-sweep function for this tensor; it returns
+    a :class:`SweepResult`.
 
     With `donate`, the factor/gram arguments are donated
     (``donate_argnums``): XLA aliases the output factor/gram buffers
@@ -103,18 +166,20 @@ def _make_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
     def sweep(factors, grams, first: bool):
         lam = None
         M = None
+        solve = []
         for m in range(nmodes):
             factor_dtype = factors[m].dtype
             M = do_mttkrp(factors, m)
             lhs = form_normal_lhs(grams, m, reg)
-            U = solve_normals(lhs, M)
+            U, info = solve_normals_report(lhs, M)
+            solve.append(info)
             U, lam = normalize_columns(U, "2" if first else "max")
             # mixed precision: factors stay in their (possibly bf16)
             # storage dtype; MTTKRP/Gram/solve accumulated in f32 above
             factors[m] = U.astype(factor_dtype)
             grams[m] = gram(factors[m])
         znormsq, inner = _zz_inner(lam, grams, M, factors[nmodes - 1])
-        return factors, grams, lam, znormsq, inner
+        return SweepResult((factors, grams, lam, znormsq, inner), solve)
 
     return jax.jit(sweep, static_argnames=("first",),
                    donate_argnums=(0, 1) if donate else ())
@@ -147,10 +212,10 @@ def _make_phased_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
     do_mttkrp = _mttkrp_closure(X)
 
     def update(grams, M, m: int, first: bool, factor_dtype):
-        U = solve_normals(form_normal_lhs(grams, m, reg), M)
+        U, info = solve_normals_report(form_normal_lhs(grams, m, reg), M)
         U, lam = normalize_columns(U, "2" if first else "max")
         U = U.astype(factor_dtype)
-        return U, lam, gram(U)
+        return U, lam, gram(U), info
 
     statics = ("m", "first", "factor_dtype")
     update_mid = jax.jit(update, static_argnames=statics,
@@ -166,15 +231,16 @@ def _make_phased_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
         factors = list(factors)
         grams = list(grams)
         lam = None
+        solve = [None] * nmodes
         for m in range(last):
             M = do_mttkrp(factors, m)
-            factors[m], lam, grams[m] = update_mid(
+            factors[m], lam, grams[m], solve[m] = update_mid(
                 grams, M, m, first, factors[m].dtype)
         M = do_mttkrp(factors, last)
-        factors[last], lam, grams[last] = update_last(
+        factors[last], lam, grams[last], solve[last] = update_last(
             grams, M, last, first, factors[last].dtype)
         znormsq, inner = fit_phase(lam, grams, M, factors[last])
-        return factors, grams, lam, znormsq, inner
+        return SweepResult((factors, grams, lam, znormsq, inner), solve)
 
     return sweep
 
@@ -192,7 +258,7 @@ def _make_profiled_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
 
     @partial(jax.jit, static_argnames=("m",))
     def solve_phase(grams, M, m: int):
-        return solve_normals(form_normal_lhs(grams, m, reg), M)
+        return solve_normals_report(form_normal_lhs(grams, m, reg), M)
 
     @partial(jax.jit, static_argnames=("first",))
     def normalize_phase(U, first: bool):
@@ -206,6 +272,7 @@ def _make_profiled_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
     def sweep(factors, grams, first: bool):
         lam = None
         M = None
+        solve = []
         for m in range(nmodes):
             factor_dtype = factors[m].dtype
             # per-mode timers at level 3: the CLI prints them in its own
@@ -214,7 +281,8 @@ def _make_profiled_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
             with timers.time("mttkrp"), timers.time(f"mttkrp_mode{m}"):
                 M = sync(do_mttkrp(factors, m))
             with timers.time("solve"):
-                U = sync(solve_phase(grams, M, m))
+                U, info = sync(solve_phase(grams, M, m))
+            solve.append(info)
             with timers.time("normalize"):
                 U, lam = sync(normalize_phase(U, first))
             factors[m] = U.astype(factor_dtype)
@@ -223,7 +291,7 @@ def _make_profiled_sweep(X: Union[SparseTensor, BlockedSparse], nmodes: int,
         with timers.time("fit"):
             znormsq, inner = sync(
                 fit_phase(lam, grams, M, factors[nmodes - 1]))
-        return factors, grams, lam, znormsq, inner
+        return SweepResult((factors, grams, lam, znormsq, inner), solve)
 
     return sweep
 
@@ -278,18 +346,22 @@ def _fit(xnormsq: float, znormsq: jax.Array, inner: jax.Array) -> jax.Array:
 # -- numerical-health sentinel (docs/guarded-als.md) ------------------------
 
 @jax.jit
-def _health_pack(factors, lam, fit):
+def _health_pack(factors, lam, fit, solve=()):
     """Fold the sentinel's finite-check reduction into ONE small device
     array: ``[fit, isfinite(U_0), ..., isfinite(U_{n-1}), isfinite(λ),
-    isfinite(fit)]`` (flags are 1.0/0.0 in fit's dtype).  The drivers
+    isfinite(fit), pinv_0, cond_0, margin_0, ..., pinv_{n-1},
+    cond_{n-1}, margin_{n-1}]`` (flags are 1.0/0.0 in fit's dtype); the
+    tail is the sweep's solve reports (:class:`SweepResult`), empty when
+    none are given.  The ALS loops
     fetch this at the fit-check host sync they already pay for, so the
-    sentinel adds no extra device round-trip."""
+    sentinel and the solve report add no extra device round-trip."""
     flags = [jnp.isfinite(U).all() for U in factors]
     flags.append(jnp.isfinite(lam).all())
     flags.append(jnp.isfinite(fit))
     fit = jnp.asarray(fit)
     return jnp.concatenate([fit.reshape(1),
-                            jnp.stack(flags).astype(fit.dtype)])
+                            jnp.stack(flags).astype(fit.dtype),
+                            *(r.astype(fit.dtype) for r in solve)])
 
 
 def _health_verdict(vec: np.ndarray, nmodes: int):
@@ -298,7 +370,7 @@ def _health_verdict(vec: np.ndarray, nmodes: int):
     isfinite flag tripped; λ/fit-only blowups report an empty list (the
     rollback then bumps regularization without re-randomizing)."""
     fitval = float(vec[0])
-    flags = np.asarray(vec[1:]) > 0.5
+    flags = np.asarray(vec[1:nmodes + 3]) > 0.5
     offending = [m for m in range(nmodes) if not flags[m]]
     healthy = bool(flags.all())
     return fitval, offending, healthy
@@ -589,21 +661,23 @@ def cpd_als(X: Union[SparseTensor, BlockedSparse], rank: int,
                         donate=opts.donate_sweep,
                         max_iterations=int(opts.max_iterations)) as root:
             jit0 = trace.jit_totals() if trace.enabled() else None
+            solves = _SolveTally()
             try:
                 return _cpd_als_traced(X, rank, opts, init,
                                        checkpoint_path, checkpoint_every,
-                                       resume, stop)
+                                       resume, stop, solves)
             finally:
                 if jit0 is not None:
-                    root.set(**trace.jit_delta(jit0))
+                    root.set(**trace.jit_delta(jit0), **solves.attrs())
 
 
 def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,
                     opts: Options, init, checkpoint_path,
                     checkpoint_every: int, resume: bool,
-                    stop) -> KruskalTensor:
+                    stop, solves: _SolveTally) -> KruskalTensor:
     """:func:`cpd_als` body, running inside the ``cpd.als`` root span
-    (and the run's tracing override) the public wrapper opened."""
+    (and the run's tracing override) the public wrapper opened; the
+    fit checks count each sweep's solve reports into `solves`."""
     if isinstance(X, SparseTensor):
         dims, nmodes = X.dims, X.nmodes
         xnormsq = X.normsq()
@@ -836,8 +910,9 @@ def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,
                     # host-side dispatch only: the device completes
                     # asynchronously and lands in the fit-check span
                     with trace.span("cpd.sweep"):
-                        f_new, g_new, lam_new, znormsq, inner = sweep(
-                            factors, grams, it == 0)
+                        out = sweep(factors, grams, it == 0)
+                    f_new, g_new, lam_new, znormsq, inner = out
+                    solve = getattr(out, "solve", [])
                     # chaos hook: a poison-armed cpd.sweep fault
                     # corrupts one sweep's factor output with
                     # non-finite values — the silent blowup the
@@ -857,8 +932,9 @@ def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,
                         # its own cpd.guard.health_pack child
                         with trace.span("cpd.fit_check", it=it + 1):
                             with trace.span("cpd.guard.health_pack"):
-                                packed = np.asarray(
-                                    _health_pack(f_new, lam_new, fit))
+                                packed = np.asarray(_health_pack(
+                                    f_new, lam_new, fit, solve))
+                        worst = solves.add(packed[nmodes + 3:])
                         fitval, offending, healthy = _health_verdict(
                             packed, nmodes)
                         if not healthy:
@@ -871,9 +947,15 @@ def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,
                             raise err
                     elif check:
                         # the one existing host sync batched device
-                        # work drains into (SPL003's sanctioned point)
+                        # work drains into (SPL003's sanctioned point);
+                        # with tracing on the solve reports ride the
+                        # same fetch
                         with trace.span("cpd.fit_check", it=it + 1):
-                            fitval = float(fit)
+                            fitval, reports = (
+                                jax.device_get((fit, solve))
+                                if trace.enabled() else (fit, []))
+                            fitval = float(fitval)
+                        worst = solves.add(reports)
                     else:
                         fitval = None
                     break
@@ -966,6 +1048,7 @@ def _cpd_als_traced(X: Union[SparseTensor, BlockedSparse], rank: int,
                     print(f"  its = {it + 1:3d} (deferred fit check)")
                 continue
             it_span.set(fit=fitval)
+            it_span.set(**worst)
             elapsed = time.perf_counter() - t0
             if snap is not None and guard > 0:
                 # refresh the rollback target only after a

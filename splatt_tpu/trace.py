@@ -105,12 +105,18 @@ from typing import Dict, List, Optional, Tuple
 #: family (``trace.span(f"timer.{name}")``).
 SPANS = {
     "cpd.als": "one cpd_als run end-to-end (attrs: rank, guard budget, "
-               "donation, and jit_s / jit: the JAX trace, lower and "
-               "compile work inside the run; the root every "
-               "single-chip span nests under)",
+               "donation, jit_s / jit: the JAX trace, lower and "
+               "compile work inside the run, and solve / cond_max / "
+               "margin_min: the normal-equation solves by path and "
+               "mode, the largest condition estimate and the smallest "
+               "factorization margin, over its fit checks; the root "
+               "every single-chip span nests under)",
     "cpd.iter": "one ALS iteration — sweep dispatch through the commit "
-                "(attrs: it, fit at check iterations); per-iteration "
-                "sums reconcile with the driver's printed sec/iter",
+                "(attrs: it, and at check iterations fit, cond_max and "
+                "margin_min, the sweep's largest condition estimate "
+                "and smallest factorization margin); "
+                "per-iteration sums reconcile with the printed "
+                "sec/iter",
     "cpd.sweep": "the sweep invocation of one iteration (host-side "
                  "dispatch; device completion lands in the fit fetch)",
     "cpd.build_sweep": "(re)building + jit-wrapping the sweep callable "
@@ -296,6 +302,11 @@ METRICS = {
     "splatt_jit_seconds_total": (
         "counter", "host seconds those stages took, by stage and "
                    "program (JAX's own durations)"),
+    "splatt_solve_total": (
+        "counter", "cpd_als normal-equation solves by path (cholesky, "
+                   "or pinv when the factorization failed: a "
+                   "numerically singular system) and mode, counted at "
+                   "fit checks from the reports the fit fetch carries"),
 }
 
 #: histogram bucket upper bounds (seconds); +Inf is implicit.  The

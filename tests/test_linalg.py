@@ -76,3 +76,106 @@ def test_normalize_zero_column_safe():
     out, lam = normalize_columns(U, "2")
     assert np.all(np.isfinite(np.asarray(out)))
     assert float(lam[0]) == 0.0
+
+
+def _spd_with_cond(n, cond, seed):
+    """A seeded n×n SPD matrix with eigenvalues log-spaced from 1 down
+    to 1/cond under a random rotation, in float64."""
+    rng = np.random.default_rng(seed)
+    q, _ = np.linalg.qr(rng.standard_normal((n, n)))
+    return (q * np.logspace(0, -np.log10(cond), n)) @ q.T
+
+
+@pytest.mark.parametrize("cond", [1e3, 1.3e4, 7.6e4, 1e6])
+def test_solve_normals_ill_conditioned_stays_least_squares(cond):
+    """An f32 SPD normal matrix at the condition numbers ALS meets on
+    skewed tensors (1.3e4-7.6e4 from the first max-norm iteration) is
+    solved to f32 accuracy: the per-row residual of ``M = X·lhs``
+    (float64), against the row's largest entry, the measure of
+    benchmark/reference.py."""
+    lhs = _spd_with_cond(50, cond, seed=11)
+    M = np.random.default_rng(12).random((64, 50)) @ lhs
+    X = solve_normals(jnp.asarray(lhs, jnp.float32),
+                      jnp.asarray(M, jnp.float32))
+    R = M - np.asarray(X, np.float64) @ lhs
+    resid = np.max(np.abs(R), axis=1) / np.max(np.abs(M), axis=1)
+    assert resid.max() <= 1e-5
+
+
+@pytest.mark.parametrize("cond", [1e3, 1e6])
+def test_solve_normals_report_cholesky_and_pivot_ratio(cond):
+    """The report of an SPD solve: Cholesky ran (pinv 0.0), and the
+    estimate is the pivot ratio, a lower bound on κ₂."""
+    from splatt_tpu.ops.linalg import solve_normals_report
+
+    lhs = _spd_with_cond(50, cond, seed=11).astype(np.float32)
+    _, report = solve_normals_report(jnp.asarray(lhs),
+                                     jnp.asarray(np.eye(50, dtype=np.float32)))
+    pinv, est, margin = np.asarray(report, np.float64)
+    assert pinv == 0.0
+    L = np.linalg.cholesky(lhs.astype(np.float64))
+    want = (np.diag(L).max() / np.diag(L).min()) ** 2
+    assert est == pytest.approx(want, rel=1e-3)
+    assert 1.0 <= est <= np.linalg.cond(lhs.astype(np.float64))
+    # the margin of the factorization test, min_i L_ii² / (n·eps·lhs_ii),
+    # off the f32 factor, whose smallest pivot rounding moves most
+    floor = 50 * np.finfo(np.float32).eps * np.diag(lhs).astype(np.float64)
+    assert margin == pytest.approx((np.diag(L) ** 2 / floor).min(),
+                                   rel=1e-2)
+    assert margin > 1.0
+
+
+def test_solve_normals_singular_with_positive_pivots_takes_pinv():
+    """A rank-deficient f32 normal matrix (the Hadamard product of two
+    Grams of rank 7 and 3, at rank 23) whose Cholesky factor rounding
+    leaves with every pivot positive still takes the pseudoinverse:
+    such a pivot is below the rounding floor of its diagonal entry.
+    Across the seeds some factors keep every pivot positive, so the
+    floor, not a NaN, is what catches them."""
+    from splatt_tpu.ops.linalg import solve_normals_report
+
+    positive = 0
+    for seed in range(12):
+        rng = np.random.default_rng(seed)
+        U1 = rng.random((7, 23)).astype(np.float32)
+        U2 = rng.random((3, 23)).astype(np.float32)
+        lhs = jnp.asarray((U1.T @ U1) * (U2.T @ U2))
+        d = np.diag(np.asarray(jnp.linalg.cholesky(lhs)))
+        positive += bool(np.all(np.isfinite(d)) and np.all(d > 0))
+        rhs = jnp.asarray(rng.random((5, 23)).astype(np.float32)) @ lhs
+        X, report = solve_normals_report(lhs, rhs)
+        assert float(report[0]) == 1.0
+        assert 0.0 <= float(report[2]) <= 1.0
+        assert np.all(np.isfinite(np.asarray(X)))
+        # the minimum-norm least-squares answer: consistent rhs is met
+        np.testing.assert_allclose(np.asarray(X @ lhs), np.asarray(rhs),
+                                   rtol=0, atol=1e-3 * float(
+                                       np.abs(np.asarray(rhs)).max()))
+    assert positive >= 1
+
+
+@pytest.mark.parametrize("seed", [21, 22, 24, 25, 26])
+def test_solve_normals_badly_scaled_singular_keeps_real_components(seed):
+    """A singular f32 normal matrix whose diagonal spans twelve orders
+    of magnitude, ``D·A·D`` with ``A`` of unit diagonal and rank n−1:
+    the factorization test refuses it, and the pseudoinverse path still
+    meets a consistent rhs in every column, each against its own
+    largest entry.  A cut relative to the largest eigenvalue alone drops
+    the directions of the small-diagonal columns; the Jacobi-scaled cut
+    drops only the null direction."""
+    from splatt_tpu.ops.linalg import solve_normals_report
+
+    n = 12
+    rng = np.random.default_rng(seed)
+    B = rng.standard_normal((n, n - 1))
+    A = B @ B.T
+    A /= np.sqrt(np.outer(np.diag(A), np.diag(A)))
+    D = np.logspace(-3, 3, n)
+    lhs = (A * np.outer(D, D)).astype(np.float32)
+    M = rng.standard_normal((32, n)) @ lhs.astype(np.float64)
+    X, report = solve_normals_report(jnp.asarray(lhs),
+                                     jnp.asarray(M, jnp.float32))
+    assert float(report[0]) == 1.0
+    R = M - np.asarray(X, np.float64) @ lhs.astype(np.float64)
+    resid = np.max(np.abs(R), axis=0) / np.max(np.abs(M), axis=0)
+    assert resid.max() <= 1e-4

@@ -224,3 +224,17 @@ def test_rdma_ring_kernels_compile_for_2x2(topo, kernel):
                            out_specs=P("x", None), check_vma=False))
     compiled = fn.lower(first, idx).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rank", [50, 200])
+def test_als_solve_compiles(one_chip, rank):
+    """The ALS update's normal-equation solve: Cholesky, and the
+    eigendecomposition pseudoinverse under a ``lax.cond`` on the
+    device, for NELL-2's longest mode."""
+    from splatt_tpu.ops.linalg import solve_normals_report
+
+    lhs = jax.ShapeDtypeStruct((rank, rank), jnp.float32, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((NELL2[2], rank), jnp.float32,
+                               sharding=one_chip)
+    compiled = jax.jit(solve_normals_report).lower(lhs, rhs).compile()
+    assert "conditional" in compiled.as_text()

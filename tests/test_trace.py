@@ -264,6 +264,115 @@ def test_traced_cpd_iteration_spans_reconcile(tmp_path):
     assert "guard overhead" in text and "iterations: 4 spans" in text
 
 
+def test_health_pack_layout_carries_solve_reports():
+    """The packed vector the fit check fetches: ``[fit, isfinite(U_0..
+    U_{n-1}), isfinite(λ), isfinite(fit)]`` then each mode's solve
+    report ``[pinv, cond, margin]`` in mode order; the verdict reads
+    only the flags."""
+    import jax.numpy as jnp
+
+    from splatt_tpu.cpd import _health_pack, _health_verdict
+
+    factors = [jnp.ones((4, 2)), jnp.ones((3, 2)), jnp.ones((5, 2))]
+    solve = [jnp.asarray([0.0, 12.5, 4e3]), jnp.asarray([1.0, 3e5, 0.5]),
+             jnp.asarray([0.0, 7.0, 9e4])]
+    vec = np.asarray(_health_pack(factors, jnp.ones(2),
+                                  jnp.asarray(0.25), solve))
+    np.testing.assert_array_equal(
+        vec, [0.25, 1, 1, 1, 1, 1, 0.0, 12.5, 4e3, 1.0, 3e5, 0.5,
+              0.0, 7.0, 9e4])
+    assert _health_verdict(vec, 3) == (0.25, [], True)
+    # without reports (run_distributed_als) the tail is empty
+    assert np.asarray(_health_pack(factors, jnp.ones(2),
+                                   jnp.asarray(0.25))).size == 6
+
+
+@pytest.mark.parametrize("path", ["cholesky", "pinv"])
+def test_solve_counter_and_span_attributes(path):
+    """Each fit check counts every mode's solve path into
+    splatt_solve_total{path,mode}; the cpd.iter spans carry the sweep's
+    largest condition estimate and smallest factorization margin, and
+    the cpd.als root the call's counts, largest estimate and smallest
+    margin.  An init whose first column is zero in every
+    mode keeps that column zero, so every normal matrix has a zero row:
+    singular, the pseudoinverse path."""
+    from splatt_tpu.blocked import BlockedSparse
+    from splatt_tpu.cpd import cpd_als
+
+    tt = _small_tensor()
+    X = BlockedSparse.from_coo(tt, _opts())
+    rng = np.random.default_rng(4)
+    init = [rng.random((d, 3)) for d in tt.dims]
+    if path == "pinv":
+        for U in init:
+            U[:, 0] = 0.0
+    cpd_als(X, rank=3, opts=_opts(trace=True, tolerance=0.0), init=init)
+    snap = trace.metrics_snapshot()
+    other = "pinv" if path == "cholesky" else "cholesky"
+    for m in range(3):
+        assert snap[f'splatt_solve_total{{mode="{m}",path="{path}"}}'] == 3
+        assert f'splatt_solve_total{{mode="{m}",path="{other}"}}' not in snap
+    root = trace.spans("cpd.als")[-1]["args"]
+    assert root["solve"] == {path: [3, 3, 3], other: [0, 0, 0]}
+    conds = [s["args"]["cond_max"] for s in trace.spans("cpd.iter")]
+    assert len(conds) == 3 and all(c >= 1.0 for c in conds)
+    assert root["cond_max"] == max(conds)
+    margins = [s["args"]["margin_min"] for s in trace.spans("cpd.iter")]
+    assert root["margin_min"] == min(margins)
+    assert all((m > 1.0) == (path == "cholesky") for m in margins)
+    _assert_prometheus_text(trace.metrics_text())
+
+
+@pytest.mark.parametrize("guard", ["3", "0"])
+def test_solve_reports_ride_the_fit_fetch(monkeypatch, guard):
+    """The solve reports add no host sync.  With the sentinel on they
+    ride its one packed fetch per check and count with tracing on or
+    off; with it off, tracing on fetches them in the fit's one
+    device_get, and tracing off leaves the fit fetch a scalar and
+    counts nothing."""
+    import jax
+
+    import splatt_tpu.cpd as cpd_mod
+    from splatt_tpu.blocked import BlockedSparse
+
+    calls = {"pack": 0, "get": 0, "block": 0}
+    real_pack, real_get = cpd_mod._health_pack, jax.device_get
+    real_block = jax.block_until_ready
+
+    def spy(key, real):
+        def f(*a, **k):
+            calls[key] += 1
+            return real(*a, **k)
+        return f
+
+    counts, solves = {}, {}
+    for enabled in (False, True):
+        X = BlockedSparse.from_coo(_small_tensor(), _opts())
+        trace.reset()
+        trace.reset_metrics()
+        for k in calls:
+            calls[k] = 0
+        monkeypatch.setenv("SPLATT_HEALTH_RETRIES", guard)
+        monkeypatch.setattr(cpd_mod, "_health_pack", spy("pack", real_pack))
+        monkeypatch.setattr(jax, "device_get", spy("get", real_get))
+        monkeypatch.setattr(jax, "block_until_ready",
+                            spy("block", real_block))
+        cpd_mod.cpd_als(X, rank=3, opts=_opts(trace=enabled, tolerance=0.0))
+        monkeypatch.undo()
+        counts[enabled] = dict(calls)
+        solves[enabled] = sum(v for k, v in trace.metrics_snapshot().items()
+                              if k.startswith("splatt_solve_total"))
+    assert counts[False]["block"] == counts[True]["block"]
+    if guard != "0":
+        assert counts[False] == counts[True]
+        assert counts[False]["pack"] == 3
+        assert solves == {False: 9, True: 9}
+    else:
+        assert counts[False]["pack"] == counts[True]["pack"] == 0
+        assert (counts[False]["get"], counts[True]["get"]) == (0, 3)
+        assert solves == {False: 0, True: 9}
+
+
 def test_summarize_self_time_subtracts_children():
     evs = [
         {"name": "cpd.als", "ph": "X", "ts": 0, "dur": 1000,
