@@ -652,7 +652,11 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
                                path=path, engine=engine,
                                block=int(layout.block),
                                enc=getattr(layout, "format_desc",
-                                           lambda: "i32/glob/?")())
+                                           lambda: "i32/glob/?")(),
+                               kernel_vmem=_kernel_vmem(
+                                   layout, factors, mode, path, engine,
+                                   scan_target),
+                               tables_vmem=_tables_vmem(factors, mode))
                     if trace.enabled() else trace.NOOP)
             with span:
                 return resilience.retry_transient(attempt,
@@ -727,7 +731,7 @@ def _mttkrp_phases(layout: ModeLayout, factors: List[jax.Array],
     plan = engine
 
     if path == "privatized":
-        width = -(-(dim + 1) // 8) * 8  # +1: room for the sentinel row
+        width = _onehot_width(layout, dim, path)
         if plan == "fused_t":
             out = fused_mttkrp_t(layout, factors, mode, width,
                                  accumulate=True, interpret=interpret)
@@ -822,6 +826,47 @@ def _engine_shape_key(layout: ModeLayout, factors: Sequence[jax.Array],
     return key
 
 
+def _onehot_width(layout: ModeLayout, dim: int, path: str) -> int:
+    """Output rows of one block's one-hot: the whole mode, 8-aligned,
+    on the privatized path (+1: room for the sentinel row), else the
+    layout's segment width."""
+    if path == "privatized":
+        return -(-(dim + 1) // 8) * 8
+    return layout.seg_width
+
+
+def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
+                 mode: int, path: str, engine: str,
+                 scan_target: int) -> int:
+    """Scoped VMEM `engine`'s Mosaic kernel asks for on this call: the
+    one-hot reduce's request at the chunk :func:`_scan_fused` gives it,
+    the device's whole ``vmem_limit`` for the fused kernels, 0 for the
+    XLA engines.  Shapes only: the dispatch span's ``kernel_vmem``."""
+    from splatt_tpu.ops import pallas_kernels as pk
+
+    if engine == "unfused_pallas":
+        R, B = int(factors[0].shape[1]), layout.block
+        dtype = factors[0].dtype
+        width = _onehot_width(layout, int(factors[mode].shape[0]), path)
+        chunk = pk.dividing_chunk(
+            _block_chunks(layout.nblocks, R * B, scan_target), width, B, R,
+            jnp.dtype(dtype).itemsize)
+        return pk.onehot_vmem_request(chunk, width, B, R, dtype)
+    if engine in ("fused_t", "fused_tg", "fused_dense"):
+        return pk._vmem_limit()
+    return 0
+
+
+def _tables_vmem(factors: Sequence[jax.Array], mode: int) -> int:
+    """Tile-padded VMEM bytes of the factor tables `mode` gathers: with
+    ``kernel_vmem``, what shows whether the scan's tables can stay
+    on-chip.  Shapes only: the dispatch span's ``tables_vmem``."""
+    from splatt_tpu.ops.pallas_kernels import padded_bytes
+
+    return sum(padded_bytes(f.shape, jnp.dtype(f.dtype).itemsize)
+               for k, f in enumerate(factors) if k != mode)
+
+
 def _engine_probed_ok(engine: str, regime: str, block: int,
                       interpret: bool) -> bool:
     """Capability gate of one chain candidate, probed LAZILY: each
@@ -899,10 +944,7 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
     interp = impl == "pallas_interpret"
     gather = pallas and (interp or all(
         int(f.shape[0]) <= 128 for k, f in enumerate(factors) if k != mode))
-    if path == "privatized":
-        width = -(-(dim + 1) // 8) * 8
-    else:
-        width = layout.seg_width
+    width = _onehot_width(layout, dim, path)
     if shape_key is None:
         shape_key = _engine_shape_key(layout, factors, mode)
 

@@ -41,8 +41,8 @@ _CHUNK = 8
 
 @functools.cache
 def _vmem_limit() -> int:
-    """Scoped VMEM the kernels request (splatt_tpu/devices.py): the
-    device's own row on a TPU, the kernels' target chip in interpret
+    """Most scoped VMEM a kernel may request (splatt_tpu/devices.py):
+    the device's own row on a TPU, the kernels' target chip in interpret
     mode."""
     from splatt_tpu.devices import DEVICE_SPECS, KERNEL_TARGET, device_spec
 
@@ -94,6 +94,53 @@ def dividing_chunk(nblocks: int, width: int, block: int, rank: int,
     cap = vmem_chunk(width, block, rank, itemsize)
     return max((d for d in range(1, min(cap, nblocks) + 1)
                 if nblocks % d == 0), default=0)
+
+
+def padded_bytes(shape, itemsize: int) -> int:
+    """VMEM bytes of an array of `shape`: its last two dims padded to
+    the dtype's (sublane, 128-lane) tile — 8 sublanes at 32 bits, 16 at
+    16 bits."""
+    *lead, rows, lanes = shape
+    n = (ceil_to(rows, 8 * max(1, 4 // itemsize)) * ceil_to(lanes, 128)
+         * itemsize)
+    for d in lead:
+        n *= d
+    return n
+
+
+#: room above :func:`onehot_vmem_request`'s counted buffers
+_ONEHOT_VMEM_MARGIN = 4 << 20
+
+
+def onehot_vmem_request(chunk: int, width: int, block: int, rank: int,
+                        dtype) -> int:
+    """Scoped VMEM the one-hot reduce kernels ask Mosaic for at a grid
+    step of `chunk` blocks: the double-buffered input blocks (local ids
+    (chunk, 1, block) i32, products (chunk, block, rank)), the
+    double-buffered output block (chunk, width, rank) at the accumulator
+    dtype (an upper bound for onehot_reduce_full's (width, rank)), the
+    in-kernel one-hot (chunk, width, block), the i32 iota it is compared
+    against and the dot result, each tile-padded, plus
+    :data:`_ONEHOT_VMEM_MARGIN`; at most the device's ``vmem_limit``.
+    What a kernel does not reserve stays with XLA, which keeps the
+    scan's live buffers (the gathered factor tables) in VMEM when they
+    fit."""
+    item = jnp.dtype(dtype).itemsize
+    acc = jnp.dtype(_acc_dtype(dtype)).itemsize
+    out = padded_bytes((chunk, width, rank), acc)
+    blocks = 2 * (padded_bytes((chunk, 1, block), 4)
+                  + padded_bytes((chunk, block, rank), item) + out)
+    temps = (padded_bytes((chunk, width, block), item)
+             + padded_bytes((chunk, width, block), 4) + out)
+    return min(blocks + temps + _ONEHOT_VMEM_MARGIN, _vmem_limit())
+
+
+def _onehot_compiler_params(chunk: int, width: int, block: int, rank: int,
+                            dtype):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(vmem_limit_bytes=onehot_vmem_request(
+        chunk, width, block, rank, dtype))
 
 
 def _sorted_kernel(local_ref, prod_ref, out_ref, *, seg_width: int):
@@ -171,7 +218,8 @@ def onehot_reduce_sorted(local: jax.Array, prod: jax.Array, seg_width: int,
                                        _acc_dtype(prod.dtype)),
         interpret=interpret,
         name="onehot_reduce_sorted",
-        compiler_params=_compiler_params(),
+        compiler_params=_onehot_compiler_params(chunk, seg_width, B, R,
+                                                prod.dtype),
     )(local, prod)
     return out[:nb]
 
@@ -990,7 +1038,8 @@ def onehot_reduce_full(local: jax.Array, prod: jax.Array, width: int,
         out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(prod.dtype)),
         interpret=interpret,
         name="onehot_reduce_full",
-        compiler_params=_compiler_params(),
+        compiler_params=_onehot_compiler_params(chunk, width, B, R,
+                                                prod.dtype),
     )(local, prod)
     return out
 

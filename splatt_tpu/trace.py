@@ -141,7 +141,10 @@ SPANS = {
     "mttkrp.dispatch": "one blocked-MTTKRP engine-chain dispatch "
                        "(attrs: mode, path, block, chosen engine, and "
                        "enc — the consumed layout encoding, e.g. "
-                       "u16/seg/bf16, docs/format.md); under a jitted "
+                       "u16/seg/bf16, docs/format.md; kernel_vmem and "
+                       "tables_vmem — the kernel's scoped-VMEM request "
+                       "and the gathered tables' padded bytes, "
+                       "docs/observability.md); under a jitted "
                        "sweep this records trace-time, once per "
                        "compilation",
     "tune.measure": "one autotuner candidate measurement (warm + "

@@ -1,8 +1,9 @@
 """The MTTKRP's observability surface (docs/observability.md).
 
 - Tracing off costs the dispatch path nothing: ``mttkrp.dispatch``
-  builds its attributes (``enc`` and the rest) only when tracing is on,
-  and a layout's format summary reads dtypes, never a device array.
+  builds its attributes (``enc``, ``kernel_vmem``, ``tables_vmem`` and
+  the rest) only when tracing is on, and a layout's format summary
+  reads dtypes, never a device array.
 - Every path and engine names its phases: the lowered program carries
   ``mttkrp.mode<m>`` and the ``mttkrp.operands`` / ``mttkrp.reduce`` /
   ``mttkrp.merge`` scopes in its op locations, which is what a device
@@ -64,10 +65,54 @@ def test_dispatch_with_tracing_off_never_builds_attributes(monkeypatch):
     trace.set_enabled(True)
     mk.mttkrp_blocked(lay, factors, 0, path="sorted_onehot")
     (span,) = trace.spans("mttkrp.dispatch")
-    assert span["args"] == {"mode": 0, "path": "sorted_onehot",
-                            "engine": span["args"]["engine"],
-                            "block": lay.block, "enc": lay.format_desc()}
-    assert span["args"]["engine"] in mk.engine_chain(lay, factors, 0)
+    engine = span["args"]["engine"]
+    assert span["args"] == {
+        "mode": 0, "path": "sorted_onehot", "engine": engine,
+        "block": lay.block, "enc": lay.format_desc(),
+        "kernel_vmem": mk._kernel_vmem(lay, factors, 0, "sorted_onehot",
+                                       engine, mk._SCAN_TARGET),
+        "tables_vmem": mk._tables_vmem(factors, 0)}
+    assert engine in mk.engine_chain(lay, factors, 0)
+
+
+@pytest.mark.parametrize("path,layout_mode", [("sorted_onehot", 0),
+                                              ("privatized", 1)])
+def test_dispatch_vmem_attributes_only_with_tracing_on(monkeypatch, path,
+                                                       layout_mode):
+    """``kernel_vmem`` is the one-hot kernel's reservation at the chunk
+    the scan gives it; ``tables_vmem`` the tile-padded bytes of the
+    tables the mode gathers.  Neither is computed with tracing off."""
+    from splatt_tpu.ops import pallas_kernels as pk
+
+    tt, bs = _blocked()
+    lay = bs.layout_for(layout_mode)
+    factors = make_factors(tt.dims)
+    monkeypatch.setattr(mk, "engine_chain",
+                        lambda *a, **k: ["unfused_pallas", "xla"])
+
+    def refuse(*a, **k):
+        raise AssertionError("a VMEM attribute was built with tracing off")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(mk, "_kernel_vmem", refuse)
+        mp.setattr(mk, "_tables_vmem", refuse)
+        mk.mttkrp_blocked(lay, factors, 0, path=path,
+                          impl="pallas_interpret")
+    assert trace.spans() == []
+
+    trace.set_enabled(True)
+    mk.mttkrp_blocked(lay, factors, 0, path=path, impl="pallas_interpret")
+    (span,) = trace.spans("mttkrp.dispatch")
+    R, B = factors[0].shape[1], lay.block
+    width = (lay.seg_width if path == "sorted_onehot"
+             else -(-(tt.dims[0] + 1) // 8) * 8)
+    C = min(mk._SCAN_TARGET // (R * B), lay.nblocks)
+    chunk = pk.dividing_chunk(C, width, B, R, 8)
+    assert span["args"]["engine"] == "unfused_pallas"
+    assert span["args"]["kernel_vmem"] == pk.onehot_vmem_request(
+        chunk, width, B, R, factors[0].dtype) > 0
+    assert span["args"]["tables_vmem"] == sum(
+        -(-d // 8) * 8 * -(-R // 128) * 128 * 8 for d in tt.dims[1:])
 
 
 class _NoIndexing:

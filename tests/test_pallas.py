@@ -14,6 +14,7 @@ import pytest
 
 from splatt_tpu.blocked import BlockedSparse
 from splatt_tpu.config import BlockAlloc, Options
+from splatt_tpu.ops import pallas_kernels as pk
 from splatt_tpu.ops.mttkrp import mttkrp, mttkrp_blocked
 from splatt_tpu.ops.pallas_kernels import (onehot_reduce_full,
                                            onehot_reduce_sorted)
@@ -54,6 +55,38 @@ def test_onehot_reduce_full_kernel():
                              interpret=True)
     want = _np_onehot_sorted(local, prod, W).sum(axis=0)
     np.testing.assert_allclose(np.asarray(got), want, atol=TOL)
+
+
+def test_onehot_vmem_request_counts_tile_padding():
+    # rank 200 pads to 256 lanes: a (5, 4096, 200) f32 products block,
+    # double-buffered, is 40 MiB on its own
+    assert pk.padded_bytes((5, 4096, 200), 4) == 5 * 4096 * 256 * 4
+    assert pk.padded_bytes((5, 1, 4096), 4) == 5 * 8 * 4096 * 4
+    assert pk.padded_bytes((3, 16), 2) == 16 * 128 * 2
+    req = pk.onehot_vmem_request(5, 8, 4096, 200, jnp.float32)
+    assert req >= 2 * pk.padded_bytes((5, 4096, 200), 4) >= 40 << 20
+    # ...and asks for less than the flat limit it replaces
+    assert req < pk._vmem_limit()
+
+
+@pytest.mark.parametrize("width,dtype", [(8, jnp.float32),
+                                         (16, jnp.bfloat16)])
+def test_onehot_vmem_request_grows_with_chunk_and_rank(width, dtype):
+    by_chunk = [pk.onehot_vmem_request(c, width, 4096, 50, dtype)
+                for c in range(1, 9)]
+    assert by_chunk == sorted(set(by_chunk))
+    # 50, 128, 200 and 256 lanes pad to 128, 128, 256 and 256
+    by_rank = [pk.onehot_vmem_request(4, width, 4096, r, dtype)
+               for r in (50, 128, 200, 256, 300)]
+    assert by_rank[0] == by_rank[1] < by_rank[2] == by_rank[3] < by_rank[4]
+
+
+def test_onehot_vmem_request_is_capped_at_vmem_limit(monkeypatch):
+    limit = pk._vmem_limit()
+    assert pk.onehot_vmem_request(8, 2048, 4096, 1024, jnp.float32) == limit
+    monkeypatch.setattr(pk, "_vmem_limit", lambda: 8 << 20)
+    assert pk.onehot_vmem_request(5, 8, 4096, 200, jnp.float32) == 8 << 20
+    assert pk.onehot_vmem_request(1, 8, 128, 8, jnp.float32) < 8 << 20
 
 
 @pytest.mark.parametrize("name", ["med", "med4"])

@@ -4,8 +4,8 @@ The TPU compiler is installed here and compiles for a described chip
 that is not attached, so what Mosaic would refuse on the chip (VMEM
 over the limit, unaligned blocks, unsupported gathers) fails here, at
 no chip time.  Shapes are NELL-2's (12092 x 9184 x 28818, 76.9M nnz)
-at rank 50 and the default nnz_block; nothing runs, so nothing about
-results or times is tested.
+at ranks 50 and 200 and the default nnz_block; nothing runs, so
+nothing about results or times is tested.
 
 The topology is described only inside the module fixture: a described
 TPU loads libtpu, which one process at a time may hold.
@@ -102,20 +102,36 @@ def spec_factors(sharding, dims=NELL2, rank=RANK):
             for d in dims]
 
 
-@pytest.mark.parametrize(
-    "mode,rank", [(m, r) for r in (RANK, 200) for m in range(3)],
-    ids=[f"{m}" for m in range(3)] + [f"{m}-r200" for m in range(3)])
-def test_unfused_pallas_engine_compiles(one_chip, mode, rank):
+MODE_RANKS = [(m, r) for r in (RANK, 200) for m in range(3)]
+MODE_RANK_IDS = [f"{m}" for m in range(3)] + [f"{m}-r200" for m in range(3)]
+
+
+@pytest.fixture(scope="module")
+def engine_program(one_chip):
+    """The compiled unfused_pallas engine per (mode, rank), compiled
+    once for the tests that read it."""
+    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _mttkrp_blocked_jit
+
+    done = {}
+
+    def compiled(mode, rank):
+        if (mode, rank) not in done:
+            done[mode, rank] = _mttkrp_blocked_jit.lower(
+                spec_layout(one_chip, mode),
+                spec_factors(one_chip, rank=rank), mode, "sorted_onehot",
+                "pallas", _SCAN_TARGET, "unfused_pallas").compile()
+        return done[mode, rank]
+
+    return compiled
+
+
+@pytest.mark.parametrize("mode,rank", MODE_RANKS, ids=MODE_RANK_IDS)
+def test_unfused_pallas_engine_compiles(engine_program, mode, rank):
     """The engine the chip runs for NELL-2: a scan over block chunks,
     XLA gather + Hadamard, Mosaic one-hot reduce.  At rank 200 a scan
     step holds 10 blocks, which the kernel takes as 2 grid steps of 5:
     no step's (C, B, R) products are padded to a larger chunk."""
-    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _mttkrp_blocked_jit
-
-    lay = spec_layout(one_chip, mode)
-    compiled = _mttkrp_blocked_jit.lower(
-        lay, spec_factors(one_chip, rank=rank), mode, "sorted_onehot",
-        "pallas", _SCAN_TARGET, "unfused_pallas").compile()
+    compiled = engine_program(mode, rank)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not re.search(rf"= f32\[\d+,{BLOCK},{rank}\]\S* pad\(", text)
@@ -123,24 +139,68 @@ def test_unfused_pallas_engine_compiles(one_chip, mode, rank):
     assert mem.temp_size_in_bytes < 4 << 30
 
 
-@pytest.mark.parametrize("kernel,width", [("sorted", SEG),
-                                          ("full", 1024)])
-def test_onehot_reduce_compiles(one_chip, kernel, width):
-    """The one-hot reduce kernels at one scan step's shapes (the chunk
-    _scan_fused takes at rank 50); the full kernel at onehot_cap."""
-    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
-    from splatt_tpu.ops.pallas_kernels import (onehot_reduce_full,
-                                               onehot_reduce_sorted,
-                                               vmem_chunk)
+def _while_state(text):
+    """The operand tuple of the program's scan loop, ``%while = (...)``."""
+    m = re.search(r"%while[.\d]* = \((.*?)\) while\(", text)
+    assert m, "no while loop in the compiled program"
+    return m.group(1)
 
-    C = _block_chunks(-(-NNZ // BLOCK), RANK * BLOCK, _SCAN_TARGET)
+
+@pytest.mark.parametrize("mode,rank", MODE_RANKS, ids=MODE_RANK_IDS)
+def test_gathered_tables_stay_in_vmem(engine_program, mode, rank):
+    """Every factor table the mode gathers sits in VMEM (memory space
+    S(1)) in the scan loop's state: the one-hot kernel reserves only
+    what its blocks need, so at rank 200 the 28818-row table fits
+    beside the other one.  Under a kernel that asks for the whole
+    vmem_limit it stays in HBM in modes 0 and 1."""
+    state = _while_state(engine_program(mode, rank).as_text())
+    for k, d in enumerate(NELL2):
+        if k != mode:
+            (layout,) = re.findall(rf"f32\[{d},{rank}\]\{{([^}}]*)\}}",
+                                   state)
+            assert layout.endswith("S(1)"), (d, layout)
+
+
+def _scoped_sizes(text, name):
+    """Scoped-VMEM bytes each compiled custom call `name` asks for."""
+    return [int(size) for line in text.splitlines()
+            if f"%{name}" in line and "tpu_custom_call" in line
+            for size in re.findall(
+                r'"scoped_memory_configs":\[\{[^]]*?"size":"(\d+)"', line)]
+
+
+@pytest.mark.parametrize(
+    "kernel,width,rank",
+    [("sorted", SEG, RANK), ("full", 1024, RANK),
+     ("sorted", SEG, 200), ("full", 1024, 200)],
+    ids=["sorted-8", "full-1024", "sorted-8-r200", "full-1024-r200"])
+def test_onehot_reduce_compiles(one_chip, kernel, width, rank):
+    """The one-hot reduce kernels at one scan step's shapes and the
+    chunk _scan_fused gives them (the full kernel at onehot_cap), fed
+    by fusions as in the scan step, compile under exactly
+    onehot_vmem_request's reservation, which stays below the device's
+    vmem_limit.  (Fed straight from parameters, a kernel is not held to
+    its reservation at compile time.)"""
+    from splatt_tpu.devices import DEVICE_SPECS
+    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
+    from splatt_tpu.ops.pallas_kernels import (dividing_chunk,
+                                               onehot_reduce_full,
+                                               onehot_reduce_sorted,
+                                               onehot_vmem_request)
+
+    C = _block_chunks(-(-NNZ // BLOCK), rank * BLOCK, _SCAN_TARGET)
     local = jax.ShapeDtypeStruct((C, BLOCK), jnp.int32, sharding=one_chip)
-    prod = jax.ShapeDtypeStruct((C, BLOCK, RANK), jnp.float32,
+    prod = jax.ShapeDtypeStruct((C, BLOCK, rank), jnp.float32,
                                 sharding=one_chip)
-    chunk = vmem_chunk(width, BLOCK, RANK)
+    chunk = dividing_chunk(C, width, BLOCK, rank)
     assert chunk >= 1
+    want = onehot_vmem_request(chunk, width, BLOCK, rank, jnp.float32)
+    kind = next(iter(one_chip.device_set)).device_kind
+    assert want < DEVICE_SPECS[kind].vmem_limit
     fn = onehot_reduce_sorted if kernel == "sorted" else onehot_reduce_full
-    fn.lower(local, prod, width, chunk=chunk).compile()
+    text = jax.jit(lambda l, p: fn(l + 1, p * 2, width, chunk=chunk)).lower(
+        local, prod).compile().as_text()
+    assert _scoped_sizes(text, f"onehot_reduce_{kernel}") == [want]
 
 
 def _fused(name):
