@@ -1212,7 +1212,7 @@ def main(gate: bool = False) -> None:
                       and (jax.default_backend() == "tpu" or native)))
         if isinstance(X, BlockedSparse):
             # name the dispatch plan in the log: the TPU number is only
-            # interpretable knowing which engine (fused_t/fused_tg/
+            # interpretable knowing which engine (unfused_pallas/
             # xla_scan/native) actually ran.  Inside a FUSED whole-sweep
             # trace the host-only native engine cannot run (tracer
             # inputs) — say so rather than mislabel the measurement.

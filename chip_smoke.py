@@ -167,7 +167,7 @@ def check_mttkrp(tt, bs, factors, seed: int) -> dict:
     return plans
 
 
-PALLAS_ENGINES = ("fused_t", "fused_tg", "unfused_pallas", "fused_dense")
+PALLAS_ENGINES = ("unfused_pallas", "fused_dense")
 
 
 def check_engines(plans: dict) -> None:

@@ -202,9 +202,10 @@ def mttkrp_bytes(alg: str, tt: SparseTensor, rank: int, mode: int,
       sum, no intermediate;
     - blocked (one-hot, xla_scan engine): block partials (nb, S, R)
       written then scatter-combined (read+write);
-    - blocked_pallas fused engines: the factor TABLES stream once
-      (VMEM-resident) instead of once per nonzero — the design's
-      whole premise — plus the same partials;
+    - blocked_pallas: the factor TABLES stream once (VMEM-resident)
+      instead of once per nonzero, plus the same partials — the
+      premise of in-kernel gather engines; ``unfused_pallas`` gathers
+      rows per nonzero in XLA, so this undercounts its traffic;
     - ttbox: one full index+value pass per rank column.
     """
     nnz = tt.nnz
@@ -278,29 +279,17 @@ def mttkrp_decode_bytes(X: BlockedSparse, rank: int, mode: int,
     traffic the in-kernel decode line exists to delete.  Zero for v1
     layouts and for the stream-native engines
     (:data:`splatt_tpu.ops.mttkrp.STREAM_NATIVE_ENGINES`: xla_scan
-    decodes per scan chunk, the xla scatter inside its fusion).  The prep-decoding Pallas engines rematerialize
-    every mode's global-i32 stream (write + read), and the transposed-
-    table kernels additionally stream the sublane-replicated request
-    tiles ``_prep_t_operands`` materializes — "achieved bytes ≈ 2x
-    encoded" for those engines.  bench reports the
-    per-path ratio as ``decode_overhead`` next to
-    ``model_gb_per_path``."""
+    decodes per scan chunk, the xla scatter inside its fusion).  Every
+    other engine is priced as rematerializing every mode's global-i32
+    stream (write + read).  bench reports the per-path ratio as
+    ``decode_overhead`` next to ``model_gb_per_path``."""
     from splatt_tpu.ops.mttkrp import STREAM_NATIVE_ENGINES
-    from splatt_tpu.utils.env import ceil_to
 
     lay = X.layout_for(mode)
     if (getattr(lay, "encoding", "v1") in ("v1", "dense")
             or engine in STREAM_NATIVE_ENGINES or engine == "native"):
         return 0.0
-    decoded = 2.0 * lay.nmodes * lay.nnz_pad * 4   # i32 write + read
-    if engine in ("fused_t", "fused_tg"):
-        b_pad = ceil_to(lay.block, 128)
-        for k, d in enumerate(X.dims):
-            if k != mode:
-                d_pad = ceil_to(int(d), 128)
-                ck = -(-b_pad // d_pad)
-                decoded += 2.0 * lay.nblocks * ck * 8 * d_pad * 4
-    return decoded
+    return 2.0 * lay.nmodes * lay.nnz_pad * 4   # i32 write + read
 
 
 def mxu_peak_gflops() -> Optional[float]:

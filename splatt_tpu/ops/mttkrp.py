@@ -56,11 +56,10 @@ STREAM_NATIVE_ENGINES = ("xla_scan", "xla")
 #: only: the scopes change no op.
 #:
 #: - operands: stream padding, index decode, factor-row gather, the
-#:   Hadamard product (and a fused kernel's operand prep);
+#:   Hadamard product (and the dense path's Khatri-Rao operands);
 #: - reduce: the reduction itself — the one-hot einsum, a Mosaic
 #:   one-hot kernel with its chunk padding, ``segment_sum``, the dense
-#:   ``dot_general``, or a kernel that fuses gather and reduce
-#:   (fused_t, fused_tg, fused_dense) whole;
+#:   ``dot_general``, or the ``fused_dense`` kernel whole;
 #: - merge: block partials into output rows (``out.at[idx].add``) and
 #:   the final slice.
 OPERANDS, REDUCE, MERGE = PHASES = ("mttkrp.operands", "mttkrp.reduce",
@@ -490,11 +489,8 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
     - "xla": fused scan — gather, Hadamard and the one-hot contraction
       all live inside one scan step, so the (nnz, R) partial-product
       tensor never hits HBM;
-    - "pallas" (TPU): the fused Mosaic kernels (gather + Hadamard +
-      reduce in VMEM) where Mosaic can lower their gather — every
-      gathered factor within one 128-lane vreg — else the unfused
-      engine: the same block-chunk scan as "xla", its one-hot reduce a
-      Mosaic kernel;
+    - "pallas" (TPU): the same block-chunk scan as "xla", its one-hot
+      reduce a Mosaic kernel (the ``unfused_pallas`` engine);
     - "pallas_interpret": kernel semantics on CPU, for tests.
 
     `scan_target` tunes how much one-hot the XLA engine's scan step
@@ -683,8 +679,6 @@ def _mttkrp_phases(layout: ModeLayout, factors: List[jax.Array],
                    scan_target: int, engine: str) -> jax.Array:
     """:func:`_mttkrp_blocked_jit`'s body, each op under one of the
     :data:`PHASES` scopes."""
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp_t, fused_mttkrp_tg
-
     dim = int(factors[mode].shape[0])
     R = factors[mode].shape[1]
     interpret = impl == "pallas_interpret"
@@ -728,21 +722,13 @@ def _mttkrp_phases(layout: ModeLayout, factors: List[jax.Array],
     # the resolved engine is a static arg: mttkrp_blocked walks the
     # engine_chain outside the jit, so a runtime demotion retraces with
     # the next engine instead of recompiling the same failing one
-    plan = engine
+    pallas = engine == "unfused_pallas"
 
     if path == "privatized":
         width = _onehot_width(layout, dim, path)
-        if plan == "fused_t":
-            out = fused_mttkrp_t(layout, factors, mode, width,
-                                 accumulate=True, interpret=interpret)
-        elif plan == "fused_tg":
-            out = fused_mttkrp_tg(layout, factors, mode, width,
-                                  accumulate=True, interpret=interpret)
-        else:
-            out = _scan_fused(layout, factors, mode, width,
-                              accumulate=True, target_elems=scan_target,
-                              pallas=plan == "unfused_pallas",
-                              interpret=interpret)
+        out = _scan_fused(layout, factors, mode, width, accumulate=True,
+                          target_elems=scan_target, pallas=pallas,
+                          interpret=interpret)
         with jax.named_scope(MERGE):
             return out[:dim]
 
@@ -750,18 +736,9 @@ def _mttkrp_phases(layout: ModeLayout, factors: List[jax.Array],
         if mode != layout.mode:
             raise ValueError("sorted_onehot requires the layout's own mode")
         S = layout.seg_width
-        if plan == "fused_t":
-            parts = fused_mttkrp_t(layout, factors, mode, S,
-                                   accumulate=False, interpret=interpret)
-        elif plan == "fused_tg":
-            parts = fused_mttkrp_tg(layout, factors, mode, S,
-                                    accumulate=False, interpret=interpret)
-        else:
-            parts = _scan_fused(layout, factors, mode, S,
-                                accumulate=False,
-                                target_elems=scan_target,
-                                pallas=plan == "unfused_pallas",
-                                interpret=interpret)    # (nb, S, R)
+        parts = _scan_fused(layout, factors, mode, S, accumulate=False,
+                            target_elems=scan_target, pallas=pallas,
+                            interpret=interpret)    # (nb, S, R)
         with jax.named_scope(MERGE):
             idx = (layout.row_start[:, None]
                    + jnp.arange(S, dtype=jnp.int32)).reshape(-1)
@@ -777,11 +754,10 @@ mttkrp_blocked.clear_cache = _mttkrp_blocked_jit.clear_cache
 
 def _chain_regime(layout: ModeLayout, factors: Sequence[jax.Array],
                   mode: int) -> str:
-    """Probe regime of this call — per lane-chunk regime: a Mosaic
-    crash in the many-chunk (small-dims) regime must not veto the
-    flagship single-chunk production shapes, and vice versa.  Only the
-    GATHERED (non-target) factors are lane-chunked, so the target
-    mode's dim does not enter the classification."""
+    """Probe regime of this call (:func:`pallas_kernels.probe_regime`):
+    a failure in the small-dims regime must not veto the production
+    shapes, and vice versa.  Only the GATHERED (non-target) factors
+    enter the classification."""
     from splatt_tpu.ops.pallas_kernels import probe_regime
 
     return probe_regime([int(f.shape[0])
@@ -840,7 +816,7 @@ def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
                  scan_target: int) -> int:
     """Scoped VMEM `engine`'s Mosaic kernel asks for on this call: the
     one-hot reduce's request at the chunk :func:`_scan_fused` gives it,
-    the device's whole ``vmem_limit`` for the fused kernels, 0 for the
+    the device's whole ``vmem_limit`` for ``fused_dense``, 0 for the
     XLA engines.  Shapes only: the dispatch span's ``kernel_vmem``."""
     from splatt_tpu.ops import pallas_kernels as pk
 
@@ -852,7 +828,7 @@ def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
             _block_chunks(layout.nblocks, R * B, scan_target), width, B, R,
             jnp.dtype(dtype).itemsize)
         return pk.onehot_vmem_request(chunk, width, B, R, dtype)
-    if engine in ("fused_t", "fused_tg", "fused_dense"):
+    if engine == "fused_dense":
         return pk._vmem_limit()
     return 0
 
@@ -873,34 +849,23 @@ def _engine_probed_ok(engine: str, regime: str, block: int,
     probe costs a compile — an engine never reached because an earlier
     one won must not be probed at all, which is why engine_chain defers
     this check to selection/fallback time instead of resolving the
-    whole chain eagerly."""
-    from splatt_tpu.ops.pallas_kernels import (fused_t_supported,
-                                               fused_tg_supported)
-
-    if interpret or engine in ("unfused_pallas", "xla_scan", "xla",
-                               "dense_xla"):
+    whole chain eagerly.  Only ``fused_dense`` has a probe."""
+    if interpret or engine != "fused_dense":
         return True
-    if engine == "fused_dense":
-        from splatt_tpu.ops.pallas_kernels import fused_dense_supported
+    from splatt_tpu.ops.pallas_kernels import fused_dense_supported
 
-        return fused_dense_supported(regime, block)
-    if engine == "fused_t":
-        return fused_t_supported(regime, block)
-    if engine == "fused_tg":
-        return fused_tg_supported(regime, block)
-    return True
+    return fused_dense_supported(regime, block)
 
 
 def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
                  path: str = "sorted_onehot", impl: str = "xla",
                  *, shape_key: Optional[str] = None) -> List[str]:
     """The ORDERED engine fallback chain for this call: every engine
-    whose cheap gates (VMEM plan, Mosaic's lane-gather limit, runtime
-    demotions) pass, best first — fused Pallas (fused_t → fused_tg) →
-    unfused Pallas (the chip's engine at real widths) → xla_scan → the
-    terminal
-    "xla" stream/scatter formulation, which has no preconditions and
-    cannot fail to apply.
+    whose cheap gates (VMEM plan, runtime demotions) pass, best first.
+    The one-hot paths run ``unfused_pallas`` (under a Pallas `impl`,
+    when one block fits VMEM) → ``xla_scan`` → the terminal ``xla``
+    stream/scatter formulation, which has no preconditions and cannot
+    fail to apply.
     Capability probes are NOT consulted here (they cost a compile
     each); :func:`_engine_probed_ok` runs them lazily when an
     engine is actually selected.  :func:`mttkrp_blocked` walks this
@@ -908,8 +873,7 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
     failure degrades the run to the next engine instead of killing it.
     """
     from splatt_tpu import resilience
-    from splatt_tpu.ops.pallas_kernels import (fused_t_vmem_ok,
-                                               fused_tg_vmem_ok, vmem_chunk)
+    from splatt_tpu.ops.pallas_kernels import vmem_chunk
 
     if path in ("scatter", "sorted_scatter", "stream"):
         return ["xla"]
@@ -935,15 +899,6 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
     B = layout.block
     itemsize = jnp.dtype(factors[0].dtype).itemsize
     pallas = impl in ("pallas", "pallas_interpret")
-    # the in-kernel gather family (fused_t/fused_tg) gathers factor
-    # rows with lane-wise take_along_axis, which Mosaic (jax 0.9.0)
-    # lowers only within ONE 128-lane vreg: compiled for a v5e, any
-    # wider table is refused ("Multiple source vregs along gather
-    # dimension"; tests/test_tpu_compile.py).  Interpret mode has no
-    # such limit, so the tests still run their math at any width.
-    interp = impl == "pallas_interpret"
-    gather = pallas and (interp or all(
-        int(f.shape[0]) <= 128 for k, f in enumerate(factors) if k != mode))
     width = _onehot_width(layout, dim, path)
     if shape_key is None:
         shape_key = _engine_shape_key(layout, factors, mode)
@@ -952,12 +907,6 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
         return not resilience.is_demoted(name, shape_key)
 
     chain = []
-    if gather and live("fused_t") and fused_t_vmem_ok(factors, mode,
-                                                      width, B):
-        chain.append("fused_t")
-    if gather and live("fused_tg") and fused_tg_vmem_ok(factors, mode,
-                                                        width, B):
-        chain.append("fused_tg")
     if (pallas and live("unfused_pallas")
             and vmem_chunk(width, B, R, itemsize) >= 1):
         chain.append("unfused_pallas")
@@ -1001,8 +950,8 @@ class Plan(NamedTuple):
 
     impl: str    # "native" | "pallas" | "pallas_interpret" | "xla"
     path: str    # one of PATHS
-    engine: str  # "native" | "fused_t" | "fused_tg" |
-                 # "unfused_pallas" | "xla_scan" | "xla"
+    engine: str  # "native" | "unfused_pallas" | "xla_scan" | "xla" |
+                 # "fused_dense" | "dense_xla"
 
 
 def _native_runnable(layout: ModeLayout, factors: Sequence[jax.Array],

@@ -224,397 +224,31 @@ def onehot_reduce_sorted(local: jax.Array, prod: jax.Array, seg_width: int,
     return out[:nb]
 
 
-# -- fused gather + Hadamard + reduce (transposed tables) -------------------
-#
-# The flagship kernel.  HBM traffic per MTTKRP is inds + vals + block
-# partials — the factor tables are VMEM-resident for the whole sweep, so
-# the (nnz, R) partial-product tensor of the unfused paths (3.7GB logical,
-# 9.5GB after XLA's R→128 lane padding at NELL-2 scale — an HBM OOM)
-# never exists anywhere.  ≙ the reference's register-blocked fiber loops
-# reading factor rows in-cache (src/mttkrp.c:427-463).
-#
-# Two Mosaic constraints shape the design (jax 0.9.0):
-# - only *same-shaped* take_along_axis gathers lower (tpu.dynamic_gather);
-#   an arbitrary B-row gather from a (D, R) table must be phrased as
-#   lane-wise take_along_axis on a *transposed* (R, D) table with the
-#   request vector padded to D — so per-block gather cost scales with
-#   max(B, D), and callers pick block ≈ max other-mode dim to amortize;
-# - a (D, R) f32 table in VMEM pads R→128 lanes (14.7MB for NELL-2's
-#   28818×50), while the transposed (R, D) form pads R→56 sublanes
-#   (6.5MB): transposed tables are what make rank-50 f32 fit at all.
-# Gathers run in 8-sublane tiles so temporaries stay ≤ (8, D).
-
-_SUBLANE = 8
-
-
-def _rank_pad(R: int, dtype) -> int:
-    """Rank rows padded to the dtype's NATIVE sublane packing
-    (config.tile_packing: 8 sublanes f32, 16 bf16/f16 — splint
-    SPL025): the transposed factor tables and (R8, width) outputs tile
-    their second-minor axis by rank, and a dtype-blind pad to 8
-    under-packs narrow-dtype tiles 2x.  Always a multiple of
-    ``_SUBLANE``, so the 8-row gather tiling below still divides it."""
-    from splatt_tpu.config import tile_packing
-
-    return ceil_to(int(R), tile_packing(dtype)[0])
-
-
-def _tile_gather(u_t, gidx, B: int):
-    """rows_t = u_t[:, idx] inside a Mosaic kernel, layout-safely.
-
-    u_t: (R8, D) transposed factor table (VMEM-resident), R8 a multiple
-    of 8, D of 128.  gidx: (ck, 8, D) int32 — the request vector
-    pre-chunked into ck lane-aligned groups of D and replicated across
-    8 sublanes *outside* the kernel.  Mosaic's layout inference rejects
-    broadcasts/slices whose input carries a nonzero lane offset, so the
-    kernel must only read whole aligned tiles: each take_along_axis here
-    is the exact same-shaped (8, D) form tpu.dynamic_gather supports,
-    and the only slice taken is [:, :B] at offset 0.
-    """
-    R8, D = u_t.shape
-    ck = gidx.shape[0]
-    pieces = []
-    for c in range(ck):
-        idx8 = gidx[c]                       # (8, D), aligned tile
-        tiles = [jnp.take_along_axis(u_t[r0:r0 + _SUBLANE, :], idx8, axis=1)
-                 for r0 in range(0, R8, _SUBLANE)]
-        pieces.append(tiles[0] if len(tiles) == 1
-                      else jnp.concatenate(tiles, axis=0))   # (R8, D)
-    rows = pieces[0] if ck == 1 else jnp.concatenate(pieces, axis=1)
-    return rows[:, :B]
-
-
-def _fused_t_kernel(local_ref, vals_ref, *refs,
-                    width: int, accumulate: bool, nother: int):
-    gidx_refs = refs[:nother]
-    ut_refs = refs[nother:2 * nother]
-    out_ref = refs[2 * nother]
-    local = local_ref[0, :, :]               # (1, B) int32
-    vals = vals_ref[0, :, :]                 # (1, B)
+@functools.partial(jax.jit,
+                   static_argnames=("width", "interpret", "chunk"))
+def onehot_reduce_full(local: jax.Array, prod: jax.Array, width: int,
+                       interpret: bool = False,
+                       chunk: int = _CHUNK) -> jax.Array:
+    """(nb, B) ids + (nb, B, R) partials → (width, R) total (privatized)."""
     B = local.shape[1]
-    dtype = vals.dtype
-    acc = out_ref.dtype
-    prod = vals                              # (1, B), broadcasts up
-    for j in range(nother):
-        u_t = ut_refs[j][...]                # (R8, D_j) resident in VMEM
-        rows_t = _tile_gather(u_t, gidx_refs[j][0], B)     # (R8, B)
-        prod = prod * rows_t
-    iota = jax.lax.broadcasted_iota(jnp.int32, (width, B), 0)
-    onehot = (jnp.broadcast_to(local, (width, B)) == iota).astype(dtype)
-    # (R8, B) · (S, B)ᵀ on the MXU → (R8, S) transposed block partials
-    part = jax.lax.dot_general(
-        prod, onehot,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=acc,
-        precision=mxu_precision(dtype))
-    if not accumulate:
-        out_ref[...] = part[None]
-        return
-
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[...] = part
-
-    @pl.when(pl.program_id(0) != 0)
-    def _accum():
-        out_ref[...] += part
-
-
-def fused_t_vmem_ok(factors, mode: int, width: int, block: int,
-                    budget_bytes: int = None) -> bool:
-    """VMEM plan of the transposed-table fused kernel: every input
-    factor resident as (R8, D) (R padded to 8 sublanes, D to 128
-    lanes), plus per-step working set — the pre-replicated (ck, 8, D)
-    index tiles, gathered rows and the accumulating (R8, B) product,
-    the (S, B) one-hot, streams and partials.
-    """
-    if budget_bytes is None:
-        budget_bytes = _vmem_budget()
-    R = int(factors[0].shape[1])
-    r8 = _rank_pad(R, factors[0].dtype)
-    itemsize = jnp.dtype(factors[0].dtype).itemsize
-    b_pad = ceil_to(block, 128)
-    fac = 0
-    work = 0
-    for k, f in enumerate(factors):
-        if k != mode:
-            d = ceil_to(int(f.shape[0]), 128)
-            ck = -(-b_pad // d)
-            fac += r8 * d * itemsize                  # resident table
-            # streamed per block -> the pipeline DOUBLE-buffers them
-            # (splint SPL026's static model counts streamed specs 2x;
-            # single-counting here undersold the true footprint)
-            work += 2 * ck * _SUBLANE * d * 4         # replicated idx tiles
-            work += r8 * ck * d * itemsize            # gathered rows
-    work += (r8 * b_pad * itemsize                    # accumulating product
-             + ceil_to(width, _SUBLANE) * b_pad * itemsize   # one-hot
-             + r8 * ceil_to(width, 128) * 4                  # partials
-             + 2 * 2 * b_pad * 4)                     # local + vals (dbuf)
-    return fac + work <= budget_bytes
-
-
-def _prep_t_operands(layout, factors, mode: int, accumulate: bool):
-    """Shared operand prep for the transposed-table fused kernels:
-    (local, vals, uts, gidxs) with the sentinel-clamp and lane-chunk
-    padding contract in ONE place.
-
-    local/vals: (nb, 1, B).  uts[j]: the (R8, d_pad) transposed,
-    zero-padded factor table for the j-th non-target mode.  gidxs[j]:
-    (nb, ck, 8, d_pad) gather requests — the per-block index vector
-    clamped to d-1 (padding entries carry the out-of-range sentinel
-    `dim`; their values are zero so the clamped row is harmless),
-    padded to whole d_pad lane chunks, replicated across 8 sublanes
-    (the same-shaped take_along_axis form Mosaic lowers).
-    """
-    nb, B = layout.nblocks, layout.block
-    R = int(factors[0].shape[1])
-    dtype = factors[0].dtype
-    R8 = _rank_pad(R, dtype)
-    others = [k for k in range(layout.nmodes) if k != mode]
-
-    # OPERAND-PREP decode through the stream-consumer interface
-    # (blocked.decode_* via mode_ids/blocked_locals): identity reads
-    # for v1, trace-fused decodes for the compact encodings — the
-    # kernel operands below are i32/compute-dtype either way, so these
-    # Mosaic kernels are format-agnostic.  The decoded i32 streams and
-    # replicated request tiles DO round-trip HBM here — the traffic
-    # bench's decode_overhead prices (docs/format.md)
-    if accumulate:
-        local = layout.mode_ids(mode).reshape(nb, B)
-    else:
-        local = layout.blocked_locals()
-    vals = layout.vals.reshape(nb, B).astype(dtype)
-    local = local[:, None, :]
-    vals = vals[:, None, :]
-
-    uts = []
-    gidxs = []
-    for k in others:
-        d = int(factors[k].shape[0])
-        d_pad = ceil_to(d, 128)
-        u_t = factors[k].T
-        uts.append(jnp.pad(u_t, ((0, R8 - R), (0, d_pad - d))))
-        ck = -(-B // d_pad)
-        idx = jnp.minimum(layout.mode_ids(k), d - 1).reshape(nb, B)
-        if ck * d_pad != B:
-            idx = jnp.pad(idx, ((0, 0), (0, ck * d_pad - B)))
-        gidxs.append(jnp.broadcast_to(idx.reshape(nb, ck, 1, d_pad),
-                                      (nb, ck, _SUBLANE, d_pad)))
-    return local, vals, uts, gidxs
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "width", "accumulate",
-                                             "interpret"))
-def fused_mttkrp_t(layout, factors, mode: int, width: int,
-                   accumulate: bool, interpret: bool = False) -> jax.Array:
-    """Fused MTTKRP with VMEM-resident transposed factor tables.
-
-    Output: (nb, width, R) block partials (sorted layouts), or
-    (width, R) totals when `accumulate` (privatized short modes) —
-    (nb, width, R)
-    block partials, or (width, R) totals when `accumulate`.
-    """
-    nb, B = layout.nblocks, layout.block
-    R = int(factors[0].shape[1])
-    dtype = factors[0].dtype
-    R8 = _rank_pad(R, dtype)
-    others = [k for k in range(layout.nmodes) if k != mode]
-    grid = (nb,)
-
-    with jax.named_scope(OPERANDS):
-        local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
-                                                   accumulate)
-    ut_specs = [pl.BlockSpec(u.shape, lambda i: (0, 0)) for u in uts]
-    gidx_specs = [pl.BlockSpec((1,) + g.shape[1:], lambda i: (i, 0, 0, 0))
-                  for g in gidxs]
-
-    acc = _acc_dtype(dtype)
-    if accumulate:
-        out_spec = pl.BlockSpec((R8, width), lambda i: (0, 0))
-        out_shape = jax.ShapeDtypeStruct((R8, width), acc)
-    else:
-        out_spec = pl.BlockSpec((1, R8, width), lambda i: (i, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((nb, R8, width), acc)
-
-    with jax.named_scope(REDUCE):
-        out = pl.pallas_call(
-            functools.partial(_fused_t_kernel, width=width,
-                              accumulate=accumulate, nother=len(others)),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, B), lambda i: (i, 0, 0)),
-                *gidx_specs,
-                *ut_specs,
-            ],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-            name="fused_mttkrp_t",
-            compiler_params=_compiler_params(),
-        )(local, vals, *gidxs, *uts)
-    # back to the (…, width, R) contract of the untransposed kernels
-    with jax.named_scope(MERGE):
-        if accumulate:
-            return out.T[:, :R]
-        return jnp.swapaxes(out, 1, 2)[:, :, :R]
-
-
-# -- sublane-tiled fused kernel (inner grid over rank tiles) ----------------
-#
-# Structurally different variant of fused_mttkrp_t (both lower only
-# where every gathered table fits one 128-lane vreg — Mosaic's
-# take_along_axis limit, tests/test_tpu_compile.py).  This variant:
-#   * grid (R8/8, nb) — each instance computes ONE 8-sublane rank tile,
-#     so the kernel body holds one take_along_axis per (factor, lane
-#     chunk) and no concatenates at all;
-#   * only an (8, D) slice of each transposed table is resident per
-#     step; the table block index depends only on the rank-tile
-#     coordinate, and nb is the fastest grid dimension, so Pallas
-#     re-fetches each slice once per rank tile (~R8/8 · ΣD · 32 B per
-#     MTTKRP — noise), not once per block;
-#   * chunk products accumulate into a VMEM scratch at static
-#     128-aligned lane offsets instead of concatenating tiles.
-# The VMEM envelope is RANK-independent (only one 8-sublane rank tile
-# is live per step) but DIM-linear: the per-step (8, d_pad) table slice
-# and index tiles scale with the padded mode dim, so rank-200 configs
-# fused_t's whole-table residency gate rejects are covered, while
-# mode dims beyond a few hundred thousand still reject (a 10M-row mode
-# ⇒ ~960 MB/step) and dispatch falls back to xla_scan.  What rescues
-# the Amazon-scale configs is the multi-chip grid: each device sees
-# only its grid-LOCAL dims, which shrink by the axis width.
-
-def _fused_tg_kernel(local_ref, vals_ref, *refs,
-                     width: int, accumulate: bool, nother: int):
-    gidx_refs = refs[:nother]
-    ut_refs = refs[nother:2 * nother]
-    out_ref = refs[2 * nother]
-    prod_ref = refs[2 * nother + 1]          # VMEM scratch (8, B)
-    local = local_ref[0, :, :]               # (1, B) int32
-    vals = vals_ref[0, :, :]                 # (1, B)
-    B = local.shape[1]
-    dtype = vals.dtype
-    prod_ref[...] = jnp.broadcast_to(vals, (_SUBLANE, B))
-    for j in range(nother):
-        u_t = ut_refs[j][...]                # (8, D_j) slice of the table
-        gidx = gidx_refs[j][0]               # (ck_j, 8, D_j)
-        ck, _, D = gidx.shape
-        for c in range(ck):
-            w = min(B - c * D, D)
-            if w <= 0:
-                break
-            tile = jnp.take_along_axis(u_t, gidx[c], axis=1)   # (8, D_j)
-            if w == B and ck == 1:
-                prod_ref[...] = prod_ref[...] * tile[:, :B]
-            else:
-                prod_ref[:, c * D:c * D + w] = (
-                    prod_ref[:, c * D:c * D + w] * tile[:, :w])
-    iota = jax.lax.broadcasted_iota(jnp.int32, (width, B), 0)
-    onehot = (jnp.broadcast_to(local, (width, B)) == iota).astype(dtype)
-    # (8, B) · (S, B)ᵀ on the MXU → (8, S) transposed partials tile
-    part = jax.lax.dot_general(
-        prod_ref[...], onehot,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-        precision=mxu_precision(dtype))
-    if not accumulate:
-        out_ref[...] = part[None]
-        return
-
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = part
-
-    @pl.when(pl.program_id(1) != 0)
-    def _accum():
-        out_ref[...] += part
-
-
-def fused_tg_vmem_ok(factors, mode: int, width: int, block: int,
-                     budget_bytes: int = None) -> bool:
-    """VMEM plan of the sublane-tiled kernel — per-step only: (8, D)
-    table slices, the replicated index tiles, the (8, B) product
-    scratch, one-hot and partials.  ×2 on streamed operands for double
-    buffering.  RANK-independent (no whole-table footprint), but
-    DIM-linear: the slice/index terms grow with each padded mode dim,
-    so very large local dims (≳ a few hundred thousand rows at
-    block 4096) correctly reject here and dispatch falls back."""
-    if budget_bytes is None:
-        budget_bytes = _vmem_budget()
-    itemsize = jnp.dtype(factors[0].dtype).itemsize
-    b_pad = ceil_to(block, 128)
-    work = 0
-    for k, f in enumerate(factors):
-        if k != mode:
-            d = ceil_to(int(f.shape[0]), 128)
-            ck = -(-b_pad // d)
-            work += 2 * _SUBLANE * d * itemsize        # table slice (dbuf)
-            work += 2 * ck * _SUBLANE * d * 4          # replicated idx tiles
-    work += (_SUBLANE * b_pad * itemsize               # prod scratch
-             + ceil_to(width, _SUBLANE) * b_pad * itemsize   # one-hot
-             + _SUBLANE * ceil_to(width, 128) * 4            # partials tile
-             + 4 * b_pad * 4)                                # local + vals
-    return work <= budget_bytes
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "width", "accumulate",
-                                             "interpret"))
-def fused_mttkrp_tg(layout, factors, mode: int, width: int,
-                    accumulate: bool, interpret: bool = False) -> jax.Array:
-    """Sublane-tiled fused MTTKRP (grid over rank tiles × blocks).
-
-    Same contract as :func:`fused_mttkrp_t`: (nb, width, R) block
-    partials, or (width, R) totals when `accumulate`.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    nb, B = layout.nblocks, layout.block
-    R = int(factors[0].shape[1])
-    dtype = factors[0].dtype
-    R8 = _rank_pad(R, dtype)  # matches _prep_t_operands' table padding
-    n_rtiles = R8 // _SUBLANE
-    others = [k for k in range(layout.nmodes) if k != mode]
-    grid = (n_rtiles, nb)     # nb fastest: table slices fetched per r-tile
-
-    with jax.named_scope(OPERANDS):
-        local, vals, uts, gidxs = _prep_t_operands(layout, factors, mode,
-                                                   accumulate)
-    ut_specs = [pl.BlockSpec((_SUBLANE, u.shape[1]), lambda r, i: (r, 0))
-                for u in uts]
-    gidx_specs = [pl.BlockSpec((1,) + g.shape[1:],
-                               lambda r, i: (i, 0, 0, 0)) for g in gidxs]
-
-    acc = _acc_dtype(dtype)
-    if accumulate:
-        out_spec = pl.BlockSpec((_SUBLANE, width), lambda r, i: (r, 0))
-        out_shape = jax.ShapeDtypeStruct((R8, width), acc)
-    else:
-        out_spec = pl.BlockSpec((1, _SUBLANE, width), lambda r, i: (i, r, 0))
-        out_shape = jax.ShapeDtypeStruct((nb, R8, width), acc)
-
-    with jax.named_scope(REDUCE):
-        out = pl.pallas_call(
-            functools.partial(_fused_tg_kernel, width=width,
-                              accumulate=accumulate, nother=len(others)),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, B), lambda r, i: (i, 0, 0)),
-                *gidx_specs,
-                *ut_specs,
-            ],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((_SUBLANE, B), dtype)],
-            interpret=interpret,
-            name="fused_mttkrp_tg",
-            compiler_params=_compiler_params(),
-        )(local, vals, *gidxs, *uts)
-    # back to the (…, width, R) contract of the untransposed kernels
-    with jax.named_scope(MERGE):
-        if accumulate:
-            return out.T[:, :R]
-        return jnp.swapaxes(out, 1, 2)[:, :, :R]
+    R = prod.shape[-1]
+    local, prod, nb_pad = _pad_blocks(local, prod, chunk)
+    grid = (nb_pad // chunk,)
+    out = pl.pallas_call(
+        functools.partial(_full_kernel, width=width),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
+            pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((width, R), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(prod.dtype)),
+        interpret=interpret,
+        name="onehot_reduce_full",
+        compiler_params=_onehot_compiler_params(chunk, width, B, R,
+                                                prod.dtype),
+    )(local, prod)
+    return out
 
 
 #: outcome of each capability probe, keyed by kernel name — "ok",
@@ -857,73 +491,24 @@ def probe_cache_store(state_key: str, state: str) -> None:
     _json_cache_update(_cache_path(), mutate)
 
 
-#: representative probe shapes per lane-chunk regime.  "ck1": the
-#: flagship NELL-like production regime — mode dims in the thousands,
-#: a single lane chunk per factor (d_pad >= block), wide gathers, a
-#: realistic seg_width (mode-0 indices laid out so each 4096-block
-#: spans ~8 rows, like a 20M-nnz tensor's density).  "multick": small
-#: mode dims against the same block, so the kernels unroll many lane
-#: chunks per factor (ck up to 11) — a regime that can crash Mosaic
-#: independently of the ck1 shape.  Probing per regime keeps a crash
-#: in one from vetoing the other.
-_PROBE_DIMS = {"ck1": (12092, 9184, 28818), "multick": (512, 384, 1024)}
-
-
 def probe_regime(dims, block: int) -> str:
-    """Which probe regime a (dims, block) config falls in: "multick"
-    when any factor needs more than one padded lane chunk per block."""
+    """Which regime a (dims, block) config falls in: "multick" when a
+    block holds more nonzeros than some gathered factor has rows
+    (padded to 128), else "ck1".  Capability probes and per-shape
+    demotions are keyed by it, so a failure in one regime never vetoes
+    the other."""
     return ("multick"
             if any(block > ceil_to(int(d), 128) for d in dims)
             else "ck1")
 
 
-def _probe_case(kernel_fn, regime: str, block: int) -> bool:
-    """The probe compile itself — module-level so tests can substitute
-    it without touching the thread/deadline/cache machinery around it."""
-    import numpy as np
-
-    from splatt_tpu.blocked import build_layout
-    from splatt_tpu.coo import SparseTensor
-
-    rng = np.random.default_rng(0)
-    dims = _PROBE_DIMS[regime]
-    nnz = max(8192, 2 * block)
-    # scale the probe's rank to the device's VMEM so a capacity
-    # rejection on small-VMEM parts (v2/v3: 16 MiB) is never cached
-    # as a capability rejection for the whole regime
-    rank = 48 if _vmem_limit() >= (32 << 20) else 16
-    if regime == "ck1":
-        # NELL-like density: each block spans ~8 output rows,
-        # giving the production seg_width (~8-16)
-        i0 = np.minimum((np.arange(nnz, dtype=np.int64) * 8) // block,
-                        dims[0] - 1)
-    else:
-        # small dims: random rows give the regime's natural wide
-        # seg_width (~dims[0]) — the width real multick kernels
-        # compile at
-        i0 = rng.integers(0, dims[0], nnz)
-    inds = np.stack([i0] + [rng.integers(0, d, nnz)
-                            for d in dims[1:]])
-    tt = SparseTensor(inds=inds.astype(np.int64),
-                      vals=np.ones(nnz), dims=dims)
-    lay = build_layout(tt, 0, block=block, val_dtype=np.float32,  # splint: ignore[SPL005] probes compile at the production f32 shape to keep one verdict cache
-                       dense=False)
-    fac = [jnp.zeros((d, rank), jnp.float32) for d in dims]  # splint: ignore[SPL005] probes compile at the production f32 shape to keep one verdict cache
-    kernel_fn.lower(lay, fac, mode=0, width=lay.seg_width,
-                    accumulate=False, interpret=False).compile()
-    return True
-
-
-def _probe_compiles(kernel_fn, name: str, regime: str = "ck1",
-                    block: int = 4096, case=None) -> bool:
-    """Whether `kernel_fn(layout, factors, mode, width, accumulate,
-    interpret)` COMPILES for this backend at a shape representative of
-    `regime` at the CALLER's block size.  Lowering alone is not
-    enough: Mosaic layout inference (e.g. the "Invalid input layout"
-    broadcast restriction) only runs at compile time.  And a toy shape
-    is not enough either: what Mosaic accepts depends on the widths
-    (its lane gather lowers only within one 128-lane vreg), so the
-    block size is part of the probe key rather than fixed."""
+def _probe_compiles(kernel_fn, name: str, regime: str, block: int,
+                    case) -> bool:
+    """Whether `kernel_fn` COMPILES for this backend, by running
+    ``case(kernel_fn, regime, block)``: a compile at a shape
+    representative of `regime` at the CALLER's block size.  Lowering
+    alone is not enough: Mosaic layout inference (e.g. the "Invalid
+    input layout" broadcast restriction) only runs at compile time."""
     state_key = f"{name}:{regime}:b{block}"
     if jax.default_backend() != "tpu":
         PROBE_STATES[state_key] = "not_tpu"
@@ -959,12 +544,7 @@ def _probe_compiles(kernel_fn, name: str, regime: str = "ck1",
 
     def attempt():
         faults.maybe_fail("probe_compile")
-        # a kernel whose call signature differs from the shared probe
-        # case (fused_dense: no width/accumulate) supplies its own
-        # `case` callable
-        if case is not None:
-            return case(kernel_fn, regime, block)
-        return _probe_case(kernel_fn, regime, block)
+        return case(kernel_fn, regime, block)
 
     try:
         state = ("ok" if resilience.retry_transient(attempt,
@@ -998,50 +578,6 @@ def _report_probe(state_key: str, state: str, why: str) -> None:
                                 verdict=state)
     print(f"splatt-tpu: WARNING: {state_key} capability probe: {state} "
           f"({why}); this engine is skipped", file=sys.stderr, flush=True)
-
-
-@functools.cache
-def fused_t_supported(regime: str = "ck1", block: int = 4096) -> bool:
-    """Whether the transposed-table fused kernel compiles here (its
-    lane-wise same-shape take_along_axis gather is the form Mosaic
-    supports on jax 0.9.0), probed per (lane-chunk regime, block)."""
-    return _probe_compiles(fused_mttkrp_t, "fused_t", regime, block)
-
-
-@functools.cache
-def fused_tg_supported(regime: str = "ck1", block: int = 4096) -> bool:
-    """Whether the sublane-tiled fused kernel compiles here (one
-    take_along_axis per factor×chunk, no concatenates, scratch-store
-    accumulation — the shape Mosaic is most likely to accept), probed
-    per (lane-chunk regime, block)."""
-    return _probe_compiles(fused_mttkrp_tg, "fused_tg", regime, block)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("width", "interpret", "chunk"))
-def onehot_reduce_full(local: jax.Array, prod: jax.Array, width: int,
-                       interpret: bool = False,
-                       chunk: int = _CHUNK) -> jax.Array:
-    """(nb, B) ids + (nb, B, R) partials → (width, R) total (privatized)."""
-    B = local.shape[1]
-    R = prod.shape[-1]
-    local, prod, nb_pad = _pad_blocks(local, prod, chunk)
-    grid = (nb_pad // chunk,)
-    out = pl.pallas_call(
-        functools.partial(_full_kernel, width=width),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((width, R), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(prod.dtype)),
-        interpret=interpret,
-        name="onehot_reduce_full",
-        compiler_params=_onehot_compiler_params(chunk, width, B, R,
-                                                prod.dtype),
-    )(local, prod)
-    return out
 
 
 # -- dense-mode MXU engine (docs/dense.md) ----------------------------------
@@ -1132,12 +668,10 @@ def fused_dense(layout, factors, mode: int,
 
 
 def _probe_case_dense(kernel_fn, regime: str, block: int) -> bool:
-    """The dense-engine probe compile — its own case because the
-    kernel's call signature has no width/accumulate (the shared
-    :func:`_probe_case` lowers the sparse-layout signature).  A
-    synthetic near-dense mode at a production-like (tile, span); the
-    Mosaic-sensitive step the probe exercises is the in-kernel
-    (n_outer, inner_pad, R) -> (span, R) Khatri-Rao reshape."""
+    """The dense-engine probe compile: a synthetic near-dense mode at a
+    production-like (tile, span); the Mosaic-sensitive step the probe
+    exercises is the in-kernel (n_outer, inner_pad, R) -> (span, R)
+    Khatri-Rao reshape."""
     import numpy as np
 
     from splatt_tpu.blocked import build_dense_layout
@@ -1163,7 +697,7 @@ def fused_dense_supported(regime: str = "ck1", block: int = 4096) -> bool:
     """Whether the dense-mode MXU kernel compiles here (the in-kernel
     broadcast-multiply + (n_outer·inner_pad, R) reshape that builds the
     Khatri-Rao tile is the Mosaic-sensitive step), probed per
-    (lane-chunk regime, tile) like every engine — an unlowerable form
-    demotes cleanly to the ``dense_xla`` reference path."""
+    (regime, tile) — an unlowerable form demotes cleanly to the
+    ``dense_xla`` reference path."""
     return _probe_compiles(fused_dense, "fused_dense", regime, block,
                            case=_probe_case_dense)

@@ -546,7 +546,7 @@ RUN_REPORT_EVENTS = {
                      "layout: records the consumed encoding and "
                      "whether decode runs natively in-kernel/per-"
                      "chunk (xla_scan/xla) or at operand "
-                     "prep (the fused_t family) — the achieved-"
+                     "prep (unfused_pallas) — the achieved-"
                      "bytes≈encoded-bytes contract made observable "
                      "(ops/mttkrp.py, docs/format.md)",
     "packing_fallback": "a balanced fiber pack failed and the build "

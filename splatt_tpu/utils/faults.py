@@ -16,7 +16,7 @@ Arming a fault
 
     - env var (whole-process, e.g. under the CLI)::
 
-        SPLATT_FAULTS="probe_compile:http500:2,engine.fused_t:runtime"
+        SPLATT_FAULTS="probe_compile:http500:2,engine.unfused_pallas:runtime"
 
       Comma-separated ``site[:kind][:modifier]...`` specs; ``times``
       defaults to 1, ``*`` means every eligible call.
@@ -37,7 +37,7 @@ Chaos schedules (docs/guarded-als.md)
 
     Modifiers compose; ``times`` still bounds the TOTAL number of
     firings once a call is eligible.  A spec whose kind is omitted
-    (``engine.fused_t:iter=3``) defaults to ``runtime``.
+    (``engine.unfused_pallas:iter=3``) defaults to ``runtime``.
     :func:`parse_schedule` / :func:`format_schedule` round-trip the
     grammar; ``splatt chaos`` (splatt_tpu/chaos.py) drives a CPD under
     a schedule and asserts the soak invariant.
@@ -45,7 +45,7 @@ Chaos schedules (docs/guarded-als.md)
 Sites used by the production code:
     - ``probe_compile``          — the capability-probe compile
     - ``engine.<name>``          — an MTTKRP dispatch engine at call
-      time (e.g. ``engine.fused_t``, ``engine.xla_scan``)
+      time (e.g. ``engine.unfused_pallas``, ``engine.xla_scan``)
     - ``checkpoint_write``       — raise during the checkpoint save
     - ``checkpoint_torn``        — consumed (not raised): the writer
       truncates the bytes it just wrote, simulating a torn write
@@ -135,7 +135,7 @@ SITES = {
     "probe_compile": "the capability-probe compile "
                      "(ops/pallas_kernels.py)",
     "engine.*": "an MTTKRP dispatch engine at call time, e.g. "
-                "engine.fused_t / engine.xla_scan (ops/mttkrp.py); "
+                "engine.unfused_pallas / engine.xla_scan (ops/mttkrp.py); "
                 "poison-armed specs corrupt the engine's OUTPUT "
                 "instead of raising",
     "checkpoint_write": "raise during the checkpoint save (cpd.py)",

@@ -111,8 +111,6 @@ def test_v2_bitparity_forced_engines():
         l2 = build_layout(tt, mode, block=128, val_dtype=np.float32,
                           fmt=V2)
         for engine, impl in (("xla_scan", "xla"),
-                             ("fused_t", "pallas_interpret"),
-                             ("fused_tg", "pallas_interpret"),
                              ("unfused_pallas", "pallas_interpret")):
             a = np.asarray(_mttkrp_blocked_jit(l1, facs, mode,
                                                "sorted_onehot", impl,
@@ -775,7 +773,7 @@ def test_rle_counts_shape_and_shrink():
 def test_format_decode_event_names_strategy():
     """The first dispatch over a compact layout records WHERE decode
     ran: 'kernel' for the stream-native engines, 'prep' for the
-    fused_t family (docs/format.md)."""
+    others (docs/format.md)."""
     from splatt_tpu.ops.mttkrp import _DEADLINE_ARMED
 
     tt = _tensor()
@@ -790,13 +788,13 @@ def test_format_decode_event_names_strategy():
     # warm dispatch: no second event for the same (engine, shape)
     mttkrp_blocked(l2, facs, 0, path="sorted_onehot", impl="xla")
     assert len(resilience.run_report().events("format_decode")) == n
-    # the interpret-Pallas chain heads with fused_t, which decodes at
-    # operand prep
+    # the interpret-Pallas chain heads with unfused_pallas, which is
+    # not stream-native
     _DEADLINE_ARMED.clear()
     mttkrp_blocked(l2, facs, 0, path="sorted_onehot",
                    impl="pallas_interpret")
     evs = resilience.run_report().events("format_decode")
-    assert evs[-1]["engine"] == "fused_t"
+    assert evs[-1]["engine"] == "unfused_pallas"
     assert evs[-1]["strategy"] == "prep"
 
 
@@ -853,11 +851,10 @@ def test_delta_rle_strict_plan_match_and_scope():
 
 def test_decode_bytes_model():
     """bench_algs.mttkrp_decode_bytes: zero for v1 layouts and the
-    stream-native engines; positive (the re-widened i32 streams +
-    request tiles) for the prep-decoding kernels over compact
-    layouts — what bench's decode_overhead ratio reads."""
-    from splatt_tpu.bench_algs import mttkrp_bytes_encoded, \
-        mttkrp_decode_bytes
+    stream-native engines; the re-widened i32 streams (written and
+    read) for the other engines over compact layouts — what bench's
+    decode_overhead ratio reads."""
+    from splatt_tpu.bench_algs import mttkrp_decode_bytes
     from splatt_tpu.ops.mttkrp import STREAM_NATIVE_ENGINES
 
     assert set(STREAM_NATIVE_ENGINES) == {"xla_scan", "xla"}
@@ -869,16 +866,12 @@ def test_decode_bytes_model():
                       idx_width="auto")
     bs1 = BlockedSparse.from_coo(tt, opts_v1)
     bs2 = BlockedSparse.from_coo(tt, opts_v2)
-    assert mttkrp_decode_bytes(bs1, 4, 0, "fused_t") == 0.0
+    assert mttkrp_decode_bytes(bs1, 4, 0, "unfused_pallas") == 0.0
     for eng in STREAM_NATIVE_ENGINES:
         assert mttkrp_decode_bytes(bs2, 4, 0, eng) == 0.0
-    enc = mttkrp_bytes_encoded("blocked_pallas", bs2, 4, 0, 4)
-    for eng in ("fused_t", "fused_tg", "unfused_pallas"):
-        dec = mttkrp_decode_bytes(bs2, 4, 0, eng)
-        assert dec > 0.0, eng
-    # the transposed-table kernels' replicated request tiles dominate:
-    # the achieved/encoded ratio is ~2x
-    assert (enc + mttkrp_decode_bytes(bs2, 4, 0, "fused_t")) / enc > 1.3
+    lay = bs2.layout_for(0)
+    assert mttkrp_decode_bytes(bs2, 4, 0, "unfused_pallas") == (
+        2.0 * lay.nmodes * lay.nnz_pad * 4)
 
 
 def test_decode_registries_declared():

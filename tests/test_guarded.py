@@ -59,7 +59,7 @@ def _opts(**kw):
     "site_a:http500",
     "site_a:http500:3",
     "site_a:oom:*",
-    "engine.fused_t:nan:iter=3",
+    "engine.unfused_pallas:nan:iter=3",
     "probe_compile:internal:p=0.25:seed=7",
     "tuner.measure:slow:delay=2.5",
     "site_b:runtime:after=1.5",
@@ -194,12 +194,12 @@ def test_classify_new_classes_and_precedence():
 
 def test_timeout_demotes_per_shape_like_oom():
     resilience.demote_engine(
-        "fused_t", DeadlineExceeded("splatt deadline blown at "
-                                    "engine.fused_t after 2s"),
+        "unfused_pallas", DeadlineExceeded("splatt deadline blown at "
+                                           "engine.unfused_pallas after 2s"),
         shape_key="ck1:b4096")
-    assert resilience.is_demoted("fused_t", "ck1:b4096")
-    assert not resilience.is_demoted("fused_t", "ck1:b128")
-    assert not resilience.is_demoted("fused_t")
+    assert resilience.is_demoted("unfused_pallas", "ck1:b4096")
+    assert not resilience.is_demoted("unfused_pallas", "ck1:b128")
+    assert not resilience.is_demoted("unfused_pallas")
 
 
 def test_numerical_error_never_triggers_engine_rescue():
@@ -209,10 +209,10 @@ def test_numerical_error_never_triggers_engine_rescue():
     from tests.test_resilience import _blocked
 
     _, bs = _blocked()
-    resilience.note_engine_attempt("fused_t", "ck1:b256")
+    resilience.note_engine_attempt("unfused_pallas", "ck1:b256")
     assert _try_engine_rescue(
         bs, _opts(), NumericalHealthError("non-finite outputs")) is False
-    assert not resilience.is_demoted("fused_t")
+    assert not resilience.is_demoted("unfused_pallas")
 
 
 # -- deadline watchdog ------------------------------------------------------
@@ -344,8 +344,8 @@ def test_nan_at_iteration_k_rolls_back_to_finite(k):
 
 
 def test_engine_site_nan_rolls_back_through_sweep_rebuild():
-    """The issue's `engine.fused_t:...` schedule: a poison-armed engine
-    fault corrupts the engine's output inside the fused sweep's TRACE;
+    """An `engine.<name>:nan` schedule: a poison-armed engine fault
+    corrupts the engine's output inside the fused sweep's TRACE;
     the rollback's sweep rebuild flushes the poisoned program and the
     run recovers."""
     from splatt_tpu.ops.mttkrp import engine_plan

@@ -163,7 +163,6 @@ def _sparse_case(path, idx_width=None):
 
 
 ENGINES = [("xla_scan", "xla"), ("unfused_pallas", "pallas_interpret"),
-           ("fused_t", "pallas_interpret"), ("fused_tg", "pallas_interpret"),
            ("xla", "xla")]
 CASES = ([("sorted_onehot", e, i, None) for e, i in ENGINES]
          + [("privatized", e, i, None) for e, i in ENGINES]
@@ -171,6 +170,10 @@ CASES = ([("sorted_onehot", e, i, None) for e, i in ENGINES]
             ("sorted_scatter", "xla", "xla", None),
             ("sorted_onehot", "xla_scan", "xla", "u16"),
             ("sorted_onehot", "unfused_pallas", "pallas_interpret", "u16"),
+            ("sorted_onehot", "xla_scan", "xla", "u8"),
+            ("sorted_onehot", "unfused_pallas", "pallas_interpret", "u8"),
+            ("privatized", "xla_scan", "xla", "u16"),
+            ("privatized", "unfused_pallas", "pallas_interpret", "u16"),
             ("dense", "dense_xla", "xla", None),
             ("dense", "fused_dense", "pallas_interpret", None)])
 

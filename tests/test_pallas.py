@@ -153,45 +153,10 @@ def test_unfused_pallas_engine_direct():
                                    err_msg=f"unfused privatized mode={mode}")
 
 
-def test_fused_tg_kernel_direct():
-    """Sublane-tiled fused kernel (grid over rank tiles × blocks) vs the
-    numpy brute force — covering multi-chunk lane gathers (block larger
-    than a padded mode dim), multiple rank tiles, and both output
-    contracts."""
-    from splatt_tpu.blocked import build_layout
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp_tg
-
-    for name, block, rank in (("med", 128, 8),      # single tile
-                              ("med", 512, 20),     # ck>1, 3 rank tiles
-                              ("med4", 256, 12)):   # 4-mode
-        tt = gen.fixture_tensor(name)
-        factors = make_factors(tt.dims, rank=rank)
-        for mode in range(tt.nmodes):
-            lay = build_layout(tt, mode, block=block, val_dtype=np.float64)
-            want = np_mttkrp(tt, factors, mode)
-            S = lay.seg_width
-            parts = fused_mttkrp_tg(lay, factors, mode, S, accumulate=False,
-                                    interpret=True)
-            idx = (np.asarray(lay.row_start)[:, None]
-                   + np.arange(S)).reshape(-1)
-            out = np.zeros((tt.dims[mode] + S + 1, rank))
-            np.add.at(out, idx, np.asarray(parts).reshape(-1, rank))
-            np.testing.assert_allclose(
-                out[:tt.dims[mode]], want, atol=TOL,
-                err_msg=f"fused_tg sorted {name} block={block} mode={mode}")
-            W = -(-(tt.dims[mode] + 1) // 8) * 8
-            tot = fused_mttkrp_tg(lay, factors, mode, W, accumulate=True,
-                                  interpret=True)
-            np.testing.assert_allclose(
-                np.asarray(tot)[:tt.dims[mode]], want, atol=TOL,
-                err_msg=f"fused_tg priv {name} block={block} mode={mode}")
-
-
-def test_fused_tg_dispatch_when_tables_too_big(monkeypatch):
-    """When whole-table residency (fused_t) is gated out, dispatch picks
-    the sublane-tiled kernel — whose VMEM plan is rank/dim independent —
-    and the answer still matches."""
-    import splatt_tpu.ops.pallas_kernels as pk
+def test_pallas_unfused_fallback_matches():
+    """The Pallas engine (interpret mode) plans unfused_pallas, the
+    engine the chip runs, for every mode with no gate patched — the
+    same answer as the brute force."""
     from splatt_tpu.ops.mttkrp import engine_plan
 
     tt = gen.fixture_tensor("med")
@@ -199,63 +164,6 @@ def test_fused_tg_dispatch_when_tables_too_big(monkeypatch):
                    val_dtype=np.float64)
     bs = BlockedSparse.from_coo(tt, opts)
     factors = make_factors(tt.dims)
-    monkeypatch.setattr(pk, "fused_t_vmem_ok", lambda *a, **k: False)
-    mttkrp_blocked.clear_cache()
-    for mode in range(tt.nmodes):
-        lay = bs.layout_for(mode)
-        assert engine_plan(lay, factors, mode, "sorted_onehot",
-                           "pallas_interpret") == "fused_tg"
-        want = np_mttkrp(tt, factors, mode)
-        got = mttkrp_blocked(lay, factors, mode,
-                             path="sorted_onehot", impl="pallas_interpret")
-        np.testing.assert_allclose(np.asarray(got), want, atol=TOL,
-                                   err_msg=f"fused_tg dispatch mode={mode}")
-
-
-def test_fused_tg_bf16_accumulates_f32():
-    from splatt_tpu.blocked import build_layout
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp_tg
-
-    tt = gen.fixture_tensor("med")
-    factors = [jnp.asarray(np.asarray(f), dtype=jnp.bfloat16)
-               for f in make_factors(tt.dims)]
-    lay = build_layout(tt, 0, block=128, val_dtype=jnp.bfloat16)
-    W = -(-(tt.dims[0] + 1) // 8) * 8
-    tot = fused_mttkrp_tg(lay, factors, 0, W, accumulate=True,
-                          interpret=True)
-    assert tot.dtype == jnp.float32
-    want = np_mttkrp(tt, [np.asarray(f, np.float64) for f in factors], 0)
-    np.testing.assert_allclose(np.asarray(tot)[:tt.dims[0]], want, atol=0.6,
-                               rtol=0.1)
-
-
-def test_fused_vmem_gate():
-    from splatt_tpu.ops.pallas_kernels import fused_t_vmem_ok
-
-    small = [jnp.zeros((64, 16)) for _ in range(3)]
-    assert fused_t_vmem_ok(small, 0, 64, 128)
-    huge = [jax.ShapeDtypeStruct((4_000_000, 64), jnp.float32)
-            for _ in range(3)]
-    assert not fused_t_vmem_ok(huge, 0, 64, 4096)
-
-
-def test_pallas_unfused_fallback_matches(monkeypatch):
-    """When factors exceed the fused VMEM budget the Pallas engine falls
-    back to the unfused (prod-precomputed) kernels — same answer."""
-    import splatt_tpu.ops.pallas_kernels as pk
-
-    tt = gen.fixture_tensor("med")
-    opts = Options(block_alloc=BlockAlloc.ALLMODE, nnz_block=128,
-                   val_dtype=np.float64)
-    bs = BlockedSparse.from_coo(tt, opts)
-    factors = make_factors(tt.dims)
-    monkeypatch.setattr(pk, "fused_t_vmem_ok", lambda *a, **k: False)
-    monkeypatch.setattr(pk, "fused_tg_vmem_ok", lambda *a, **k: False)
-    # identical statics/avals were traced earlier in this file with the
-    # fused branch; drop the cache so the monkeypatch is consulted
-    mttkrp_blocked.clear_cache()
-    from splatt_tpu.ops.mttkrp import engine_plan
-
     for mode in range(tt.nmodes):
         lay = bs.layout_for(mode)
         assert engine_plan(lay, factors, mode, "sorted_onehot",
@@ -265,6 +173,73 @@ def test_pallas_unfused_fallback_matches(monkeypatch):
                              path="sorted_onehot", impl="pallas_interpret")
         np.testing.assert_allclose(np.asarray(got), want, atol=TOL,
                                    err_msg=f"unfused fallback mode={mode}")
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret", "xla"])
+@pytest.mark.parametrize("path", ["sorted_onehot", "privatized"])
+def test_engine_chain_is_the_chips(impl, path):
+    """The one-hot paths' chain is exactly unfused_pallas → xla_scan →
+    xla under a Pallas impl, on the chip and in interpret mode alike,
+    and xla_scan → xla under the XLA impl: the CPU tests that force
+    Pallas run the engine the chip runs."""
+    from splatt_tpu.ops.mttkrp import engine_chain
+
+    tt = gen.fixture_tensor("med")
+    opts = Options(block_alloc=BlockAlloc.ALLMODE, nnz_block=128,
+                   val_dtype=np.float64)
+    bs = BlockedSparse.from_coo(tt, opts)
+    factors = make_factors(tt.dims)
+    lay = bs.layout_for(0 if path == "sorted_onehot" else 1)
+    want = (["unfused_pallas", "xla_scan", "xla"] if impl != "xla"
+            else ["xla_scan", "xla"])
+    assert engine_chain(lay, factors, 0, path, impl) == want
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
+def test_engine_chain_skips_unfused_pallas_past_vmem(impl):
+    """When not even one block's one-hot, products and partials fit the
+    kernel's VMEM budget (vmem_chunk is 0), the chain starts at
+    xla_scan, which streams the one-hot through HBM instead."""
+    from types import SimpleNamespace
+
+    from splatt_tpu.ops.mttkrp import engine_chain
+
+    facs = [jax.ShapeDtypeStruct((d, 256), jnp.float32)
+            for d in (4096, 48, 80)]
+    lay = SimpleNamespace(block=16384, seg_width=4096, nnz_pad=16384)
+    assert pk.vmem_chunk(4096, 16384, 256) == 0
+    assert engine_chain(lay, facs, 0, "sorted_onehot",
+                        impl) == ["xla_scan", "xla"]
+
+
+def test_demoted_unfused_pallas_lands_on_xla_scan():
+    """A per-shape (RESOURCE) demotion of unfused_pallas leaves xla_scan
+    at the head of the chain for that shape, and dispatch through it
+    matches the brute force."""
+    from splatt_tpu import resilience
+    from splatt_tpu.ops.mttkrp import _engine_shape_key, engine_plan
+
+    tt = gen.fixture_tensor("med")
+    opts = Options(block_alloc=BlockAlloc.ALLMODE, nnz_block=128,
+                   val_dtype=np.float64)
+    bs = BlockedSparse.from_coo(tt, opts)
+    factors = make_factors(tt.dims)
+    lay = bs.layout_for(0)
+    key = _engine_shape_key(lay, factors, 0)
+    resilience.reset_demotions()
+    try:
+        resilience.demote_engine(
+            "unfused_pallas", RuntimeError("RESOURCE_EXHAUSTED: vmem"),
+            shape_key=key)
+        assert engine_plan(lay, factors, 0, "sorted_onehot",
+                           "pallas_interpret") == "xla_scan"
+        got = mttkrp_blocked(lay, factors, 0, path="sorted_onehot",
+                             impl="pallas_interpret")
+        np.testing.assert_allclose(np.asarray(got),
+                                   np_mttkrp(tt, factors, 0), atol=TOL)
+        assert resilience.demotions()[0].shape_key == key
+    finally:
+        resilience.reset_demotions()
 
 
 def test_unfused_pallas_bf16_accumulates_f32():
@@ -313,34 +288,45 @@ def test_pallas_bf16_accumulates_f32():
                                atol=3e-2)
 
 
-def test_fused_tg_production_dims_interpret():
-    """fused_tg index math at real NELL-2 production dims — block 4096,
-    28928-lane padded gathers, rank 50 (the shapes whose Mosaic
-    compiles crash for fused_t) — stays exact in interpret mode."""
+@pytest.mark.parametrize("path,seg", [("sorted_onehot", 8),
+                                      ("sorted_onehot", 16),
+                                      ("privatized", None)])
+def test_unfused_pallas_production_dims_interpret(path, seg):
+    """unfused_pallas at NELL-2's gathered widths (9184 and 28818
+    rows), block 4096 and rank 50, through dispatch in interpret mode:
+    sorted blocks spanning 8 or 16 output rows (the cells' seg_width),
+    and a privatized short mode, match the stream oracle."""
     from splatt_tpu.blocked import build_layout
-    from splatt_tpu.ops.mttkrp import mttkrp_stream
-    from splatt_tpu.ops.pallas_kernels import fused_mttkrp_tg
+    from splatt_tpu.coo import SparseTensor
+    from splatt_tpu.ops.mttkrp import engine_plan, mttkrp_stream
 
     rng = np.random.default_rng(0)
-    dims = (12092, 9184, 28818)
-    nnz, rank = 4096, 50
-    inds = np.stack([rng.integers(0, d, nnz) for d in dims]).astype(np.int64)
-    from splatt_tpu.coo import SparseTensor
-
+    block, rank = 4096, 50
+    nnz = 2 * block
+    if path == "sorted_onehot":
+        dims = (12092, 9184, 28818)
+        # block b holds rows [b*seg, (b+1)*seg): seg_width is seg
+        i0 = np.arange(nnz) * seg // block
+    else:
+        # a short target mode, privatized against mode 1's layout
+        dims = (1000, 9184, 28818)
+        i0 = rng.integers(0, dims[0], nnz)
+    inds = np.stack([i0] + [rng.integers(0, d, nnz)
+                            for d in dims[1:]]).astype(np.int64)
     tt = SparseTensor(inds=inds, vals=rng.standard_normal(nnz), dims=dims)
     fac = [jnp.asarray(rng.standard_normal((d, rank)).astype(np.float32))
            for d in dims]
-    lay = build_layout(tt, 0, block=4096, val_dtype=np.float32)
-    S = lay.seg_width
-    parts = fused_mttkrp_tg(lay, fac, 0, S, accumulate=False,
-                            interpret=True)
-    idx = (np.asarray(lay.row_start)[:, None] + np.arange(S)).reshape(-1)
-    out = np.zeros((dims[0] + S + 1, rank), np.float32)
-    np.add.at(out, idx, np.asarray(parts).reshape(-1, rank))
+    lay = build_layout(tt, 0 if path == "sorted_onehot" else 1,
+                       block=block, val_dtype=np.float32)
+    if seg is not None:
+        assert lay.seg_width == seg
+    assert engine_plan(lay, fac, 0, path,
+                       "pallas_interpret") == "unfused_pallas"
+    got = np.asarray(mttkrp_blocked(lay, fac, 0, path=path,
+                                    impl="pallas_interpret"))
     gold = np.asarray(mttkrp_stream(jnp.asarray(tt.inds),
                                     jnp.asarray(tt.vals), fac, 0, dims[0]))
-    err = (np.abs(out[:dims[0]] - gold).max()
-           / max(np.abs(gold).max(), 1e-9))
+    err = np.abs(got - gold).max() / max(np.abs(gold).max(), 1e-9)
     assert err < 5e-5, err
 
 

@@ -41,6 +41,11 @@ def fake_tpu(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
+def _compiles(fn, regime, block):
+    """A probe compile that succeeds."""
+    return True
+
+
 def _states(snapshot):
     """Context to keep PROBE_STATES isolated per test."""
     pk.PROBE_STATES.clear()
@@ -48,78 +53,82 @@ def _states(snapshot):
 
 
 def test_store_load_roundtrip(cache_file):
-    pk.probe_cache_store("fused_t:ck1:b4096", "ok")
-    assert pk.probe_cache_load("fused_t:ck1:b4096") == "ok"
-    assert pk.probe_cache_load("fused_t:ck1:b128") is None
+    pk.probe_cache_store("fused_dense:ck1:b4096", "ok")
+    assert pk.probe_cache_load("fused_dense:ck1:b4096") == "ok"
+    assert pk.probe_cache_load("fused_dense:ck1:b128") is None
     # the file is keyed by environment (jax version | device kind)
     data = json.loads(cache_file.read_text())
     (env_key,) = data.keys()
     assert jax.__version__ in env_key
 
 
-def test_cache_hit_skips_probe(cache_file, fake_tpu, monkeypatch):
+def test_cache_hit_skips_probe(cache_file, fake_tpu):
     _states({})
     pk.probe_cache_store("testk:ck1:b4096", "compile_failed")
 
     def boom(*a, **k):
         raise AssertionError("probe must not run on a cache hit")
 
-    monkeypatch.setattr(pk, "_probe_case", boom)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = boom
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.PROBE_STATES["testk:ck1:b4096"] == "compile_failed"
 
     pk.probe_cache_store("testk2:ck1:b4096", "ok")
-    assert pk._probe_compiles(None, "testk2", "ck1", 4096) is True
+    assert pk._probe_compiles(None, "testk2", "ck1", 4096, case) is True
     assert pk.PROBE_STATES["testk2:ck1:b4096"] == "ok"
 
 
-def test_cache_miss_runs_probe_and_stores(cache_file, fake_tpu, monkeypatch):
+def test_cache_miss_runs_probe_and_stores(cache_file, fake_tpu):
     _states({})
     calls = []
-    monkeypatch.setattr(pk, "_probe_case",
-                        lambda fn, regime, block: calls.append(1) or True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+
+    def case(fn, regime, block):
+        calls.append(1)
+        return True
+
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert calls == [1]
     assert pk.probe_cache_load("testk:ck1:b4096") == "ok"
     # a second PROCESS (simulated: fresh PROBE_STATES) hits the cache
     _states({})
-    monkeypatch.setattr(pk, "_probe_case",
-                        lambda fn, regime, block: calls.append(2) or True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+
+    def case(fn, regime, block):
+        calls.append(2)
+        return True
+
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert calls == [1], "second process must not re-probe"
 
 
-def test_timeout_is_retried_not_inherited(cache_file, fake_tpu, monkeypatch):
+def test_timeout_is_retried_not_inherited(cache_file, fake_tpu):
     _states({})
     pk.probe_cache_store("testk:ck1:b4096", "timeout")
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
+    case = _compiles
     # an unproven verdict must NOT short-circuit: the probe runs and
     # upgrades the cached state to the proven one
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert pk.probe_cache_load("testk:ck1:b4096") == "ok"
 
 
-def test_infra_error_is_retried_not_inherited(cache_file, fake_tpu,
-                                              monkeypatch):
+def test_infra_error_is_retried_not_inherited(cache_file, fake_tpu):
     _states({})
 
     def flaky(fn, regime, block):
         raise RuntimeError("UNAVAILABLE: TPU backend setup error")
 
-    monkeypatch.setattr(pk, "_probe_case", flaky)
+    case = flaky
     # a transient service failure is NOT a kernel rejection
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.PROBE_STATES["testk:ck1:b4096"] == "infra"
     assert pk.probe_cache_load("testk:ck1:b4096") == "infra"
     # the next process re-probes and can prove the kernel fine
     _states({})
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert pk.probe_cache_load("testk:ck1:b4096") == "ok"
 
 
-def test_transient_500_retried_in_place_then_proven(cache_file, fake_tpu,
-                                                    monkeypatch):
+def test_transient_500_retried_in_place_then_proven(cache_file, fake_tpu):
     """A transient HTTP 500 is retried with backoff INSIDE the probe:
     when the service recovers within the retry budget, the verdict is
     proven in this very process — no demotion at all."""
@@ -132,15 +141,13 @@ def test_transient_500_retried_in_place_then_proven(cache_file, fake_tpu,
             raise RuntimeError("XLA compile: HTTP code 500 from the compile service")
         return True
 
-    monkeypatch.setattr(pk, "_probe_case", flaky_then_ok)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = flaky_then_ok
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert len(calls) == 3
     assert pk.probe_cache_load("testk:ck1:b4096") == "ok"
 
 
-def test_transient_500_never_persisted_as_compile_failed(cache_file,
-                                                         fake_tpu,
-                                                         monkeypatch):
+def test_transient_500_never_persisted_as_compile_failed(cache_file, fake_tpu):
     """ADVICE.md medium: one wedged-service 500 must NOT demote the
     flagship engine for every future session.  Retries exhausted →
     'infra' (re-probed next process); the on-disk cache must contain
@@ -150,8 +157,8 @@ def test_transient_500_never_persisted_as_compile_failed(cache_file,
     def always_500(fn, regime, block):
         raise RuntimeError("XLA compile: HTTP code 500 from the compile service")
 
-    monkeypatch.setattr(pk, "_probe_case", always_500)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = always_500
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.PROBE_STATES["testk:ck1:b4096"] == "infra"
     assert "compile_failed" not in cache_file.read_text()
     # bare INTERNAL: is transient too (no Mosaic co-marker)
@@ -160,17 +167,16 @@ def test_transient_500_never_persisted_as_compile_failed(cache_file,
     def always_internal(fn, regime, block):
         raise RuntimeError("INTERNAL: compile service stream reset")
 
-    monkeypatch.setattr(pk, "_probe_case", always_internal)
-    assert pk._probe_compiles(None, "testk2", "ck1", 4096) is False
+    case = always_internal
+    assert pk._probe_compiles(None, "testk2", "ck1", 4096, case) is False
     assert "compile_failed" not in cache_file.read_text()
     # the next process re-probes and can prove the kernels fine
     _states({})
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
 
 
-def test_internal_mosaic_co_marker_is_deterministic(cache_file, fake_tpu,
-                                                    monkeypatch):
+def test_internal_mosaic_co_marker_is_deterministic(cache_file, fake_tpu):
     """'INTERNAL: Mosaic failed ...' carries a real compiler signature:
     the transient INTERNAL: prefix must not launder it into a retry —
     it persists as a proven rejection."""
@@ -179,14 +185,12 @@ def test_internal_mosaic_co_marker_is_deterministic(cache_file, fake_tpu,
     def mosaic_internal(fn, regime, block):
         raise RuntimeError("INTERNAL: Mosaic failed to lower the kernel")
 
-    monkeypatch.setattr(pk, "_probe_case", mosaic_internal)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = mosaic_internal
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.probe_cache_load("testk:ck1:b4096") == "compile_failed"
 
 
-def test_resource_verdict_is_shape_scoped_and_persisted(cache_file,
-                                                        fake_tpu,
-                                                        monkeypatch):
+def test_resource_verdict_is_shape_scoped_and_persisted(cache_file, fake_tpu):
     """An OOM is capacity, not capability: persisted as 'resource' for
     THIS (regime, block) shape only — other shapes keep probing."""
     _states({})
@@ -194,8 +198,8 @@ def test_resource_verdict_is_shape_scoped_and_persisted(cache_file,
     def oom(fn, regime, block):
         raise RuntimeError("RESOURCE_EXHAUSTED: attempting to allocate 9G")
 
-    monkeypatch.setattr(pk, "_probe_case", oom)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = oom
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.probe_cache_load("testk:ck1:b4096") == "resource"
     # the verdict short-circuits the next process for the same shape
     _states({})
@@ -204,14 +208,14 @@ def test_resource_verdict_is_shape_scoped_and_persisted(cache_file,
         raise AssertionError("probe must not run on a cached resource "
                              "verdict")
 
-    monkeypatch.setattr(pk, "_probe_case", boom)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = boom
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     # ... but a DIFFERENT shape still probes
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 128) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 128, case) is True
 
 
-def test_ttl_expiry_reprobes(cache_file, fake_tpu, monkeypatch):
+def test_ttl_expiry_reprobes(cache_file, fake_tpu):
     """Even a proven verdict expires after the TTL: infrastructure
     drifts under a fixed env key, so stale rejections (and stale OKs)
     are re-earned instead of trusted forever."""
@@ -224,8 +228,8 @@ def test_ttl_expiry_reprobes(cache_file, fake_tpu, monkeypatch):
             time.time() - pk.probe_cache_ttl() - 1)
     cache_file.write_text(json.dumps(data))
     assert pk.probe_cache_load("testk:ck1:b4096") is None
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
     assert pk.probe_cache_load("testk:ck1:b4096") == "ok"
 
 
@@ -245,25 +249,24 @@ def test_kernel_edit_invalidates_cache(cache_file, fake_tpu, monkeypatch):
     # simulate a kernel fix: the module source hash changes, so the old
     # environment's verdicts no longer apply and the probe re-runs
     monkeypatch.setattr(pk, "_kernel_src_hash", lambda: "newhash12345")
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
 
 
-def test_compile_failure_is_stored(cache_file, fake_tpu, monkeypatch):
+def test_compile_failure_is_stored(cache_file, fake_tpu):
     _states({})
 
     def fail(fn, regime, block):
         raise RuntimeError("Mosaic failed to compile the kernel")
 
-    monkeypatch.setattr(pk, "_probe_case", fail)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = fail
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.probe_cache_load("testk:ck1:b4096") == "compile_failed"
     assert pk.PROBE_STATES["testk:ck1:b4096"] == "compile_failed"
 
 
 def test_unrecognized_error_is_not_persisted_as_rejection(cache_file,
-                                                         fake_tpu,
-                                                         monkeypatch):
+                                                          fake_tpu):
     """Only whitelisted deterministic signatures may persist as
     compile_failed — the cache makes misclassification permanent, so an
     unknown exception is unproven and the next process re-probes."""
@@ -272,17 +275,17 @@ def test_unrecognized_error_is_not_persisted_as_rejection(cache_file,
     def weird(fn, regime, block):
         raise OSError("Connection reset by peer")
 
-    monkeypatch.setattr(pk, "_probe_case", weird)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    case = weird
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is False
     assert pk.probe_cache_load("testk:ck1:b4096") == "infra"
     _states({})
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    case = _compiles
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
 
 
 def test_not_tpu_short_circuits_without_cache(cache_file):
     _states({})
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is False
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, _compiles) is False
     assert pk.PROBE_STATES["testk:ck1:b4096"] == "not_tpu"
     assert not cache_file.exists()
 
@@ -293,9 +296,9 @@ def test_cache_io_failure_is_harmless(fake_tpu, monkeypatch, tmp_path):
     blocker = tmp_path / "blocker"
     blocker.write_text("")
     monkeypatch.setenv(pk._CACHE_ENV, str(blocker / "sub" / "cache.json"))
-    monkeypatch.setattr(pk, "_probe_case", lambda fn, regime, block: True)
+    case = _compiles
     # store/load both raise internally; dispatch still gets its verdict
-    assert pk._probe_compiles(None, "testk", "ck1", 4096) is True
+    assert pk._probe_compiles(None, "testk", "ck1", 4096, case) is True
 
 
 def test_reads_route_through_shared_json_cache_load(cache_file,

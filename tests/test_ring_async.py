@@ -222,7 +222,7 @@ def test_comm_fallback_oom_scoped_never_demotes_all2all():
     assert not resilience.is_demoted("comm.async_ring",
                                      "d8x8x8:w8:r5:float64:comm")
     # MTTKRP engine keys are a different namespace entirely
-    assert not resilience.is_demoted("fused_t")
+    assert not resilience.is_demoted("unfused_pallas")
 
 
 def test_demoted_comm_engine_skipped_next_run():

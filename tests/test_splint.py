@@ -1308,7 +1308,7 @@ def test_config_matches_pyproject():
     assert "tile_packing" in cfg.tile_pack_helpers
     assert int(cfg.vmem_budget_mib) > 0
     gate_map = dict(e.split("=") for e in cfg.vmem_gate_map)
-    assert gate_map["fused_mttkrp_t"] == "fused_t_vmem_ok"
+    assert gate_map["onehot_reduce_sorted"] == "vmem_chunk"
     assert "_tuned_plan_for" in cfg.plan_match_functions
     # SPL005 joined the zero-rules in the v5 burn-down
     assert "SPL005" in cfg.zero_rules
@@ -1355,50 +1355,44 @@ def test_spl024_fires_when_gram_pin_reverted(tmp_path):
     assert hits and any("matmul" in f.message for f in hits)
 
 
-def test_spl025_fires_when_rank_pad_reverted(tmp_path):
-    """Reverting a kernel's rank padding to the dtype-blind
-    ``ceil_to(R, 8)`` (the pre-v5 shape: correct for f32, half the
-    sublane tile for bf16) must trip SPL025 on the block position the
-    padded value certifies."""
-    anchor = ("    R8 = _rank_pad(R, dtype)\n"
-              "    others = [k for k in range(layout.nmodes) "
-              "if k != mode]\n"
-              "    grid = (nb,)\n")
+def test_spl025_fires_when_grid_pad_reverted(tmp_path):
+    """Running the one-hot kernel's grid over the unpadded block count
+    (``nb // chunk`` instead of the ``_pad_blocks`` extent) must trip
+    SPL025's grid leg: a ragged tail block would silently drop."""
+    anchor = ("    local, prod, nb_pad = _pad_blocks(local, prod, chunk)\n"
+              "    grid = (nb_pad // chunk,)\n"
+              "    out = pl.pallas_call(\n"
+              "        functools.partial(_sorted_kernel, ")
 
     def mutate(src):
-        assert anchor in src, "pallas_kernels.py rank-pad anchor drifted"
-        return src.replace(
-            anchor,
-            "    R8 = ceil_to(R, 8)\n"
-            "    others = [k for k in range(layout.nmodes) "
-            "if k != mode]\n"
-            "    grid = (nb,)\n", 1)
+        assert anchor in src, "pallas_kernels.py grid-pad anchor drifted"
+        return src.replace(anchor, anchor.replace("(nb_pad // chunk,)",
+                                                  "(nb // chunk,)"), 1)
 
     cfg = _copy_package_tree(
         tmp_path, "splatt_tpu/ops/pallas_kernels.py", mutate)
     hits = [f for f in run(cfg, baseline={}).findings
             if f.rule == "SPL025"]
-    assert hits and any("R8" in f.message for f in hits)
+    assert hits and any("nb // chunk" in f.message for f in hits)
 
 
 def test_spl026_fires_when_gate_consult_dropped(tmp_path):
-    """Short-circuiting the fused_t dispatch gate — the kernel runs
-    whether or not its block plan fits VMEM — must trip SPL026's
+    """Short-circuiting the dense kernel's dispatch gate — the kernel
+    runs whether or not its block plan fits VMEM — must trip SPL026's
     registry leg: the declared gate is never consulted."""
-    anchor = ('    if gather and live("fused_t") and '
-              "fused_t_vmem_ok(factors, mode,")
+    anchor = "                and dense_vmem_ok(layout, factors, mode)):"
 
     def mutate(src):
-        assert anchor in src, "mttkrp.py fused_t gate anchor drifted"
+        assert anchor in src, "mttkrp.py dense gate anchor drifted"
         return src.replace(
             anchor,
-            '    if gather and live("fused_t") and '
-            "(lambda *a: True)(factors, mode,", 1)
+            "                and (lambda *a: True)(layout, factors, mode)):",
+            1)
 
     cfg = _copy_package_tree(tmp_path, "splatt_tpu/ops/mttkrp.py", mutate)
     hits = [f for f in run(cfg, baseline={}).findings
             if f.rule == "SPL026"]
-    assert hits and any("fused_t_vmem_ok" in f.message
+    assert hits and any("dense_vmem_ok" in f.message
                         and "consulted" in f.message for f in hits)
 
 

@@ -28,8 +28,6 @@ RANK = 50
 BLOCK = 4096
 #: max output rows one 4096-nonzero block spans at NELL-2 density
 SEG = 8
-#: what Mosaic (jax 0.9.0) says for a lane gather wider than one vreg
-GATHER_REFUSAL = "Multiple source vregs along gather dimension"
 
 
 @pytest.fixture(scope="module")
@@ -201,36 +199,6 @@ def test_onehot_reduce_compiles(one_chip, kernel, width, rank):
     text = jax.jit(lambda l, p: fn(l + 1, p * 2, width, chunk=chunk)).lower(
         local, prod).compile().as_text()
     assert _scoped_sizes(text, f"onehot_reduce_{kernel}") == [want]
-
-
-def _fused(name):
-    from splatt_tpu.ops import pallas_kernels as pk
-
-    return {"fused_t": pk.fused_mttkrp_t, "fused_tg": pk.fused_mttkrp_tg}[name]
-
-
-@pytest.mark.parametrize("name", ["fused_t", "fused_tg"])
-def test_fused_gather_refused_at_nell2_widths(one_chip, name):
-    """The in-kernel gather family at NELL-2 widths: Mosaic refuses the
-    lane gather over a 9184-row table.  This is why engine_chain keeps
-    these engines off the chip above 128 gathered rows; when a JAX
-    release lowers it, this test fails and the gate can go."""
-    lay = spec_layout(one_chip, 0)
-    with pytest.raises(Exception, match=GATHER_REFUSAL):
-        _fused(name).lower(lay, spec_factors(one_chip), mode=0,
-                           width=SEG, accumulate=False,
-                           interpret=False).compile()
-
-
-@pytest.mark.parametrize("name", ["fused_t", "fused_tg"])
-def test_fused_gather_within_one_vreg(one_chip, name):
-    """...and where every gathered factor fits one 128-lane vreg they
-    compile (the chain admits them on the chip)."""
-    dims = (NELL2[0], 128, 120)
-    lay = spec_layout(one_chip, 0, dims=dims, nnz=1000 * BLOCK)
-    _fused(name).lower(lay, spec_factors(one_chip, dims), mode=0,
-                       width=SEG, accumulate=False,
-                       interpret=False).compile()
 
 
 def test_fused_dense_compiles(one_chip):

@@ -504,7 +504,7 @@ def test_metrics_per_job_isolation():
         resilience.run_report().add("health_rollback", iteration=1,
                                     attempt=1)
     with resilience.scope("tenant-b"):
-        resilience.run_report().add("engine_demotion", engine="fused_t",
+        resilience.run_report().add("engine_demotion", engine="unfused_pallas",
                                     failure_class="oom",
                                     shape_key="k", error="x")
     a_text = trace.metrics_text(job="tenant-a")

@@ -127,7 +127,7 @@ class Config:
     #: dtype-blind padding helpers (ceil_to/_pad_blocks) — values they
     #: produce need a unit that certifies the block position (SPL025)
     align_helpers: List[str] = dataclasses.field(default_factory=list)
-    #: dtype-AWARE padding helpers (_rank_pad/tile_packing) whose
+    #: dtype-AWARE padding helpers (tile_packing) whose
     #: results certify any sublane position (SPL025)
     tile_pack_helpers: List[str] = dataclasses.field(default_factory=list)
     #: declared dispatch envelope for SPL026's static accounting:

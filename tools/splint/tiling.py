@@ -17,7 +17,7 @@ SPL025 tile alignment
       of the position's unit (8 sublane, 128 lane);
     * a name this function PADDED (assigned from ``align-helpers``
       (``ceil_to``/``_pad_blocks``) or ``tile-pack-helpers``
-      (``_rank_pad``/``tile_packing``)) → the pad unit must certify
+      (``tile_packing``)) → the pad unit must certify
       the position — lane: multiple of 128; sublane: a tile-pack
       helper or a multiple of 16 (bf16-safe).  A dtype-blind
       ``ceil_to(R, 8)`` fires: it under-pads 2-byte storage.  This
@@ -55,8 +55,8 @@ SPL026 static VMEM budget
     ``pallas_call`` must appear in ``vmem-gate-map`` (``"fn=gate"``),
     its gate must exist in the same module, and the gate must be
     consulted somewhere outside its own def — an ungated kernel or an
-    orphaned gate is exactly how the fused_t double-buffer
-    undercount shipped.
+    orphaned gate is how a kernel's double-buffer undercount once
+    shipped.
 
 SPL027 plan-cache schema completeness
     Any module assigning ``PLAN_CACHE_VERSION`` must declare
@@ -259,8 +259,8 @@ class TileAlignment(_TilingRule):
 
     id = "SPL025"
     title = "tile-alignment hazard"
-    hint = ("pad the sublane dim through config.tile_packing / "
-            "_rank_pad (dtype-aware: 8 f32, 16 bf16) and lane dims to "
+    hint = ("pad the sublane dim through config.tile_packing "
+            "(dtype-aware: 8 f32, 16 bf16) and lane dims to "
             "multiples of 128; block dims equal to the materialized "
             "array extent are fine.  If Mosaic provably accepts this "
             "shape, add `# splint: ignore[SPL025] <reason>`")
@@ -372,7 +372,7 @@ class TileAlignment(_TilingRule):
         return (f"block sublane dim {name!r} padded with dtype-blind "
                 f"unit {uval}; bf16/f16 storage packs "
                 f"{_NARROW_SUBLANE} sublanes — pad via "
-                "config.tile_packing (see _rank_pad)")
+                "config.tile_packing")
 
     def _check_grid(self, ctx: FileCtx, fn: ast.AST,
                     shapes: _FnShapes) -> List[Finding]:
