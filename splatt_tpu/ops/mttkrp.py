@@ -56,10 +56,12 @@ STREAM_NATIVE_ENGINES = ("xla_scan", "xla")
 #: only: the scopes change no op.
 #:
 #: - operands: stream padding, index decode, factor-row gather, the
-#:   Hadamard product (and the dense path's Khatri-Rao operands);
+#:   Hadamard product outside a kernel (and the dense path's
+#:   Khatri-Rao operands);
 #: - reduce: the reduction itself — the one-hot einsum, a Mosaic
-#:   one-hot kernel with its chunk padding, ``segment_sum``, the dense
-#:   ``dot_general``, or the ``fused_dense`` kernel whole;
+#:   one-hot kernel (which forms its Hadamard products in VMEM) with
+#:   its chunk padding, ``segment_sum``, the dense ``dot_general``, or
+#:   the ``fused_dense`` kernel whole;
 #: - merge: block partials into output rows (``out.at[idx].add``) and
 #:   the final slice.
 OPERANDS, REDUCE, MERGE = PHASES = ("mttkrp.operands", "mttkrp.reduce",
@@ -267,8 +269,8 @@ def dense_mttkrp(layout, factors: Sequence[jax.Array],
 #: elements of one-hot materialized per scan step of the XLA engine —
 #: the fallback's main tuning knob (more = fewer, bigger fused steps).
 #: Env-overridable so a tuning sweep can measure it.  The unfused
-#: Pallas engine sizes its steps by the same budget, counted in
-#: materialized (C, B, R) products instead.
+#: Pallas engine sizes its steps by the same budget, counted in each
+#: gathered (C, B, R) row array instead.
 _SCAN_TARGET = read_env_int("SPLATT_SCAN_TARGET_ELEMS")
 
 
@@ -294,14 +296,16 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
     per fiber inside the traversal (src/mttkrp.c:427-463) rather than
     staging an intermediate.
 
-    The reduction is an XLA einsum (the ``xla_scan`` engine), or with
-    `pallas` the Mosaic one-hot kernels (the ``unfused_pallas`` engine:
-    onehot_reduce_sorted / onehot_reduce_full), which build the one-hot
-    in VMEM instead of materializing it.  `target_elems` bounds the
-    elements one step materializes in HBM: the one-hot for the einsum,
-    the (C, B, R) products for the kernels.  A scan step is a whole
-    number of kernel grid steps (pallas_kernels.dividing_chunk), so the
-    kernels never pad the step's products to a larger chunk.
+    The reduction is an XLA einsum over the step's products (the
+    ``xla_scan`` engine), or with `pallas` the Mosaic one-hot kernels
+    (the ``unfused_pallas`` engine: onehot_reduce_sorted /
+    onehot_reduce_full), which take the gathered rows and the values,
+    form the products and the one-hot in VMEM, and so materialize
+    neither in HBM.  `target_elems` bounds the elements one step
+    materializes in HBM: the one-hot for the einsum, each gathered
+    (C, B, R) row array for the kernels.  The kernels run one block per
+    grid step (pallas_kernels.vmem_chunk), so they never pad the step's
+    rows to a larger chunk.
     """
     nb, B = layout.nblocks, layout.block
     R = int(factors[0].shape[1])
@@ -366,7 +370,7 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
         # v1) — decoded here, in registers, via the shared helpers
         inds_c, vals_c, rs_c, base_c = xs
         with jax.named_scope(OPERANDS):
-            prod = vals_c.astype(dtype)[..., None]
+            rows = []
             for k in range(nmodes):
                 if k != mode:
                     # decode_global_ids handles every stream kind — incl.
@@ -376,9 +380,12 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
                         inds_c[k],
                         None if base_c is None else base_c[k][:, None],
                         encs[k], B)
-                    rows = jnp.take(factors[k], g.reshape(-1), axis=0,
-                                    mode="clip").reshape(C, B, R)
-                    prod = prod * rows
+                    rows.append(jnp.take(factors[k], g.reshape(-1), axis=0,
+                                         mode="clip").reshape(C, B, R))
+            if not pallas:
+                prod = vals_c.astype(dtype)[..., None]
+                for r in rows:
+                    prod = prod * r
             if accumulate:
                 if base_c is None:
                     local = inds_c[mode]
@@ -393,15 +400,15 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
         with jax.named_scope(REDUCE):
             if pallas:
                 from splatt_tpu.ops.pallas_kernels import (
-                    dividing_chunk, onehot_reduce_full, onehot_reduce_sorted)
+                    onehot_reduce_full, onehot_reduce_sorted, vmem_chunk)
 
-                kchunk = dividing_chunk(C, width, B, R,
-                                        jnp.dtype(dtype).itemsize)
+                kchunk = vmem_chunk(width, B, R, len(rows), dtype)
                 if accumulate:
                     return carry + onehot_reduce_full(
-                        local, prod, width, interpret=interpret,
+                        local, vals_c, rows, width, interpret=interpret,
                         chunk=kchunk), None
-                return carry, onehot_reduce_sorted(local, prod, width,
+                return carry, onehot_reduce_sorted(local, vals_c, rows,
+                                                   width,
                                                    interpret=interpret,
                                                    chunk=kchunk)
             onehot = (local[:, None, :] == iota[None, :, None]).astype(dtype)
@@ -650,8 +657,7 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
                                enc=getattr(layout, "format_desc",
                                            lambda: "i32/glob/?")(),
                                kernel_vmem=_kernel_vmem(
-                                   layout, factors, mode, path, engine,
-                                   scan_target),
+                                   layout, factors, mode, path, engine),
                                tables_vmem=_tables_vmem(factors, mode))
                     if trace.enabled() else trace.NOOP)
             with span:
@@ -812,8 +818,7 @@ def _onehot_width(layout: ModeLayout, dim: int, path: str) -> int:
 
 
 def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
-                 mode: int, path: str, engine: str,
-                 scan_target: int) -> int:
+                 mode: int, path: str, engine: str) -> int:
     """Scoped VMEM `engine`'s Mosaic kernel asks for on this call: the
     one-hot reduce's request at the chunk :func:`_scan_fused` gives it,
     the device's whole ``vmem_limit`` for ``fused_dense``, 0 for the
@@ -823,11 +828,10 @@ def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
     if engine == "unfused_pallas":
         R, B = int(factors[0].shape[1]), layout.block
         dtype = factors[0].dtype
+        nrows = len(factors) - 1
         width = _onehot_width(layout, int(factors[mode].shape[0]), path)
-        chunk = pk.dividing_chunk(
-            _block_chunks(layout.nblocks, R * B, scan_target), width, B, R,
-            jnp.dtype(dtype).itemsize)
-        return pk.onehot_vmem_request(chunk, width, B, R, dtype)
+        chunk = pk.vmem_chunk(width, B, R, nrows, dtype)
+        return pk.onehot_vmem_request(chunk, width, B, R, nrows, dtype)
     if engine == "fused_dense":
         return pk._vmem_limit()
     return 0
@@ -897,7 +901,6 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
     dim = int(factors[mode].shape[0])
     R = int(factors[0].shape[1])
     B = layout.block
-    itemsize = jnp.dtype(factors[0].dtype).itemsize
     pallas = impl in ("pallas", "pallas_interpret")
     width = _onehot_width(layout, dim, path)
     if shape_key is None:
@@ -908,7 +911,8 @@ def engine_chain(layout: ModeLayout, factors: List[jax.Array], mode: int,
 
     chain = []
     if (pallas and live("unfused_pallas")
-            and vmem_chunk(width, B, R, itemsize) >= 1):
+            and vmem_chunk(width, B, R, len(factors) - 1,
+                           factors[0].dtype) >= 1):
         chain.append("unfused_pallas")
     if live("xla_scan"):
         chain.append("xla_scan")

@@ -2,15 +2,17 @@
 
 The performance-critical reduction in blocked MTTKRP is
 
-    out[b, s, :] = Σ_j  [local[b, j] == s] · prod[b, j, :]
+    out[b, s, :] = Σ_j  [local[b, j] == s] · val[b, j] · ⊛_k rows_k[b, j, :]
 
 i.e. a per-block one-hot contraction (S×B)@(B×R) — the TPU replacement
 for the reference's scattered accumulation with its mutex pool /
 privatization / tile scheduling (src/mttkrp.c:104-236).  XLA executes
 the same einsum but materializes the one-hot operand (nb·S·B elements)
-in HBM; the Pallas kernel builds it on the fly in VMEM with a
-broadcasted iota-compare and feeds the MXU directly, so HBM traffic is
-just prod in + partials out.
+in HBM; the Pallas kernels build it on the fly in VMEM with a
+broadcasted iota-compare, fold the values into it, form the Hadamard
+product of the gathered factor rows on the VPU and feed both to the
+MXU, so HBM traffic is just the ids, values and gathered rows in +
+partials out: the (nb, B, R) products never exist in HBM.
 
 Two variants:
 - :func:`onehot_reduce_sorted`  — per-block partials (sorted layouts,
@@ -35,8 +37,8 @@ from splatt_tpu.ops.mttkrp import (MERGE, OPERANDS, REDUCE, _acc_dtype,
                                    mxu_precision)
 from splatt_tpu.utils.env import ceil_to
 
-# Max blocks per grid step; the actual chunk is sized against VMEM by
-# vmem_chunk() below.
+# Blocks per grid step of a direct kernel call; the MTTKRP scan passes
+# vmem_chunk()'s one block instead.
 _CHUNK = 8
 
 @functools.cache
@@ -60,40 +62,22 @@ def _compiler_params():
     return pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit())
 
 
-def vmem_chunk(width: int, block: int, rank: int,
-               itemsize: int = 4, budget_bytes: int = None,
-               out_itemsize: int = None) -> int:
-    """Blocks per grid step such that the kernel's working set —
-    one-hot (C,width,block) + prod (C,block,rank) + out (C,width,rank) —
-    fits the VMEM budget (_vmem_budget()//2, against the measured 128MiB
-    v5e VMEM and the raised _VMEM_LIMIT compiler cap, leaving room for
-    double buffering).  The out term is costed at the accumulator
-    width (f32 even for bf16 inputs).  Returns 0 when even one block
-    does not fit: callers must fall back to the XLA engine, which
-    streams the one-hot through HBM instead.
+def vmem_chunk(width: int, block: int, rank: int, nrows: int,
+               dtype) -> int:
+    """Blocks per grid step of the MTTKRP scan's one-hot reduce kernels
+    reading `nrows` gathered row arrays: 1 where one block's
+    :func:`onehot_vmem_request` fits the kernel budget, else 0, and
+    callers fall back to the XLA engine, which streams the one-hot
+    through HBM instead.
+
+    One block is the least a kernel can reserve, and what it does not
+    reserve goes to XLA, which keeps the scan's live buffers in VMEM
+    when they fit: the gathered factor tables and the step's gathered
+    row arrays, each of which would otherwise make an HBM round trip.
+    A larger chunk would save only per-grid-step overhead.
     """
-    if budget_bytes is None:
-        budget_bytes = _vmem_budget() // 2
-    if out_itemsize is None:
-        out_itemsize = max(itemsize, 4)
-    per_block = ((width * block + block * rank) * itemsize
-                 + width * rank * out_itemsize)
-    if per_block <= 0:
-        return _CHUNK
-    return min(_CHUNK, budget_bytes // per_block)
-
-
-def dividing_chunk(nblocks: int, width: int, block: int, rank: int,
-                   itemsize: int = 4) -> int:
-    """Blocks per grid step for a call on exactly `nblocks` blocks: the
-    largest divisor of `nblocks` no larger than :func:`vmem_chunk`'s
-    cap, so the call is a whole number of grid steps and
-    :func:`_pad_blocks` copies nothing (a 10-block scan step under a
-    cap of 8 runs as 2 steps of 5, not 16 blocks with 6 of zeros).
-    0 exactly when :func:`vmem_chunk` is 0."""
-    cap = vmem_chunk(width, block, rank, itemsize)
-    return max((d for d in range(1, min(cap, nblocks) + 1)
-                if nblocks % d == 0), default=0)
+    need = _onehot_vmem_bytes(1, width, block, rank, nrows, dtype)
+    return 1 if need <= _vmem_budget() else 0
 
 
 def padded_bytes(shape, itemsize: int) -> int:
@@ -111,63 +95,95 @@ def padded_bytes(shape, itemsize: int) -> int:
 #: room above :func:`onehot_vmem_request`'s counted buffers
 _ONEHOT_VMEM_MARGIN = 4 << 20
 
+#: whole (chunk, width, block) f32 copies of the weighted one-hot that
+#: Mosaic's dot keeps when the rank spans more than one 128-lane tile;
+#: they cover the kernel's other temporaries too.  At rank 200 a 512- or
+#: 1024-wide full kernel needs 48.4 / 80.6 MiB of scoped VMEM, 3.9
+#: copies above its blocks (AOT compile for a v5e).  Within one lane
+#: tile the dot streams the one-hot and keeps no copy: a 1717-wide full
+#: kernel at rank 50 or 128 needs 11.4 / 12.4 MiB.
+_DOT_ONEHOT_COPIES = 4
+
+
+def _onehot_vmem_bytes(chunk: int, width: int, block: int, rank: int,
+                       nrows: int, dtype) -> int:
+    """:func:`onehot_vmem_request` before its cap at the device's
+    ``vmem_limit``."""
+    item = jnp.dtype(dtype).itemsize
+    acc = jnp.dtype(_acc_dtype(dtype)).itemsize
+    ids = padded_bytes((chunk, 1, block), 4)
+    vals = padded_bytes((chunk, 1, block), item)
+    rows = padded_bytes((chunk, block, rank), item)
+    out = padded_bytes((chunk, width, rank), acc)
+    copies = _DOT_ONEHOT_COPIES if rank > 128 else 0
+    blocks = 2 * (ids + vals + nrows * rows + out)
+    temps = max(rows + out,
+                copies * padded_bytes((chunk, width, block), 4))
+    return blocks + temps + _ONEHOT_VMEM_MARGIN
+
 
 def onehot_vmem_request(chunk: int, width: int, block: int, rank: int,
-                        dtype) -> int:
+                        nrows: int, dtype) -> int:
     """Scoped VMEM the one-hot reduce kernels ask Mosaic for at a grid
-    step of `chunk` blocks: the double-buffered input blocks (local ids
-    (chunk, 1, block) i32, products (chunk, block, rank)), the
+    step of `chunk` blocks and `nrows` gathered row arrays: the
+    double-buffered input blocks (local ids (chunk, 1, block) i32,
+    values (chunk, 1, block), each row array (chunk, block, rank)), the
     double-buffered output block (chunk, width, rank) at the accumulator
-    dtype (an upper bound for onehot_reduce_full's (width, rank)), the
-    in-kernel one-hot (chunk, width, block), the i32 iota it is compared
-    against and the dot result, each tile-padded, plus
+    dtype (an upper bound for onehot_reduce_full's (width, rank)), and
+    the larger of the rows' product (chunk, block, rank) with the dot
+    result and, where the rank passes one 128-lane tile,
+    :data:`_DOT_ONEHOT_COPIES` of the one-hot, each tile-padded, plus
     :data:`_ONEHOT_VMEM_MARGIN`; at most the device's ``vmem_limit``.
+    The values are counted at the rows' dtype: a one-row block pads to
+    the same bytes at 16 and 32 bits.
     What a kernel does not reserve stays with XLA, which keeps the
     scan's live buffers (the gathered factor tables) in VMEM when they
     fit."""
-    item = jnp.dtype(dtype).itemsize
-    acc = jnp.dtype(_acc_dtype(dtype)).itemsize
-    out = padded_bytes((chunk, width, rank), acc)
-    blocks = 2 * (padded_bytes((chunk, 1, block), 4)
-                  + padded_bytes((chunk, block, rank), item) + out)
-    temps = (padded_bytes((chunk, width, block), item)
-             + padded_bytes((chunk, width, block), 4) + out)
-    return min(blocks + temps + _ONEHOT_VMEM_MARGIN, _vmem_limit())
+    return min(_onehot_vmem_bytes(chunk, width, block, rank, nrows, dtype),
+               _vmem_limit())
 
 
 def _onehot_compiler_params(chunk: int, width: int, block: int, rank: int,
-                            dtype):
+                            nrows: int, dtype):
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(vmem_limit_bytes=onehot_vmem_request(
-        chunk, width, block, rank, dtype))
+        chunk, width, block, rank, nrows, dtype))
 
 
-def _sorted_kernel(local_ref, prod_ref, out_ref, *, seg_width: int):
+def _partials(local_ref, vals_ref, row_refs, width: int, out_dtype):
+    """(C, width, R) block partials ``W @ P`` of one grid step: ``P``
+    the Hadamard product of the gathered rows, formed on the VPU, and
+    ``W`` the one-hot of the local ids with each hit carrying its
+    nonzero's value — the values share the ids' lane layout, so they
+    fold into the one-hot with no transpose."""
+    prod = row_refs[0][...]                     # (C, B, R)
+    for ref in row_refs[1:]:
+        prod = prod * ref[...]
     local = local_ref[:, 0, :]                  # (C, B) int32
-    prod = prod_ref[...]                        # (C, B, R)
+    vals = vals_ref[:, 0, :].astype(prod.dtype)
     C, B = local.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (C, seg_width, B), 1)
-    onehot = (local[:, None, :] == iota).astype(prod.dtype)
-    out_ref[...] = jax.lax.dot_general(
-        onehot, prod,
+    iota = jax.lax.broadcasted_iota(jnp.int32, (C, width, B), 1)
+    weights = jnp.where(local[:, None, :] == iota, vals[:, None, :],
+                        jnp.zeros((), prod.dtype))
+    return jax.lax.dot_general(
+        weights, prod,
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=out_ref.dtype,
+        preferred_element_type=out_dtype,
         precision=mxu_precision(prod.dtype))
 
 
-def _full_kernel(local_ref, prod_ref, out_ref, *, width: int):
-    local = local_ref[:, 0, :]                  # (C, B) int32
-    prod = prod_ref[...]                        # (C, B, R)
-    C, B = local.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (C, width, B), 1)
-    onehot = (local[:, None, :] == iota).astype(prod.dtype)
-    part = jax.lax.dot_general(
-        onehot, prod,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=out_ref.dtype,
-        precision=mxu_precision(prod.dtype))    # (C, width, R)
-    acc = jnp.sum(part, axis=0)
+def _sorted_kernel(local_ref, vals_ref, *refs, seg_width: int):
+    *row_refs, out_ref = refs
+    out_ref[...] = _partials(local_ref, vals_ref, row_refs, seg_width,
+                             out_ref.dtype)
+
+
+def _full_kernel(local_ref, vals_ref, *refs, width: int):
+    *row_refs, out_ref = refs
+    acc = jnp.sum(_partials(local_ref, vals_ref, row_refs, width,
+                            out_ref.dtype), axis=0,
+                  dtype=_acc_dtype(out_ref.dtype))
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -178,76 +194,89 @@ def _full_kernel(local_ref, prod_ref, out_ref, *, width: int):
         out_ref[...] += acc
 
 
-def _pad_blocks(local: jax.Array, prod: jax.Array, chunk: int):
-    """Pad to whole chunks; local gains a singleton middle dim so its
-    Mosaic block shape (chunk, 1, B) is legal for any chunk (the last
-    two block dims must divide (8, 128) or equal the array dims).
+def _pad_blocks(local: jax.Array, vals: jax.Array, rows, chunk: int):
+    """Pad to whole chunks (ids -1, values and rows 0); local and vals
+    gain a singleton middle dim so their Mosaic block shape
+    (chunk, 1, B) is legal for any chunk (the last two block dims must
+    divide (8, 128) or equal the array dims).
 
-    The pad is a fresh HBM copy of `prod`; only direct callers pay it.
-    ``_scan_fused`` passes a :func:`dividing_chunk`, so its scan step
-    is a whole number of kernel steps and nothing is padded here."""
+    The pad is a fresh HBM copy of every row array; only direct callers
+    pay it.  ``_scan_fused`` passes :func:`vmem_chunk`'s one block,
+    so nothing is padded here."""
     nb = local.shape[0]
     nb_pad = ceil_to(max(nb, 1), chunk)
     if nb_pad != nb:
-        local = jnp.pad(local, ((0, nb_pad - nb), (0, 0)),
-                        constant_values=-1)
-        prod = jnp.pad(prod, ((0, nb_pad - nb), (0, 0), (0, 0)))
-    return local[:, None, :], prod, nb_pad
+        pad = ((0, nb_pad - nb), (0, 0))
+        local = jnp.pad(local, pad, constant_values=-1)
+        vals = jnp.pad(vals, pad)
+        rows = [jnp.pad(r, pad + ((0, 0),)) for r in rows]
+    return local[:, None, :], vals[:, None, :], list(rows), nb_pad
 
 
 @functools.partial(jax.jit,
                    static_argnames=("seg_width", "interpret", "chunk"))
-def onehot_reduce_sorted(local: jax.Array, prod: jax.Array, seg_width: int,
-                         interpret: bool = False,
+def onehot_reduce_sorted(local: jax.Array, vals: jax.Array, rows,
+                         seg_width: int, interpret: bool = False,
                          chunk: int = _CHUNK) -> jax.Array:
-    """(nb, B) local ids + (nb, B, R) partials → (nb, S, R) block partials."""
+    """(nb, B) local ids, (nb, B) values and the gathered factor rows
+    (a sequence of (nb, B, R) arrays) → (nb, S, R) block partials
+    ``Σ_j [local == s] · vals · ⊛ rows``."""
     nb = local.shape[0]
     B = local.shape[1]
-    R = prod.shape[-1]
-    local, prod, nb_pad = _pad_blocks(local, prod, chunk)
+    R = rows[0].shape[-1]
+    dtype = rows[0].dtype
+    row_specs = [pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0))
+                 for _ in rows]
+    local, vals, rows, nb_pad = _pad_blocks(local, vals, rows, chunk)
     grid = (nb_pad // chunk,)
     out = pl.pallas_call(
         functools.partial(_sorted_kernel, seg_width=seg_width),
         grid=grid,
         in_specs=[
             pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0)),
+            pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
+            *row_specs,
         ],
         out_specs=pl.BlockSpec((chunk, seg_width, R), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb_pad, seg_width, R),
-                                       _acc_dtype(prod.dtype)),
+                                       _acc_dtype(dtype)),
         interpret=interpret,
         name="onehot_reduce_sorted",
         compiler_params=_onehot_compiler_params(chunk, seg_width, B, R,
-                                                prod.dtype),
-    )(local, prod)
+                                                len(rows), dtype),
+    )(local, vals, *rows)
     return out[:nb]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("width", "interpret", "chunk"))
-def onehot_reduce_full(local: jax.Array, prod: jax.Array, width: int,
+def onehot_reduce_full(local: jax.Array, vals: jax.Array, rows, width: int,
                        interpret: bool = False,
                        chunk: int = _CHUNK) -> jax.Array:
-    """(nb, B) ids + (nb, B, R) partials → (width, R) total (privatized)."""
+    """(nb, B) ids, (nb, B) values and the gathered factor rows (a
+    sequence of (nb, B, R) arrays) → (width, R) total (privatized)."""
     B = local.shape[1]
-    R = prod.shape[-1]
-    local, prod, nb_pad = _pad_blocks(local, prod, chunk)
+    R = rows[0].shape[-1]
+    dtype = rows[0].dtype
+    row_specs = [pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0))
+                 for _ in rows]
+    local, vals, rows, nb_pad = _pad_blocks(local, vals, rows, chunk)
     grid = (nb_pad // chunk,)
     out = pl.pallas_call(
         functools.partial(_full_kernel, width=width),
         grid=grid,
         in_specs=[
             pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
-            pl.BlockSpec((chunk, B, R), lambda i: (i, 0, 0)),
+            pl.BlockSpec((chunk, 1, B), lambda i: (i, 0, 0)),
+            *row_specs,
         ],
         out_specs=pl.BlockSpec((width, R), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(prod.dtype)),
+        out_shape=jax.ShapeDtypeStruct((width, R), _acc_dtype(dtype)),
         interpret=interpret,
         name="onehot_reduce_full",
         compiler_params=_onehot_compiler_params(chunk, width, B, R,
-                                                prod.dtype),
-    )(local, prod)
+                                                len(rows), dtype),
+    )(local, vals, *rows)
     return out
 
 

@@ -70,7 +70,7 @@ def test_dispatch_with_tracing_off_never_builds_attributes(monkeypatch):
         "mode": 0, "path": "sorted_onehot", "engine": engine,
         "block": lay.block, "enc": lay.format_desc(),
         "kernel_vmem": mk._kernel_vmem(lay, factors, 0, "sorted_onehot",
-                                       engine, mk._SCAN_TARGET),
+                                       engine),
         "tables_vmem": mk._tables_vmem(factors, 0)}
     assert engine in mk.engine_chain(lay, factors, 0)
 
@@ -106,11 +106,10 @@ def test_dispatch_vmem_attributes_only_with_tracing_on(monkeypatch, path,
     R, B = factors[0].shape[1], lay.block
     width = (lay.seg_width if path == "sorted_onehot"
              else -(-(tt.dims[0] + 1) // 8) * 8)
-    C = min(mk._SCAN_TARGET // (R * B), lay.nblocks)
-    chunk = pk.dividing_chunk(C, width, B, R, 8)
+    chunk = pk.vmem_chunk(width, B, R, 2, factors[0].dtype)
     assert span["args"]["engine"] == "unfused_pallas"
     assert span["args"]["kernel_vmem"] == pk.onehot_vmem_request(
-        chunk, width, B, R, factors[0].dtype) > 0
+        chunk, width, B, R, 2, factors[0].dtype) > 0
     assert span["args"]["tables_vmem"] == sum(
         -(-d // 8) * 8 * -(-R // 128) * 128 * 8 for d in tt.dims[1:])
 

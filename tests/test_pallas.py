@@ -35,12 +35,25 @@ def _np_onehot_sorted(local, prod, S):
     return out
 
 
+def _kernel_inputs(rng, nb, B, R, nrows=2, dtype=np.float64):
+    """Values (nb, B) and `nrows` gathered row arrays (nb, B, R), and
+    their products ``vals · ⊛ rows`` in float64 from the values as
+    passed."""
+    vals = jnp.asarray(rng.random((nb, B)), dtype=dtype)
+    rows = [jnp.asarray(rng.random((nb, B, R)), dtype=dtype)
+            for _ in range(nrows)]
+    prod = np.asarray(vals, np.float64)[..., None]
+    for r in rows:
+        prod = prod * np.asarray(r, np.float64)
+    return vals, rows, prod
+
+
 def test_onehot_reduce_sorted_kernel():
     rng = np.random.default_rng(0)
     nb, B, S, R = 5, 128, 16, 8
     local = rng.integers(-1, S + 3, size=(nb, B)).astype(np.int32)
-    prod = rng.random((nb, B, R))
-    got = onehot_reduce_sorted(jnp.asarray(local), jnp.asarray(prod), S,
+    vals, rows, prod = _kernel_inputs(rng, nb, B, R)
+    got = onehot_reduce_sorted(jnp.asarray(local), vals, rows, S,
                                interpret=True)
     np.testing.assert_allclose(np.asarray(got),
                                _np_onehot_sorted(local, prod, S), atol=TOL)
@@ -50,43 +63,189 @@ def test_onehot_reduce_full_kernel():
     rng = np.random.default_rng(1)
     nb, B, W, R = 9, 128, 24, 8  # nb not divisible by the chunk size
     local = rng.integers(0, W, size=(nb, B)).astype(np.int32)
-    prod = rng.random((nb, B, R))
-    got = onehot_reduce_full(jnp.asarray(local), jnp.asarray(prod), W,
+    vals, rows, prod = _kernel_inputs(rng, nb, B, R)
+    got = onehot_reduce_full(jnp.asarray(local), vals, rows, W,
                              interpret=True)
     want = _np_onehot_sorted(local, prod, W).sum(axis=0)
     np.testing.assert_allclose(np.asarray(got), want, atol=TOL)
 
 
+#: (values, factor rows) dtypes: f32 throughout; bf16 storage of the
+#: values beside f32 factors (the cells' ``val_storage="bf16"``); bf16
+#: throughout
+_KERNEL_DTYPES = {"f32": (jnp.float32, jnp.float32),
+                  "bf16vals": (jnp.bfloat16, jnp.float32),
+                  "bf16": (jnp.bfloat16, jnp.bfloat16)}
+
+
+def _stream_case(nmodes, rank, val_dtype, dtype, B=128, nblocks=4):
+    """A tensor with one output mode sorted into `nblocks` blocks of B
+    nonzeros, as the scan step hands them to the kernels: the local
+    ids, values and gathered rows per block, the blocks' first rows,
+    and the stream oracle over the same (rounded) values and factors.
+    The last real block is ragged (its tail ids -1, values 0: the
+    sentinel), and an all-zero block follows it."""
+    from splatt_tpu.ops.mttkrp import mttkrp_stream
+
+    rng = np.random.default_rng(nmodes * 1000 + rank)
+    dims = (40, 23, 31, 17)[:nmodes]
+    nnz = (nblocks - 1) * B - B // 3
+    inds = np.stack([np.sort(rng.integers(0, dims[0], nnz))]
+                    + [rng.integers(0, d, nnz) for d in dims[1:]])
+    vals = jnp.asarray(rng.random(nnz), dtype=val_dtype)
+    factors = [jnp.asarray(rng.random((d, rank)), dtype=dtype)
+               for d in dims]
+    gold = mttkrp_stream(jnp.asarray(inds),
+                         jnp.asarray(vals, jnp.float64),
+                         [jnp.asarray(f, jnp.float64) for f in factors],
+                         0, dims[0])
+    pad = nblocks * B - nnz
+    ids = np.concatenate([inds[0], np.full(pad, -1)]).reshape(nblocks, B)
+    row_start = np.where(ids >= 0, ids, dims[0]).min(axis=1)
+    local = np.where(ids >= 0, ids - row_start[:, None], -1)
+    vals_b = jnp.pad(vals, (0, pad)).reshape(nblocks, B)
+    rows = [jnp.pad(factors[k][inds[k]], ((0, pad), (0, 0)))
+            .reshape(nblocks, B, rank) for k in range(1, nmodes)]
+    return (jnp.asarray(local, jnp.int32), vals_b, rows, row_start,
+            np.asarray(ids), np.asarray(gold), dims[0])
+
+
+@pytest.mark.parametrize("rank", [50, 200])
+@pytest.mark.parametrize("nmodes", [3, 4])
+@pytest.mark.parametrize("dtypes", sorted(_KERNEL_DTYPES))
+@pytest.mark.parametrize("kernel", ["sorted", "full"])
+def test_onehot_kernels_match_stream(kernel, dtypes, nmodes, rank):
+    """The kernels, fed the ids, values and nmodes - 1 gathered row
+    arrays of a sorted mode (a ragged sentinel tail and an all-zero
+    block included), match mttkrp_stream over the same values and
+    factors: the per-block partials merged at their first rows, or the
+    full-width total.  bf16 inputs accumulate in f32 (SPL024)."""
+    val_dtype, dtype = _KERNEL_DTYPES[dtypes]
+    local, vals, rows, row_start, ids, gold, dim = _stream_case(
+        nmodes, rank, val_dtype, dtype)
+    assert len(rows) == nmodes - 1
+    if kernel == "sorted":
+        S = int(np.asarray(local).max()) + 1
+        parts = onehot_reduce_sorted(local, vals, rows, S, interpret=True,
+                                     chunk=2)
+        assert parts.dtype == jnp.float32
+        got = np.zeros((dim + S + 1, rank))
+        np.add.at(got, (row_start[:, None] + np.arange(S)).reshape(-1),
+                  np.asarray(parts, np.float64).reshape(-1, rank))
+    else:
+        W = -(-(dim + 1) // 8) * 8
+        got = onehot_reduce_full(jnp.asarray(np.where(ids >= 0, ids, dim),
+                                             jnp.int32),
+                                 vals, rows, W, interpret=True, chunk=3)
+        assert got.dtype == jnp.float32
+        got = np.asarray(got, np.float64)
+    err = np.abs(got[:dim] - gold).max() / np.abs(gold).max()
+    assert err < (1e-6 if dtype == jnp.float32 else 1e-2), err
+
+
 def test_onehot_vmem_request_counts_tile_padding():
-    # rank 200 pads to 256 lanes: a (5, 4096, 200) f32 products block,
-    # double-buffered, is 40 MiB on its own
-    assert pk.padded_bytes((5, 4096, 200), 4) == 5 * 4096 * 256 * 4
+    # rank 200 pads to 256 lanes: two (1, 4096, 200) f32 row blocks,
+    # double-buffered, are 16 MiB on their own
+    assert pk.padded_bytes((1, 4096, 200), 4) == 4096 * 256 * 4
     assert pk.padded_bytes((5, 1, 4096), 4) == 5 * 8 * 4096 * 4
+    assert pk.padded_bytes((5, 1, 4096), 2) == 5 * 8 * 4096 * 4
     assert pk.padded_bytes((3, 16), 2) == 16 * 128 * 2
-    req = pk.onehot_vmem_request(5, 8, 4096, 200, jnp.float32)
-    assert req >= 2 * pk.padded_bytes((5, 4096, 200), 4) >= 40 << 20
+    req = pk.onehot_vmem_request(1, 8, 4096, 200, 2, jnp.float32)
+    assert req >= 2 * 2 * pk.padded_bytes((1, 4096, 200), 4) >= 16 << 20
     # ...and asks for less than the flat limit it replaces
     assert req < pk._vmem_limit()
+
+
+#: the request the kernels made at NELL-2's scan steps (10 blocks of
+#: 4096 at rank 200 as 2 grid steps of 5, 40 at rank 50 as 5 of 8) when
+#: they read one (C, B, R) products block: (rank, seg_width) → bytes
+_PRODUCTS_KERNEL_REQUEST = {(200, 8): 48_881_664, (50, 8): 42_041_344,
+                            (50, 16): 44_236_800}
+
+
+@pytest.mark.parametrize("rank,seg", sorted(_PRODUCTS_KERNEL_REQUEST))
+def test_onehot_vmem_request_at_nell2_steps(rank, seg):
+    """At NELL-2's scan steps (block 4096; 10 blocks at rank 200, 40 at
+    rank 50; seg widths 8 and 16) the kernels reading two gathered row
+    arrays, one block per grid step, ask for no more VMEM than the
+    products kernel did, so XLA keeps the room it had for the gathered
+    tables."""
+    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
+
+    C = _block_chunks(18770, rank * 4096, _SCAN_TARGET)
+    assert C == {200: 10, 50: 40}[rank]
+    chunk = pk.vmem_chunk(seg, 4096, rank, 2, jnp.float32)
+    assert chunk == 1
+    req = pk.onehot_vmem_request(chunk, seg, 4096, rank, 2, jnp.float32)
+    assert req <= _PRODUCTS_KERNEL_REQUEST[rank, seg]
+
+
+def test_vmem_chunk_counts_every_row_array():
+    """Each gathered row array is one more double-buffered (1, B, R)
+    block: the request grows by exactly two padded row blocks per row
+    array, and the gate closes once they no longer fit."""
+    for rank in (50, 200):
+        reqs = [pk.onehot_vmem_request(1, 8, 4096, rank, n, jnp.float32)
+                for n in (1, 2, 3)]
+        step = 2 * pk.padded_bytes((1, 4096, rank), 4)
+        assert reqs[1] - reqs[0] == reqs[2] - reqs[1] == step
+        assert [pk.vmem_chunk(8, 4096, rank, n, jnp.float32)
+                for n in (1, 2, 3, 7)] == [1, 1, 1, 1]
+    # 16 MiB row blocks: two row arrays fit the kernel budget, three not
+    assert [pk.vmem_chunk(8, 16384, 256, n, jnp.float32)
+            for n in (1, 2, 3)] == [1, 1, 0]
+
+
+#: the chain head of a privatized mode (block 4096, three modes) by
+#: rank and mode length.  At rank 50 the dot streams the one-hot, and
+#: even a 4080-row mode needs little VMEM; at rank 200 Mosaic keeps
+#: whole one-hot copies, and past about 1150 rows one block needs more
+#: than the kernel budget (AOT compile for a v5e).  The products kernel
+#: before the in-kernel products gave the same heads, except for rank
+#: 50 at 4080 rows (xla_scan: it counted the one-hot at every rank) and
+#: rank 200 at 1717 and 2482 rows (unfused_pallas, whose compile then
+#: failed: its one-hot copies alone pass the device's vmem_limit).
+_PRIVATIZED_HEADS = {(50, 1140): "unfused_pallas", (50, 1717): "unfused_pallas",
+                     (50, 2482): "unfused_pallas", (50, 2862): "unfused_pallas",
+                     (50, 4080): "unfused_pallas",
+                     (200, 1024): "unfused_pallas",
+                     (200, 1140): "unfused_pallas", (200, 1717): "xla_scan",
+                     (200, 2482): "xla_scan", (200, 2862): "xla_scan"}
+
+
+@pytest.mark.parametrize("rank,dim", sorted(_PRIVATIZED_HEADS))
+def test_engine_chain_at_privatized_widths(rank, dim):
+    from types import SimpleNamespace
+
+    from splatt_tpu.ops.mttkrp import engine_chain
+
+    facs = [jax.ShapeDtypeStruct((d, rank), jnp.float32)
+            for d in (dim, 9184, 28818)]
+    lay = SimpleNamespace(block=4096, seg_width=8, nnz_pad=4096)
+    chain = engine_chain(lay, facs, 0, "privatized", "pallas")
+    assert chain[0] == _PRIVATIZED_HEADS[rank, dim]
+    assert chain[-2:] == ["xla_scan", "xla"]
 
 
 @pytest.mark.parametrize("width,dtype", [(8, jnp.float32),
                                          (16, jnp.bfloat16)])
 def test_onehot_vmem_request_grows_with_chunk_and_rank(width, dtype):
-    by_chunk = [pk.onehot_vmem_request(c, width, 4096, 50, dtype)
+    by_chunk = [pk.onehot_vmem_request(c, width, 4096, 50, 2, dtype)
                 for c in range(1, 9)]
     assert by_chunk == sorted(set(by_chunk))
     # 50, 128, 200 and 256 lanes pad to 128, 128, 256 and 256
-    by_rank = [pk.onehot_vmem_request(4, width, 4096, r, dtype)
+    by_rank = [pk.onehot_vmem_request(4, width, 4096, r, 2, dtype)
                for r in (50, 128, 200, 256, 300)]
     assert by_rank[0] == by_rank[1] < by_rank[2] == by_rank[3] < by_rank[4]
 
 
 def test_onehot_vmem_request_is_capped_at_vmem_limit(monkeypatch):
     limit = pk._vmem_limit()
-    assert pk.onehot_vmem_request(8, 2048, 4096, 1024, jnp.float32) == limit
+    assert pk.onehot_vmem_request(8, 2048, 4096, 1024, 2,
+                                  jnp.float32) == limit
     monkeypatch.setattr(pk, "_vmem_limit", lambda: 8 << 20)
-    assert pk.onehot_vmem_request(5, 8, 4096, 200, jnp.float32) == 8 << 20
-    assert pk.onehot_vmem_request(1, 8, 128, 8, jnp.float32) < 8 << 20
+    assert pk.onehot_vmem_request(5, 8, 4096, 200, 2, jnp.float32) == 8 << 20
+    assert pk.onehot_vmem_request(1, 8, 128, 8, 2, jnp.float32) < 8 << 20
 
 
 @pytest.mark.parametrize("name", ["med", "med4"])
@@ -123,9 +282,9 @@ def test_public_mttkrp_forced_pallas():
 
 
 def test_unfused_pallas_engine_direct():
-    """The chip's main-path engine (scan over block chunks: XLA gather
-    + Hadamard, Mosaic one-hot reduce) — sorted partials + privatized
-    totals vs the numpy brute force."""
+    """The chip's main-path engine (scan over block chunks: XLA gather,
+    Mosaic Hadamard product and one-hot reduce) — sorted partials +
+    privatized totals vs the numpy brute force."""
     from splatt_tpu.blocked import build_layout
     from splatt_tpu.ops.mttkrp import _scan_fused
 
@@ -197,8 +356,8 @@ def test_engine_chain_is_the_chips(impl, path):
 
 @pytest.mark.parametrize("impl", ["pallas", "pallas_interpret"])
 def test_engine_chain_skips_unfused_pallas_past_vmem(impl):
-    """When not even one block's one-hot, products and partials fit the
-    kernel's VMEM budget (vmem_chunk is 0), the chain starts at
+    """When not even one block's one-hot, gathered rows and partials fit
+    the kernel's VMEM budget (vmem_chunk is 0), the chain starts at
     xla_scan, which streams the one-hot through HBM instead."""
     from types import SimpleNamespace
 
@@ -207,7 +366,7 @@ def test_engine_chain_skips_unfused_pallas_past_vmem(impl):
     facs = [jax.ShapeDtypeStruct((d, 256), jnp.float32)
             for d in (4096, 48, 80)]
     lay = SimpleNamespace(block=16384, seg_width=4096, nnz_pad=16384)
-    assert pk.vmem_chunk(4096, 16384, 256) == 0
+    assert pk.vmem_chunk(4096, 16384, 256, 2, jnp.float32) == 0
     assert engine_chain(lay, facs, 0, "sorted_onehot",
                         impl) == ["xla_scan", "xla"]
 
@@ -262,9 +421,11 @@ def test_unfused_pallas_bf16_accumulates_f32():
 def test_vmem_chunk_bounds():
     from splatt_tpu.ops.pallas_kernels import vmem_chunk
 
-    assert vmem_chunk(64, 512, 128) >= 1          # typical config fits
-    assert vmem_chunk(4096, 4096, 128) == 0       # pathological: fall back
-    assert 1 <= vmem_chunk(8, 128, 8) <= 8
+    f32 = jnp.float32
+    assert vmem_chunk(64, 512, 128, 2, f32) >= 1      # typical config fits
+    assert vmem_chunk(4096, 4096, 256, 2, f32) == 0   # pathological: fall back
+    assert 1 <= vmem_chunk(8, 128, 8, 2, f32) <= 8
+    assert 1 <= vmem_chunk(8, 128, 8, 7, f32) <= 8    # an 8-mode tensor
 
 
 def test_pallas_bf16_accumulates_f32():
@@ -273,17 +434,18 @@ def test_pallas_bf16_accumulates_f32():
     rng = np.random.default_rng(3)
     nb, B, S, R = 4, 128, 16, 8
     local = rng.integers(-1, S + 2, size=(nb, B)).astype(np.int32)
-    prod16 = jnp.asarray(rng.random((nb, B, R)), dtype=jnp.bfloat16)
-    got = onehot_reduce_sorted(jnp.asarray(local), prod16, S, interpret=True)
+    vals16, rows16, prod = _kernel_inputs(rng, nb, B, R,
+                                          dtype=jnp.bfloat16)
+    got = onehot_reduce_sorted(jnp.asarray(local), vals16, rows16, S,
+                               interpret=True)
     assert got.dtype == jnp.float32
-    want = _np_onehot_sorted(local, np.asarray(prod16, dtype=np.float64), S)
+    want = _np_onehot_sorted(local, prod, S)
     np.testing.assert_allclose(np.asarray(got, dtype=np.float64), want,
                                atol=3e-2)
-    got2 = onehot_reduce_full(jnp.asarray(local), prod16, S + 8,
+    got2 = onehot_reduce_full(jnp.asarray(local), vals16, rows16, S + 8,
                               interpret=True)
     assert got2.dtype == jnp.float32
-    want2 = _np_onehot_sorted(local, np.asarray(prod16, dtype=np.float64),
-                              S + 8).sum(axis=0)
+    want2 = _np_onehot_sorted(local, prod, S + 8).sum(axis=0)
     np.testing.assert_allclose(np.asarray(got2, dtype=np.float64), want2,
                                atol=3e-2)
 
@@ -372,20 +534,3 @@ def test_scan_target_knob_changes_chunking_not_results(engine, path):
             got = _mttkrp_blocked_jit(*args)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=TOL, err_msg=str(target))
-
-
-@pytest.mark.parametrize("C,expect", [(10, 5), (40, 8), (81, 3), (7, 7),
-                                      (11, 1), (1, 1)])
-def test_dividing_chunk(C, expect):
-    """The kernel chunk of a C-block scan step: the largest divisor of C
-    within vmem_chunk's cap (8 at rank 200, block 4096), and 0 exactly
-    where vmem_chunk is 0 (the engine does not fit; dispatch falls back
-    to XLA)."""
-    from splatt_tpu.ops.pallas_kernels import dividing_chunk, vmem_chunk
-
-    assert vmem_chunk(8, 4096, 200) == 8
-    got = dividing_chunk(C, 8, 4096, 200)
-    assert got == expect
-    assert C % got == 0 and got <= 8
-    assert vmem_chunk(4096, 4096, 128) == 0
-    assert dividing_chunk(C, 4096, 4096, 128) == 0

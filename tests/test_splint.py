@@ -1359,7 +1359,8 @@ def test_spl025_fires_when_grid_pad_reverted(tmp_path):
     """Running the one-hot kernel's grid over the unpadded block count
     (``nb // chunk`` instead of the ``_pad_blocks`` extent) must trip
     SPL025's grid leg: a ragged tail block would silently drop."""
-    anchor = ("    local, prod, nb_pad = _pad_blocks(local, prod, chunk)\n"
+    anchor = ("    local, vals, rows, nb_pad = _pad_blocks(local, vals, rows, "
+              "chunk)\n"
               "    grid = (nb_pad // chunk,)\n"
               "    out = pl.pallas_call(\n"
               "        functools.partial(_sorted_kernel, ")

@@ -126,9 +126,10 @@ def engine_program(one_chip):
 @pytest.mark.parametrize("mode,rank", MODE_RANKS, ids=MODE_RANK_IDS)
 def test_unfused_pallas_engine_compiles(engine_program, mode, rank):
     """The engine the chip runs for NELL-2: a scan over block chunks,
-    XLA gather + Hadamard, Mosaic one-hot reduce.  At rank 200 a scan
-    step holds 10 blocks, which the kernel takes as 2 grid steps of 5:
-    no step's (C, B, R) products are padded to a larger chunk."""
+    XLA gather, Mosaic Hadamard product and one-hot reduce.  At rank 200
+    a scan step holds 10 blocks, which the kernel takes as 10 grid
+    steps of 1: no step's (C, B, R) rows are padded to a larger
+    chunk."""
     compiled = engine_program(mode, rank)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -159,6 +160,31 @@ def test_gathered_tables_stay_in_vmem(engine_program, mode, rank):
             assert layout.endswith("S(1)"), (d, layout)
 
 
+@pytest.mark.parametrize("mode,rank", MODE_RANKS, ids=MODE_RANK_IDS)
+def test_no_products_outside_the_kernel(engine_program, mode, rank):
+    """The scan step hands the gathered rows to the one-hot kernel,
+    which forms their Hadamard product in VMEM: the compiled program
+    has no multiply shaped like a step's (C, B, R) products, or their
+    flat (C·B, R) form, outside the kernel, and the kernel reads the
+    ids, the values and both gathered row arrays."""
+    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
+
+    C = _block_chunks(-(-NNZ // BLOCK), rank * BLOCK, _SCAN_TARGET)
+    text = engine_program(mode, rank).as_text()
+    for shape in (f"{C},{BLOCK},{rank}", f"{C * BLOCK},{rank}"):
+        assert not re.search(rf"= f32\[{shape}\]\S* multiply\(", text), shape
+        assert not re.search(rf"%\S*multiply\S*fusion\S* = f32\[{shape}\]",
+                             text), shape
+    (call,) = [line for line in text.splitlines()
+               if "onehot_reduce_sorted" in line
+               and "tpu_custom_call" in line and " = " in line]
+    constraints = call.split("operand_layout_constraints={")[1]
+    constraints = constraints.split("}}")[0]
+    assert re.findall(r"(\w+)\[([\d,]+)\]", constraints) == [
+        ("s32", f"{C},1,{BLOCK}"), ("f32", f"{C},1,{BLOCK}"),
+        ("f32", f"{C},{BLOCK},{rank}"), ("f32", f"{C},{BLOCK},{rank}")]
+
+
 def _scoped_sizes(text, name):
     """Scoped-VMEM bytes each compiled custom call `name` asks for."""
     return [int(size) for line in text.splitlines()
@@ -169,35 +195,40 @@ def _scoped_sizes(text, name):
 
 @pytest.mark.parametrize(
     "kernel,width,rank",
-    [("sorted", SEG, RANK), ("full", 1024, RANK),
+    [("sorted", SEG, RANK), ("full", 1024, RANK), ("full", 4088, RANK),
      ("sorted", SEG, 200), ("full", 1024, 200)],
-    ids=["sorted-8", "full-1024", "sorted-8-r200", "full-1024-r200"])
+    ids=["sorted-8", "full-1024", "full-4088", "sorted-8-r200",
+         "full-1024-r200"])
 def test_onehot_reduce_compiles(one_chip, kernel, width, rank):
     """The one-hot reduce kernels at one scan step's shapes and the
-    chunk _scan_fused gives them (the full kernel at onehot_cap), fed
-    by fusions as in the scan step, compile under exactly
-    onehot_vmem_request's reservation, which stays below the device's
-    vmem_limit.  (Fed straight from parameters, a kernel is not held to
-    its reservation at compile time.)"""
+    chunk _scan_fused gives them (the full kernel at onehot_cap, and at
+    rank 50 at the privatized path's widest, where the dot streams the
+    one-hot and keeps no whole copy of it), fed the ids, values and two
+    gathered row arrays by fusions as in the scan step, compile under
+    exactly onehot_vmem_request's reservation, which stays below the
+    device's vmem_limit.  (Fed straight from parameters, a kernel is not
+    held to its reservation at compile time.)"""
     from splatt_tpu.devices import DEVICE_SPECS
     from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
-    from splatt_tpu.ops.pallas_kernels import (dividing_chunk,
-                                               onehot_reduce_full,
+    from splatt_tpu.ops.pallas_kernels import (onehot_reduce_full,
                                                onehot_reduce_sorted,
-                                               onehot_vmem_request)
+                                               onehot_vmem_request,
+                                               vmem_chunk)
 
     C = _block_chunks(-(-NNZ // BLOCK), rank * BLOCK, _SCAN_TARGET)
     local = jax.ShapeDtypeStruct((C, BLOCK), jnp.int32, sharding=one_chip)
-    prod = jax.ShapeDtypeStruct((C, BLOCK, rank), jnp.float32,
-                                sharding=one_chip)
-    chunk = dividing_chunk(C, width, BLOCK, rank)
-    assert chunk >= 1
-    want = onehot_vmem_request(chunk, width, BLOCK, rank, jnp.float32)
+    vals = jax.ShapeDtypeStruct((C, BLOCK), jnp.float32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((C, BLOCK, rank), jnp.float32,
+                               sharding=one_chip)
+    chunk = vmem_chunk(width, BLOCK, rank, 2, jnp.float32)
+    assert chunk == 1
+    want = onehot_vmem_request(chunk, width, BLOCK, rank, 2, jnp.float32)
     kind = next(iter(one_chip.device_set)).device_kind
     assert want < DEVICE_SPECS[kind].vmem_limit
     fn = onehot_reduce_sorted if kernel == "sorted" else onehot_reduce_full
-    text = jax.jit(lambda l, p: fn(l + 1, p * 2, width, chunk=chunk)).lower(
-        local, prod).compile().as_text()
+    text = jax.jit(lambda l, v, a, b: fn(l + 1, v * 2, [a * 2, b * 3],
+                                         width, chunk=chunk)).lower(
+        local, vals, row, row).compile().as_text()
     assert _scoped_sizes(text, f"onehot_reduce_{kernel}") == [want]
 
 

@@ -20,7 +20,7 @@ contract on the OUTPUT dtypes:
      the static tiling table SPL025 judges against — (8, 128) f32,
      (16, 128) bf16;
   4. the one real execution (``onehot_reduce_sorted`` in interpret
-     mode over bf16 partials) produces a wide output whose VALUES
+     mode over bf16 values and rows) produces a wide output whose VALUES
      match an exactly-accumulated reference — dtype discipline that
      types correctly but sums garbage is still caught.
 
@@ -253,9 +253,9 @@ def _check_fit(result: Result, storage: str) -> None:
 
 def _check_interpret(result: Result) -> None:
     """One REAL execution: the sorted one-hot Pallas reduction in
-    interpret mode over bf16 partials — output must be wide AND match
-    an exactly-accumulated reference (a cast inserted after the
-    accumulate would type correctly and still lose mass)."""
+    interpret mode over bf16 values and gathered rows — output must be
+    wide AND match an exactly-accumulated reference (a cast inserted
+    after the accumulate would type correctly and still lose mass)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -264,14 +264,18 @@ def _check_interpret(result: Result) -> None:
     rng = np.random.default_rng(7)
     nb, B, S, R = 2, 128, 8, 8
     local = rng.integers(-1, S + 2, size=(nb, B)).astype(np.int32)
-    prod = jnp.asarray(rng.random((nb, B, R)), dtype=jnp.bfloat16)
-    got = onehot_reduce_sorted(jnp.asarray(local), prod, S,
+    vals = jnp.asarray(rng.random((nb, B)), dtype=jnp.bfloat16)
+    rows = [jnp.asarray(rng.random((nb, B, R)), dtype=jnp.bfloat16)
+            for _ in range(2)]
+    got = onehot_reduce_sorted(jnp.asarray(local), vals, rows, S,
                                interpret=True)
     _expect(result, "onehot_reduce_sorted[interpret]", "bfloat16",
             got.dtype, _ACC, "block-partial accumulator")
-    # reference: the SAME bf16-rounded inputs, accumulated exactly
+    # reference: the SAME bf16 inputs and the kernel's bf16 product of
+    # the rows, weighted by the values and accumulated exactly
     want = np.zeros((nb, S, R))
-    p64 = np.asarray(prod, dtype=np.float64)
+    p64 = (np.asarray(rows[0] * rows[1], dtype=np.float64)
+           * np.asarray(vals, dtype=np.float64)[..., None])
     for b in range(nb):
         for j in range(B):
             if 0 <= local[b, j] < S:
