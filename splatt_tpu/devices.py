@@ -19,6 +19,7 @@ class DeviceSpec(NamedTuple):
     mxu_gflops: float     # peak MXU compute (bf16), GFLOP/s
     hbm_bytes: int        # HBM capacity per chip
     vmem_limit: int       # most scoped VMEM a kernel may request from Mosaic
+    vmem_bytes: int       # VMEM per core
 
 
 #: Sources: Google Cloud documentation, "TPU v5e" (819 GB/s HBM,
@@ -32,7 +33,8 @@ class DeviceSpec(NamedTuple):
 DEVICE_SPECS = {
     "TPU v5 lite": DeviceSpec(hbm_gbs=819.0, mxu_gflops=197000.0,
                               hbm_bytes=16 * 10**9,
-                              vmem_limit=100 << 20),
+                              vmem_limit=100 << 20,
+                              vmem_bytes=128 << 20),
 }
 
 #: the generation the kernels are written for: interpret mode (CPU

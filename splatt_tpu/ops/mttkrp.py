@@ -269,9 +269,35 @@ def dense_mttkrp(layout, factors: Sequence[jax.Array],
 #: elements of one-hot materialized per scan step of the XLA engine —
 #: the fallback's main tuning knob (more = fewer, bigger fused steps).
 #: Env-overridable so a tuning sweep can measure it.  The unfused
-#: Pallas engine sizes its steps by the same budget, counted in each
-#: gathered (C, B, R) row array instead.
+#: Pallas engine bounds each gathered (C, B, R) row array's elements
+#: by the same budget, and the step's padded row arrays by
+#: :func:`_scan_row_bytes`.
 _SCAN_TARGET = read_env_int("SPLATT_SCAN_TARGET_ELEMS")
+
+
+def _scan_row_bytes() -> int:
+    """Most tile-padded bytes one ``unfused_pallas`` scan step's gathered
+    row arrays, all ``nmodes - 1`` of them, may take: 5/4 of the
+    device's VMEM (160 MiB on a v5e).  The chip lays a (C, B, R) row
+    array out in (8, 128) tiles, so a rank below 128 pads to 128 lanes,
+    and XLA keeps the gathered factor tables in VMEM across the scan
+    only while the step's row arrays stay about this small: AOT compiles
+    for a v5e at 4 modes and rank 25 keep every table that fits, in all
+    four modes, at steps of up to 168 MiB (28 blocks), and keep none in
+    mode 0 from 180 MiB (30 blocks).  Within that window 5/4 is the
+    share at which NELL-2's rank-50 step of 40 blocks (two row arrays of
+    80 MiB) is exactly full, so its programs are those of the element
+    bound alone."""
+    from splatt_tpu.ops.pallas_kernels import vmem_bytes
+
+    return vmem_bytes() * 5 // 4
+
+
+def _row_bytes(block: int, rank: int, dtype) -> int:
+    """Tile-padded bytes of one block's (B, R) gathered rows."""
+    from splatt_tpu.ops.pallas_kernels import padded_bytes
+
+    return padded_bytes((block, rank), jnp.dtype(dtype).itemsize)
 
 
 def _block_chunks(nblocks: int, elems_per_block: int,
@@ -281,6 +307,21 @@ def _block_chunks(nblocks: int, elems_per_block: int,
         target_elems = _SCAN_TARGET
     c = max(1, target_elems // max(elems_per_block, 1))
     return min(c, nblocks)
+
+
+def _scan_chunk(nblocks: int, width: int, block: int, rank: int,
+                nrows: int, dtype, pallas: bool,
+                target_elems: Optional[int] = None) -> int:
+    """Blocks per step of :func:`_scan_fused`.  ``xla_scan``: the step's
+    (C, width, B) one-hot within `target_elems` elements.
+    ``unfused_pallas``: each of the step's `nrows` gathered (C, B, R)
+    row arrays within `target_elems` elements, and all of them,
+    tile-padded, within :func:`_scan_row_bytes`."""
+    if not pallas:
+        return _block_chunks(nblocks, width * block, target_elems)
+    row = _row_bytes(block, rank, dtype)
+    return min(_block_chunks(nblocks, rank * block, target_elems),
+               max(1, _scan_row_bytes() // (max(nrows, 1) * row)))
 
 
 def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
@@ -301,17 +342,17 @@ def _scan_fused(layout: ModeLayout, factors: Sequence[jax.Array], mode: int,
     (the ``unfused_pallas`` engine: onehot_reduce_sorted /
     onehot_reduce_full), which take the gathered rows and the values,
     form the products and the one-hot in VMEM, and so materialize
-    neither in HBM.  `target_elems` bounds the elements one step
-    materializes in HBM: the one-hot for the einsum, each gathered
-    (C, B, R) row array for the kernels.  The kernels run one block per
-    grid step (pallas_kernels.vmem_chunk), so they never pad the step's
-    rows to a larger chunk.
+    neither in HBM.  :func:`_scan_chunk` sizes the step from
+    `target_elems` (and, for the kernels, the row arrays' padded bytes).
+    The kernels run one block per grid step (pallas_kernels.vmem_chunk),
+    so they never pad the step's rows to a larger chunk.
     """
     nb, B = layout.nblocks, layout.block
     R = int(factors[0].shape[1])
     dtype = factors[0].dtype
     nmodes = layout.nmodes
-    C = _block_chunks(nb, (R if pallas else width) * B, target_elems)
+    C = _scan_chunk(nb, width, B, R, nmodes - 1, dtype, pallas,
+                    target_elems)
     nsteps = -(-nb // C)
     nb_pad = nsteps * C
 
@@ -656,9 +697,8 @@ def mttkrp_blocked(layout: ModeLayout, factors: List[jax.Array], mode: int,
                                block=int(layout.block),
                                enc=getattr(layout, "format_desc",
                                            lambda: "i32/glob/?")(),
-                               kernel_vmem=_kernel_vmem(
-                                   layout, factors, mode, path, engine),
-                               tables_vmem=_tables_vmem(factors, mode))
+                               **_vmem_attrs(layout, factors, mode, path,
+                                             engine, scan_target))
                     if trace.enabled() else trace.NOOP)
             with span:
                 return resilience.retry_transient(attempt,
@@ -837,14 +877,81 @@ def _kernel_vmem(layout: ModeLayout, factors: Sequence[jax.Array],
     return 0
 
 
+def _table_bytes(factors: Sequence[jax.Array], mode: int) -> dict:
+    """Tile-padded VMEM bytes of each factor table `mode` gathers, by
+    mode."""
+    from splatt_tpu.ops.pallas_kernels import padded_bytes
+
+    return {k: padded_bytes(f.shape, jnp.dtype(f.dtype).itemsize)
+            for k, f in enumerate(factors) if k != mode}
+
+
 def _tables_vmem(factors: Sequence[jax.Array], mode: int) -> int:
     """Tile-padded VMEM bytes of the factor tables `mode` gathers: with
     ``kernel_vmem``, what shows whether the scan's tables can stay
     on-chip.  Shapes only: the dispatch span's ``tables_vmem``."""
-    from splatt_tpu.ops.pallas_kernels import padded_bytes
+    return sum(_table_bytes(factors, mode).values())
 
-    return sum(padded_bytes(f.shape, jnp.dtype(f.dtype).itemsize)
-               for k, f in enumerate(factors) if k != mode)
+
+def _tables_hbm(factors: Sequence[jax.Array], mode: int,
+                kernel_vmem: int, step_bytes: int = 0) -> List[int]:
+    """Modes whose gathered factor tables the scan cannot keep in VMEM.
+    A step whose padded row arrays, `step_bytes` in all, pass
+    :func:`_scan_row_bytes` leaves every table in HBM (the AOT compile
+    at Enron's widths and rank 25, 81 blocks a step, leaves all twelve
+    there); within it the tables, smallest first, fill what VMEM holds
+    beside the kernel's request `kernel_vmem`, and each that no longer
+    fits stays in HBM.  The AOT compiles of tests/test_tpu_compile.py
+    pin both against XLA's placement.  Shapes only: the dispatch span's
+    ``tables_hbm``; the device's own placement is read from a trace
+    (docs/observability.md)."""
+    from splatt_tpu.ops.pallas_kernels import vmem_bytes
+
+    tables = _table_bytes(factors, mode)
+    if step_bytes > _scan_row_bytes():
+        return sorted(tables)
+    free = vmem_bytes() - kernel_vmem
+    hbm = []
+    for k, need in sorted(tables.items(), key=lambda kv: (kv[1], kv[0])):
+        if need <= free:
+            free -= need
+        else:
+            hbm.append(k)
+    return sorted(hbm)
+
+
+def _step_blocks(layout: ModeLayout, factors: Sequence[jax.Array],
+                 mode: int, path: str, engine: str,
+                 scan_target: int) -> int:
+    """Blocks per scan step (:func:`_scan_chunk`) of a scan engine on a
+    one-hot path, 0 for the engines that do not scan.  Shapes only: the
+    dispatch span's ``step_blocks``."""
+    if (engine not in ("unfused_pallas", "xla_scan")
+            or path not in ("sorted_onehot", "privatized")):
+        return 0
+    width = _onehot_width(layout, int(factors[mode].shape[0]), path)
+    return _scan_chunk(layout.nblocks, width, layout.block,
+                       int(factors[0].shape[1]), len(factors) - 1,
+                       factors[0].dtype, engine == "unfused_pallas",
+                       scan_target)
+
+
+def _vmem_attrs(layout: ModeLayout, factors: Sequence[jax.Array],
+                mode: int, path: str, engine: str,
+                scan_target: int) -> dict:
+    """The dispatch span's placement attributes, from shapes:
+    ``kernel_vmem``, ``tables_vmem``, ``step_blocks``, ``tables_hbm``
+    (the step's row arrays counted for the kernels' scan only)."""
+    kernel = _kernel_vmem(layout, factors, mode, path, engine)
+    step = _step_blocks(layout, factors, mode, path, engine, scan_target)
+    rows = 0
+    if engine == "unfused_pallas":
+        rows = step * (len(factors) - 1) * _row_bytes(
+            layout.block, int(factors[0].shape[1]), factors[0].dtype)
+    return {"kernel_vmem": kernel,
+            "tables_vmem": _tables_vmem(factors, mode),
+            "step_blocks": step,
+            "tables_hbm": _tables_hbm(factors, mode, kernel, rows)}
 
 
 def _engine_probed_ok(engine: str, regime: str, block: int,

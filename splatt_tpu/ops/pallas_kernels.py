@@ -42,14 +42,23 @@ from splatt_tpu.utils.env import ceil_to
 _CHUNK = 8
 
 @functools.cache
-def _vmem_limit() -> int:
-    """Most scoped VMEM a kernel may request (splatt_tpu/devices.py):
-    the device's own row on a TPU, the kernels' target chip in interpret
-    mode."""
+def _spec():
+    """The kernels' row of splatt_tpu/devices.py: the device's own on a
+    TPU, the kernels' target chip in interpret mode."""
     from splatt_tpu.devices import DEVICE_SPECS, KERNEL_TARGET, device_spec
 
-    spec = device_spec() or DEVICE_SPECS[KERNEL_TARGET]
-    return spec.vmem_limit
+    return device_spec() or DEVICE_SPECS[KERNEL_TARGET]
+
+
+def _vmem_limit() -> int:
+    """Most scoped VMEM a kernel may request."""
+    return _spec().vmem_limit
+
+
+def vmem_bytes() -> int:
+    """VMEM per core: what XLA shares among a program's live buffers
+    and the scoped request of the kernel it runs."""
+    return _spec().vmem_bytes
 
 
 def _vmem_budget() -> int:

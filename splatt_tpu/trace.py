@@ -141,9 +141,11 @@ SPANS = {
     "mttkrp.dispatch": "one blocked-MTTKRP engine-chain dispatch "
                        "(attrs: mode, path, block, chosen engine, and "
                        "enc — the consumed layout encoding, e.g. "
-                       "u16/seg/bf16, docs/format.md; kernel_vmem and "
-                       "tables_vmem — the kernel's scoped-VMEM request "
-                       "and the gathered tables' padded bytes, "
+                       "u16/seg/bf16, docs/format.md; kernel_vmem, "
+                       "tables_vmem, step_blocks and tables_hbm — the "
+                       "kernel's scoped-VMEM request, the gathered "
+                       "tables' padded bytes, the scan step's blocks "
+                       "and the tables reckoned in HBM, "
                        "docs/observability.md); under a jitted "
                        "sweep this records trace-time, once per "
                        "compilation",

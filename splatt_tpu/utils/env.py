@@ -47,7 +47,12 @@ ENV_VARS = {
                                      "0/off/false/no = fail loudly"),
     "SPLATT_SCAN_TARGET_ELEMS": EnvVar(1 << 23, "one-hot elements "
                                        "materialized per scan step of "
-                                       "the xla_scan MTTKRP engine"),
+                                       "the xla_scan MTTKRP engine; "
+                                       "elements of each gathered row "
+                                       "array per unfused_pallas scan "
+                                       "step (its padded row arrays "
+                                       "also stay within 5/4 of the "
+                                       "device's VMEM)"),
     "SPLATT_FAULTS": EnvVar("", "comma-separated fault-arming specs "
                             "site[:kind][:modifier]... for the fault-"
                             "injection harness, including seeded chaos "

@@ -69,9 +69,8 @@ def test_dispatch_with_tracing_off_never_builds_attributes(monkeypatch):
     assert span["args"] == {
         "mode": 0, "path": "sorted_onehot", "engine": engine,
         "block": lay.block, "enc": lay.format_desc(),
-        "kernel_vmem": mk._kernel_vmem(lay, factors, 0, "sorted_onehot",
-                                       engine),
-        "tables_vmem": mk._tables_vmem(factors, 0)}
+        **mk._vmem_attrs(lay, factors, 0, "sorted_onehot", engine,
+                         mk._SCAN_TARGET)}
     assert engine in mk.engine_chain(lay, factors, 0)
 
 
@@ -81,7 +80,9 @@ def test_dispatch_vmem_attributes_only_with_tracing_on(monkeypatch, path,
                                                        layout_mode):
     """``kernel_vmem`` is the one-hot kernel's reservation at the chunk
     the scan gives it; ``tables_vmem`` the tile-padded bytes of the
-    tables the mode gathers.  Neither is computed with tracing off."""
+    tables the mode gathers; ``step_blocks`` the scan step's blocks;
+    ``tables_hbm`` the gathered tables that do not fit VMEM beside the
+    kernel (none, at this size).  None is computed with tracing off."""
     from splatt_tpu.ops import pallas_kernels as pk
 
     tt, bs = _blocked()
@@ -94,8 +95,9 @@ def test_dispatch_vmem_attributes_only_with_tracing_on(monkeypatch, path,
         raise AssertionError("a VMEM attribute was built with tracing off")
 
     with monkeypatch.context() as mp:
-        mp.setattr(mk, "_kernel_vmem", refuse)
-        mp.setattr(mk, "_tables_vmem", refuse)
+        for name in ("_kernel_vmem", "_tables_vmem", "_step_blocks",
+                     "_tables_hbm"):
+            mp.setattr(mk, name, refuse)
         mk.mttkrp_blocked(lay, factors, 0, path=path,
                           impl="pallas_interpret")
     assert trace.spans() == []
@@ -112,6 +114,9 @@ def test_dispatch_vmem_attributes_only_with_tracing_on(monkeypatch, path,
         chunk, width, B, R, 2, factors[0].dtype) > 0
     assert span["args"]["tables_vmem"] == sum(
         -(-d // 8) * 8 * -(-R // 128) * 128 * 8 for d in tt.dims[1:])
+    assert span["args"]["step_blocks"] == mk._scan_chunk(
+        lay.nblocks, width, B, R, 2, factors[0].dtype, True)
+    assert span["args"]["tables_hbm"] == []
 
 
 class _NoIndexing:

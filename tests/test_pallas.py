@@ -170,14 +170,71 @@ def test_onehot_vmem_request_at_nell2_steps(rank, seg):
     arrays, one block per grid step, ask for no more VMEM than the
     products kernel did, so XLA keeps the room it had for the gathered
     tables."""
-    from splatt_tpu.ops.mttkrp import _SCAN_TARGET, _block_chunks
+    from splatt_tpu.ops.mttkrp import _scan_chunk
 
-    C = _block_chunks(18770, rank * 4096, _SCAN_TARGET)
+    C = _scan_chunk(18770, seg, 4096, rank, 2, jnp.float32, True)
     assert C == {200: 10, 50: 40}[rank]
     chunk = pk.vmem_chunk(seg, 4096, rank, 2, jnp.float32)
     assert chunk == 1
     req = pk.onehot_vmem_request(chunk, seg, 4096, rank, 2, jnp.float32)
     assert req <= _PRODUCTS_KERNEL_REQUEST[rank, seg]
+
+
+@pytest.mark.parametrize("rank,nrows,nblocks,dtype,want", [
+    (25, 3, 13233, jnp.float32, 26),
+    (50, 2, 18770, jnp.float32, 40),
+    (200, 2, 18770, jnp.float32, 10),
+    (25, 2, 18770, jnp.float32, 40),
+    (25, 3, 13233, jnp.bfloat16, 53),
+    (256, 7, 18770, jnp.float32, 5),
+    (50, 2, 12, jnp.float32, 12)],
+    ids=["enron-r25", "nell2-r50", "nell2-r200", "3mode-r25",
+         "enron-r25-bf16", "8mode-r256", "short-mode"])
+def test_scan_chunk_counts_padded_row_arrays(rank, nrows, nblocks, dtype,
+                                             want):
+    """The unfused Pallas scan step holds each (C, 4096, R) row array to
+    2^23 elements and all of them, tile-padded to 128 lanes, to 160
+    MiB: Enron's rank 25 pads to 128 lanes, so three row arrays take 26
+    blocks (81 by elements alone); NELL-2's steps stay at 40 (rank 50,
+    both bounds) and 10 (rank 200, the elements); bf16 rows pad to half
+    the bytes; a mode shorter than a step takes all its blocks."""
+    from splatt_tpu.ops.mttkrp import (_SCAN_TARGET, _block_chunks,
+                                       _scan_chunk, _scan_row_bytes)
+
+    C = _scan_chunk(nblocks, 8, 4096, rank, nrows, dtype, True)
+    assert C == want
+    lanes = -(-rank // 128) * 128
+    step = C * nrows * 4096 * lanes * jnp.dtype(dtype).itemsize
+    assert _scan_row_bytes() == 160 << 20
+    assert step <= _scan_row_bytes() and C * rank * 4096 <= _SCAN_TARGET
+    # the XLA engine's step is its one-hot's, as before
+    assert (_scan_chunk(nblocks, 8, 4096, rank, nrows, dtype, False)
+            == _block_chunks(nblocks, 8 * 4096))
+
+
+def test_tables_hbm_fills_vmem_smallest_first():
+    """``tables_hbm`` reckons the gathered tables, smallest first, into
+    what VMEM holds beside the kernel's request: Enron's 244,268-row
+    table (119.3 MiB padded at rank 25) fits no 128 MiB VMEM beside an
+    18.5 MiB kernel; beside a kernel asking for the whole 100 MiB
+    vmem_limit only the small tables fit.  A step whose padded row
+    arrays pass the 160 MiB bound (81 blocks of three rank-25 row
+    arrays: 486 MiB) leaves every table in HBM; one at the bound (26
+    blocks: 156 MiB) does not change the reckoning."""
+    from splatt_tpu.ops.mttkrp import _tables_hbm
+
+    f = [jnp.zeros((d, 25), jnp.float32) for d in (6066, 5699, 244268, 1176)]
+    mib = 1 << 20
+    assert _tables_hbm(f, 0, 18 * mib + mib // 2) == [2]
+    assert _tables_hbm(f, 2, 18 * mib + mib // 2) == []
+    assert _tables_hbm(f, 0, 0) == []
+    assert _tables_hbm(f, 3, 100 * mib) == [2]
+    assert _tables_hbm(f, 3, 127 * mib) == [0, 1, 2]
+    row = 4096 * 128 * 4
+    assert _tables_hbm(f, 0, 18 * mib, 26 * 3 * row) == [2]
+    assert _tables_hbm(f, 0, 18 * mib, 160 * mib) == [2]
+    assert _tables_hbm(f, 0, 18 * mib, 81 * 3 * row) == [1, 2, 3]
+    assert _tables_hbm(f, 2, 18 * mib, 160 * mib + 1) == [0, 1, 3]
 
 
 def test_vmem_chunk_counts_every_row_array():
